@@ -6,7 +6,7 @@ import sys
 
 required = ["TONY_JOB_NAME", "TONY_TASK_INDEX", "TONY_TASK_NUM", "TONY_IS_CHIEF",
             "CLUSTER_SPEC", "TONY_JOB_ID", "TONY_SESSION_ID",
-            "TONY_JOB_DIR", "TONY_COMPILE_CACHE_DIR"]
+            "TONY_JOB_DIR"]
 missing = [k for k in required if k not in os.environ]
 if missing:
     print("missing env:", missing)

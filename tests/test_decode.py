@@ -80,16 +80,15 @@ def test_flash_decode_int8_cache(qkv):
 def test_flash_decode_zero_length_slot_rows(qkv):
     """Per-slot lengths (serve/): a length-0 row — an EMPTY continuous-
     batching slot — must emit EXACT zeros (never NaN, never a uniform
-    average of junk V tiles) while live rows stay exact. Covers the GQA
-    kernel's never-ran accumulator and the MHA kernel's `valid` mask
-    (decode.py _finalize)."""
+    average of junk V tiles) while live rows stay exact, at GQA and MHA
+    head layouts (the `valid` mask in decode.py _finalize)."""
     q, k, v = qkv
     length = jnp.asarray([0, 37], jnp.int32)
     out = np.asarray(flash_decode(q, k, v, length, block_k=16))
     assert (out[0] == 0).all()
     ref = _ref_decode(q, k, v, np.asarray([37, 37]))
     np.testing.assert_allclose(out[1], ref[1], atol=2e-5, rtol=2e-5)
-    # MHA batched-rows kernel (bh_blk path needs (b*kvh) % 8 == 0)
+    # MHA: every q head its own kv head
     kf = jnp.repeat(k, 4, axis=2)
     vf = jnp.repeat(v, 4, axis=2)
     out = np.asarray(flash_decode(q, kf, vf, length, block_k=16))
@@ -179,7 +178,7 @@ def test_int8_cache_vars_allocated(tiny_lm):
 
 @pytest.fixture(scope="module")
 def qkv_mha():
-    # h == kvh and b*kvh % 8 == 0 -> the batched-rows MHA kernel
+    # h == kvh: one query row per kv head (group 1)
     b, s, h, d = 2, 64, 8, 16
     q = jax.random.normal(jax.random.PRNGKey(7), (b, h, d))
     k = jax.random.normal(jax.random.PRNGKey(8), (b, s, h, d))
@@ -189,10 +188,9 @@ def qkv_mha():
 
 @pytest.mark.parametrize("window", [0, 10])
 def test_flash_decode_mha_mixed_lengths(qkv_mha, window):
-    """The batched-rows MHA kernel assembles per-row lengths from SMEM
-    (rows of one 8-row block span batches with DIFFERENT lengths) and
-    gates blocks on the max/min over rows — exactness against the numpy
-    reference across mixed lengths and a sliding window."""
+    """MHA (group 1): each batch row reads its own SMEM length —
+    exactness against the numpy reference across mixed lengths and a
+    sliding window."""
     q, k, v = qkv_mha
     length = jnp.asarray([37, 64], jnp.int32)
     out = flash_decode(q, k, v, length, window=window, block_k=16)
@@ -201,7 +199,7 @@ def test_flash_decode_mha_mixed_lengths(qkv_mha, window):
 
 
 def test_flash_decode_mha_int8_cache(qkv_mha):
-    """int8 cache through the MHA kernel's scale-tile dequant path."""
+    """int8 cache at group 1 through the scale-tile fold."""
     q, k, v = qkv_mha
     kq, ks = quantize_kv(k)
     vq, vs = quantize_kv(v)
@@ -214,9 +212,8 @@ def test_flash_decode_mha_int8_cache(qkv_mha):
 
 
 def test_flash_decode_mha_windowed_int8(qkv_mha):
-    """Window + int8 + mixed lengths together on the MHA kernel (the
-    conservative in_range gate must not skip a block any row's window
-    still reaches)."""
+    """Window + int8 + mixed lengths together at group 1 (the in_range
+    gate must not skip a block the row's window still reaches)."""
     q, k, v = qkv_mha
     kq, ks = quantize_kv(k)
     vq, vs = quantize_kv(v)
@@ -231,11 +228,11 @@ def test_flash_decode_mha_windowed_int8(qkv_mha):
 
 @pytest.mark.parametrize("h", [12, 16])
 def test_flash_decode_mha_head_count_branches(h):
-    """The tile-legality rule (r14): 16 MHA heads take the
-    head-blocked kernel with hb=8 (a sublane multiple); 12 heads have
-    no legal head block (12 % 8 != 0) and fall back to the GQA
-    kernel. Both paths must match the reference, int8 included (the
-    MHA path folds the transposed scale tiles onto scores/probs)."""
+    """The tile-legality rule (``_head_block``): 16 heads ride 8 per
+    instance (a sublane multiple); 12 heads have no 8-multiple divisor
+    and ride all 12 in one full-dim block. Both must match the
+    reference, int8 included (the kernel folds the transposed scale
+    tiles onto scores/probs)."""
     b, s, d = 2, 64, 16
     q = jax.random.normal(jax.random.PRNGKey(20), (b, h, d))
     k = jax.random.normal(jax.random.PRNGKey(21), (b, s, h, d))
@@ -257,9 +254,8 @@ def test_flash_decode_mha_head_count_branches(h):
 
 
 def test_flash_decode_mha_zero_length_row():
-    """A zero-length row sharing an 8-row MHA block with live rows (an
-    empty continuous-batching slot) must emit 0, exactly like the GQA
-    kernel whose per-row gate never runs such rows."""
+    """A zero-length batch row (an empty continuous-batching slot)
+    beside a live one must emit exact zeros."""
     q, k, v = (jax.random.normal(jax.random.PRNGKey(i), s) for i, s in
                enumerate([(2, 8, 16), (2, 64, 8, 16), (2, 64, 8, 16)]))
     length = jnp.asarray([0, 40], jnp.int32)

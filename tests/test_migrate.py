@@ -575,7 +575,11 @@ def test_remote_delta_migration_ships_suffix_only(tiny):
     prompt, budget = _prompt(), 24
     expect = _control(tiny, prompt, budget)
     http = _start_agent(tiny, prefix_cache_mb=2.0, fault_plan=_slow())
-    stub = _stub(http.address)
+    # heartbeats stay at 0.1 s (they ship the radix summary) but the
+    # lease is 5 s, not the helper's 0.3 s: nothing here tests lease
+    # expiry, and under a loaded multi-worker run a 0.3 s scheduling
+    # stall expired it, broke the remote and left the migration no taker
+    stub = _stub(http.address, lease_misses=50)
     # affinity off: it would route the live stream straight onto the
     # warm remote, and the point is to MIGRATE into it over the wire
     gw = Gateway([_mk(tiny, fault_plan=_slow()), stub],

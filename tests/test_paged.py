@@ -487,3 +487,39 @@ def test_kv_counters_block(tiny):
     c = srv.counters()
     # store retains the donated pages; live-slot tokens are gone
     assert c["kv_pages_reserved"] == 0
+
+
+@pytest.mark.parametrize("stats, expect_pages, source", [
+    # the CPU reports no memory stats: the capacity-parity floor
+    (None, 8, "capacity-parity floor"),
+    # a device with room: half of what is free, capped at 4x the floor
+    ({"bytes_limit": 1 << 30, "bytes_in_use": 0}, 32, "memory_stats"),
+    # a nearly full device: never below the floor
+    ({"bytes_limit": 1 << 20, "bytes_in_use": (1 << 20) - 8}, 8,
+     "memory_stats"),
+    # half of the free bytes, shared by the replicas that will coexist
+    ({"bytes_limit": 40 * 8192, "bytes_in_use": 0}, 10, "memory_stats"),
+])
+def test_auto_pool_is_sized_from_the_device_it_already_holds(
+        tiny, monkeypatch, capsys, stats, expect_pages, source):
+    """``--kv-pages 0`` asks the device this process already holds
+    (``memory_stats()``), not a second program, and says on stderr which
+    source sized the pool. tiny: max_seq_len 32 -> pages of 16 tokens,
+    8192 bytes each; batch 4 -> a floor of 8 pages."""
+    import argparse
+
+    from tony_tpu.cli.generate import resolve_paged_kv
+
+    class _Device:
+        def memory_stats(self):
+            return stats
+
+    model, _ = tiny
+    monkeypatch.setattr(jax, "local_devices", lambda: [_Device()])
+    args = argparse.Namespace(kv_pages=0, kv_page_size=0, no_paged_kv=False)
+    got = resolve_paged_kv(args, model, 4, n_replicas=2)
+    assert got == {"paged": True, "kv_page_size": 16,
+                   "kv_pages": expect_pages}
+    note = capsys.readouterr().err
+    assert f"kv pool: {expect_pages} pages x 16 tokens" in note
+    assert source in note

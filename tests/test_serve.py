@@ -47,6 +47,26 @@ def _solo_trimmed(model, params, prompt, n, eos_ids):
     return toks
 
 
+@pytest.fixture(scope="module")
+def eos_probe(tiny):
+    """(prompt, its 8 greedy tokens, eos id, index of eos) where the
+    eos id is FIRST emitted mid-sequence — so an engine stopping on it
+    strikes after real decoding. Which prompt has that property
+    depends on the seeded weights, hence on the installed jax's
+    initialisers: search seeded 6-token prompts instead of hard-coding
+    one (every candidate shares one compiled generate program)."""
+    model, params = tiny
+    rng = np.random.default_rng(0)
+    for _ in range(64):
+        prompt = rng.integers(1, 64, size=6).tolist()
+        solo = _solo(model, params, prompt, 8)
+        hit = next(((t, i) for i, t in enumerate(solo)
+                    if i > 0 and t not in solo[:i]), None)
+        if hit is not None:
+            return prompt, solo, hit[0], hit[1]
+    pytest.fail("no seeded prompt emits a new token mid-sequence")
+
+
 def test_mixed_length_batch_matches_solo(tiny):
     """Mixed-length prompts through 2 slots == per-prompt solo decodes,
     token for token (the continuous-batching correctness anchor)."""
@@ -64,16 +84,12 @@ def test_mixed_length_batch_matches_solo(tiny):
         assert results[i].prompt == p
 
 
-def test_slot_reuse_after_eos_exact(tiny):
+def test_slot_reuse_after_eos_exact(tiny, eos_probe):
     """A slot evicted on EOS and re-admitted with a new prompt produces
     token-for-token the same output as a solo generate() of that
     prompt — stale cache content must never leak into the new tenant."""
     model, params = tiny
-    probe = [17, 46, 10, 20, 62, 26]
-    solo = _solo(model, params, probe, 8)
-    # an id first emitted mid-sequence: EOS strikes after real decoding
-    eos, idx = next((t, i) for i, t in enumerate(solo)
-                    if i > 0 and t not in solo[:i])
+    probe, solo, eos, idx = eos_probe
     follower = [7, 2, 5, 11, 4]
     server = Server(model, params, batch_size=1, eos_id=eos, min_bucket=8)
     res = {r.id: r for r in server.run([
@@ -87,13 +103,11 @@ def test_slot_reuse_after_eos_exact(tiny):
                                                  6, (eos,))
 
 
-def test_chunk_size_does_not_change_results(tiny):
+def test_chunk_size_does_not_change_results(tiny, eos_probe):
     """chunk_steps only trades dispatches for latency: results are
     identical at 1 (token-at-a-time) and 8 (overshoot + trim)."""
     model, params = tiny
-    probe = [17, 46, 10, 20, 62, 26]
-    solo = _solo(model, params, probe, 8)
-    eos = next(t for i, t in enumerate(solo) if i > 0 and t not in solo[:i])
+    probe, _, eos, _ = eos_probe
     reqs = [Request(probe, max_new_tokens=8, id="a"),
             Request([5, 9], max_new_tokens=7, id="b"),
             Request([3, 3, 3, 3], max_new_tokens=5, id="c")]
@@ -115,7 +129,7 @@ def test_chunk_size_does_not_change_results(tiny):
     # overshoot-zero tests, and the paged cell compiles a superset of
     # the machinery (paged_view/write_back under freeze)
     [pytest.param(False, marks=pytest.mark.slow), True])
-def test_frozen_chunk_invariance_1_vs_16(tiny, paged):
+def test_frozen_chunk_invariance_1_vs_16(tiny, eos_probe, paged):
     """The ISSUE-13 chunk-invariance pin, extended to the frozen-slot
     variant: with in-dispatch EOS a chunk_steps=16 engine — deeper
     than every request's budget, so EVERY finishing slot freezes
@@ -124,10 +138,7 @@ def test_frozen_chunk_invariance_1_vs_16(tiny, paged):
     trim walk clean (freeze_faults == 0). Sampled co-tenants pin that
     frozen rows stop advancing rng without moving live draw chains."""
     model, params = tiny
-    probe = [17, 46, 10, 20, 62, 26]
-    solo = _solo(model, params, probe, 8)
-    eos = next(t for i, t in enumerate(solo)
-               if i > 0 and t not in solo[:i])
+    probe, _, eos, _ = eos_probe
     reqs = [Request(probe, max_new_tokens=8, id="a"),
             Request([5, 9], max_new_tokens=13, id="b"),
             Request([3, 3, 3, 3], max_new_tokens=5, id="c"),
@@ -185,17 +196,14 @@ def test_frozen_decode_scan_layers_int8(tiny):
     assert out[True] == out[False]
 
 
-def test_mid_chunk_eos_refill_parity(tiny):
+def test_mid_chunk_eos_refill_parity(tiny, eos_probe):
     """A slot that samples EOS mid-chunk freezes in-dispatch, is
     evicted by the trim walk, and its slot refills from the queue the
     same scheduler round — the waiting request's output must be
     token-exact vs a solo generate() (stale frozen re-emits must never
     leak into the next tenant), with zero wasted steps end to end."""
     model, params = tiny
-    probe = [17, 46, 10, 20, 62, 26]
-    solo = _solo(model, params, probe, 8)
-    eos, idx = next((t, i) for i, t in enumerate(solo)
-                    if i > 0 and t not in solo[:i])
+    probe, solo, eos, idx = eos_probe
     followers = [[7, 2, 5, 11, 4], [1, 6, 3], [44, 2, 9, 13]]
     server = Server(model, params, batch_size=2, eos_id=eos,
                     min_bucket=8, chunk_steps=8)

@@ -242,15 +242,25 @@ def test_mid_window_eos_trims_exactly():
     model = Transformer(cfg)
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, 8), jnp.int32))["params"]
-    prompt = [20, 30, 40, 50]
-    solo = _solo(model, params, prompt, 18)
-    first = {}
-    for i, t in enumerate(solo):
-        first.setdefault(t, i)
-    # the token appearing LATEST for the first time: speculation has
-    # been running (and transitioning phases) for many rounds by then
-    eos, idx = max(first.items(), key=lambda kv: kv[1])
-    assert idx >= 3, (solo, eos, idx)  # the premise of the test
+    # which prompt changes phase depends on the seeded weights, hence
+    # on the installed jax's initialisers: search seeded 4-token
+    # prompts (one compiled generate program serves them all) for the
+    # premise instead of hard-coding one
+    rng = np.random.default_rng(0)
+    for _ in range(64):
+        prompt = rng.integers(1, 64, size=4).tolist()
+        solo = _solo(model, params, prompt, 18)
+        first = {}
+        for i, t in enumerate(solo):
+            first.setdefault(t, i)
+        # the token appearing LATEST for the first time: speculation
+        # has been running (and transitioning phases) for many rounds
+        # by then — after a repeat the n-gram drafter could draft from
+        eos, idx = max(first.items(), key=lambda kv: kv[1])
+        if idx >= 3 and len(set(solo[:idx])) < idx:
+            break
+    else:
+        pytest.fail("no seeded prompt changes phase before a late EOS")
     off, ro = _run(model, params,
                    [Request(list(prompt), max_new_tokens=18, id="e")],
                    batch_size=1, chunk_steps=1, eos_id=eos)

@@ -163,7 +163,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--kv-pages", type=int, default=0,
                    help="KV page-pool size per replica; 0 auto-sizes "
                         "(the unpaged-equivalent footprint, grown "
-                        "into free TpuDiscoverer HBM on TPU — same "
+                        "into the HBM the device reports free — same "
                         "resolution style as --prefix-cache-mb)")
     p.add_argument("--no-paged-kv", action="store_true",
                    help="serve fixed-shape per-slot cache rows instead "
@@ -183,13 +183,15 @@ def build_parser() -> argparse.ArgumentParser:
                         "('tensor=4,expert=2'). Params shard on "
                         "output dims, KV page pools on the kv-head "
                         "axis; streams are byte-identical to a "
-                        "single-chip replica. '' = single-chip (the "
-                        "default); topology shows on /stats under "
-                        "engine.mesh")
+                        "single-chip replica on the CPU backend — on "
+                        "TPU chips the logits agree to rounding and "
+                        "the streams can diverge (PERF.md, PR 24). "
+                        "'' = single-chip (the default); topology "
+                        "shows on /stats under engine.mesh")
     p.add_argument("--shard-rules", default="serve",
                    help="parallel.sharding rule preset for --mesh "
-                        "(default 'serve' — the only preset with the "
-                        "token-exactness contract)")
+                        "(default 'serve' — the only preset that "
+                        "shards no contraction dim)")
     p.add_argument("--host", default="127.0.0.1")
     p.add_argument("--port", type=int, default=8000,
                    help="0 picks an ephemeral port")
@@ -445,10 +447,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "for a place to land; GET /debug/bundle "
                         "serves the same document on demand either "
                         "way")
-    p.add_argument("--compile-cache",
-                   default=os.path.join(os.path.expanduser("~"), ".cache",
-                                        "tony_tpu", "compile-cache"),
-                   help="persistent XLA compile-cache dir ('' disables)")
+    p.add_argument("--compile-cache", default=None,
+                   help="persistent XLA compile-cache dir; default: "
+                        "JAX_COMPILATION_CACHE_DIR when set, else "
+                        "<checkout>/.jax_compile_cache ('' disables)")
     return p
 
 
@@ -664,7 +666,7 @@ def agent_argv(args, index: int) -> list:
         argv.append("--demo-model")
     else:
         argv += ["--model", args.model]
-    if getattr(args, "compile_cache", ""):
+    if getattr(args, "compile_cache", None) is not None:
         argv += ["--compile-cache", args.compile_cache]
     return argv
 
@@ -926,7 +928,8 @@ def main(argv=None) -> int:
         parser.error("--remote-replica needs --model or --demo-model "
                      "to hand to the launched agents")
     logging.basicConfig(level=logging.INFO)
-    if args.compile_cache:
+    if args.compile_cache != "" and not remote:
+        # a pure router compiles nothing: the agents arm their own
         from tony_tpu.utils import compilecache
 
         compilecache.enable(args.compile_cache)
@@ -975,7 +978,18 @@ def main(argv=None) -> int:
             # tokenizer still serves token_ids requests
             print("note: no tokenizer in model dir; token_ids "
                   "requests only", file=sys.stderr)
+    return serve(args, model, params, eos, encode=encode, decode=decode)
 
+
+def serve(args, model, params, eos, *, encode=None, decode=None,
+          on_ready=None) -> int:
+    """Everything after the weights are in hand: build the fleet, open
+    the HTTP front end, print the address, then block until
+    SIGTERM/SIGINT and drain. Returns the process exit code. Must run
+    on the main thread (it installs the signal handlers).
+    ``on_ready(http)`` is called once the listener is up — how an
+    embedding caller (``chip_smoke.py`` serves a seeded random model
+    this way) learns the ephemeral port."""
     from tony_tpu.gateway import GatewayEdge, GatewayHTTP
     from tony_tpu.metrics import MetricsStore
 
@@ -1028,7 +1042,7 @@ def main(argv=None) -> int:
         elastic += ", rebalance on"
     n_rep = len(gateway.replicas)
     mode = ""
-    if remote:
+    if bool(args.agents.strip()) or args.remote_replica:
         mode = " remote agents: " + ", ".join(
             r.host for r in gateway.replicas)
     print(f"tony-tpu gateway at http://{http.host}:{http.port} "
@@ -1046,6 +1060,8 @@ def main(argv=None) -> int:
 
     signal.signal(signal.SIGTERM, _on_signal)
     signal.signal(signal.SIGINT, _on_signal)
+    if on_ready is not None:
+        on_ready(http)
     stop.wait()
     ok = gateway.drain(timeout=args.drain_timeout)
     http.stop()

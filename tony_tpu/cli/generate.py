@@ -34,7 +34,6 @@ wait on long ones — that is the point): ``{"id", "token_ids",
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 
 
@@ -127,12 +126,12 @@ def build_parser() -> argparse.ArgumentParser:
                    help="--serve mode: fixed-shape per-slot cache rows "
                         "instead of the paged pool (A/B escape hatch; "
                         "sliding-window models downgrade automatically)")
-    p.add_argument("--compile-cache",
-                   default=os.path.join(os.path.expanduser("~"), ".cache",
-                                        "tony_tpu", "compile-cache"),
+    p.add_argument("--compile-cache", default=None,
                    help="persistent XLA compile-cache dir; decode programs "
                         "compile once per (model, length) ever, not once "
-                        "per process ('' disables)")
+                        "per process. Default: JAX_COMPILATION_CACHE_DIR "
+                        "when set, else <checkout>/.jax_compile_cache "
+                        "('' disables)")
     return p
 
 
@@ -180,10 +179,14 @@ def resolve_paged_kv(args, model, batch_size: int,
     the engine refuses (sliding-window attention), and ``--kv-pages 0``
     auto-sizes the per-replica pool: the unpaged-equivalent footprint
     (``batch x ceil(max_seq_len / page_size)`` — capacity parity) as
-    the floor, grown toward half the free HBM TpuDiscoverer reports
-    SPLIT ACROSS the ``n_replicas`` pools that will coexist (capped at
-    4x the floor) when a TPU is present — the freed fixed-shape waste
-    is exactly what bigger batches grow into."""
+    the floor, grown toward half the HBM the device reports free
+    (``memory_stats()`` of the first local device — this process
+    already holds the chip and the weights, so no second program is
+    asked) SPLIT ACROSS the ``n_replicas`` pools that will coexist and
+    capped at 4x the floor — the freed fixed-shape waste is exactly
+    what bigger batches grow into. A backend without memory stats
+    (the CPU) keeps the floor. Which source sized the pool goes to
+    stderr."""
     if getattr(args, "no_paged_kv", False):
         return {"paged": False}
     if model.cfg.sliding_window:
@@ -197,21 +200,22 @@ def resolve_paged_kv(args, model, batch_size: int,
         or default_page_size(cfg)
     ps = max(1, min(ps, cfg.max_seq_len))
     pages = int(getattr(args, "kv_pages", 0) or 0)
+    source = "--kv-pages"
     if pages <= 0:
-        base = batch_size * (-(-cfg.max_seq_len // ps))
-        pages = base
-        try:
-            from tony_tpu.utils.tpu_info import TpuDiscoverer
+        import jax
 
-            info = TpuDiscoverer().get_device_information()
-            free = sum(c.hbm_total_bytes - c.hbm_used_bytes
-                       for c in info.chips)
-            if free > 0:
-                hbm_pages = int(free * 0.5 / max(1, n_replicas)) \
-                    // kv_page_nbytes(cfg, ps)
-                pages = max(base, min(4 * base, hbm_pages))
-        except Exception:  # noqa: BLE001 — no TPU / no tpu-info binary:
-            pass           # the capacity-parity floor is always safe
+        pages = base = batch_size * (-(-cfg.max_seq_len // ps))
+        source = "capacity-parity floor (device reports no memory stats)"
+        stats = jax.local_devices()[0].memory_stats()
+        if stats and stats.get("bytes_limit"):
+            free = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+            hbm_pages = int(free * 0.5 / max(1, n_replicas)) \
+                // kv_page_nbytes(cfg, ps)
+            pages = max(base, min(4 * base, hbm_pages))
+            source = (f"memory_stats: {free / 2**30:.2f} GiB free, "
+                      f"floor {base}, cap {4 * base}")
+    print(f"kv pool: {pages} pages x {ps} tokens per replica ({source})",
+          file=sys.stderr)
     return {"paged": True, "kv_page_size": ps, "kv_pages": pages}
 
 
@@ -340,7 +344,7 @@ def main(argv=None) -> int:
         print("need --prompt or --token-ids", file=sys.stderr)
         return 2
 
-    if args.compile_cache:
+    if args.compile_cache != "":
         from tony_tpu.utils import compilecache
 
         compilecache.enable(args.compile_cache)

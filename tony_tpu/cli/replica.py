@@ -97,10 +97,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "slots freeze into parked snapshots (0 "
                         "disables the watchdog; in-flight work runs "
                         "to completion and parks as results)")
-    p.add_argument("--compile-cache",
-                   default=os.path.join(os.path.expanduser("~"), ".cache",
-                                        "tony_tpu", "compile-cache"),
-                   help="persistent XLA compile-cache dir ('' disables)")
+    p.add_argument("--compile-cache", default=None,
+                   help="persistent XLA compile-cache dir; default: "
+                        "JAX_COMPILATION_CACHE_DIR when set, else "
+                        "<checkout>/.jax_compile_cache ('' disables)")
     return p
 
 
@@ -137,7 +137,7 @@ def build_server(args):
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     logging.basicConfig(level=logging.INFO)
-    if args.compile_cache:
+    if args.compile_cache != "":
         from tony_tpu.utils import compilecache
 
         compilecache.enable(args.compile_cache)

@@ -15,11 +15,7 @@ from __future__ import annotations
 import json
 import os
 import re
-
-try:
-    import tomllib
-except ModuleNotFoundError:  # python < 3.11: tomli IS tomllib upstream
-    import tomli as tomllib
+import tomllib
 from typing import Any, Iterable
 
 from tony_tpu.config import keys as K

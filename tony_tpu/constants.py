@@ -23,8 +23,8 @@ ATTEMPT_NUMBER = "TONY_ATTEMPT_NUMBER"  # coordinator retry attempt (ref: ATTEMP
 CHECKPOINT_DIR = "TONY_CHECKPOINT_DIR"  # resume: checkpoint root (no ref analog, SURVEY 5.4)
 RESUME_STEP = "TONY_RESUME_STEP"  # resume: newest step found at (re)launch
 JOB_DIR = "TONY_JOB_DIR"  # per-job working dir (staging, logs, events)
-COMPILE_CACHE_DIR = "TONY_COMPILE_CACHE_DIR"  # persistent XLA compile cache
-# (job-dir scoped: retry attempts reuse each other's compiles)
+COMPILE_CACHE_DIR = "TONY_COMPILE_CACHE_DIR"  # explicit compile-cache dir a
+# job asks for through tony.application.shell-env (utils/compilecache.py)
 AGENT_PID = "TONY_AGENT_PID"  # pid of the task agent (preemption-notice target)
 PREPROCESSING_JOB = "PREPROCESSING_JOB"  # "true" inside the preprocess task
 # (ref: Constants.PREPROCESSING_JOB :75)

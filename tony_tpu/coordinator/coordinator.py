@@ -545,10 +545,6 @@ class Coordinator:
             C.METRICS_PORT: str(self.metrics_rpc.port),
             "TONY_CONF_PATH": os.path.join(self.job_dir, C.TONY_FINAL_CONF),
             C.JOB_DIR: self.job_dir,
-            # every attempt of this job shares one compile cache, so a
-            # retried/resumed task skips its XLA compiles (VERDICT r2 #2;
-            # consumed by distributed.initialize via utils.compilecache)
-            C.COMPILE_CACHE_DIR: os.path.join(self.job_dir, "compile-cache"),
             "TONY_TASK_COMMAND": self._task_command(req),
         }
         mode = str(self.conf.get("tony.application.launch-mode", "local"))

@@ -43,9 +43,9 @@ def initialize(timeout_s: int | None = None) -> dict | None:
     from tony_tpu.profiler import maybe_start_server
     from tony_tpu.utils import compilecache
 
-    # before any compile: point XLA's persistent cache at the job-scoped
-    # dir so retries/resumes (and other gang members on this host) reuse
-    # compiled executables. No-op outside a job.
+    # before any compile: arm XLA's persistent cache (one directory for
+    # every entry point — utils/compilecache.py) so retries/resumes and
+    # other gang members on this host reuse compiled executables
     compilecache.enable()
 
     spec = env_spec()
@@ -55,21 +55,6 @@ def initialize(timeout_s: int | None = None) -> dict | None:
         return spec
     import jax
 
-    # CPU gangs (CI, the mini cluster, local smoke runs): the CPU
-    # backend's cross-process collectives need the gloo implementation
-    # selected BEFORE backend init, or every psum/allgather dies with
-    # "Multiprocess computations aren't implemented on the CPU
-    # backend". Newer jax defaults to gloo and may drop the knob — the
-    # update is best-effort. Read the platform from config/env, not
-    # jax.default_backend(), which would initialize the backend early.
-    platforms = str(jax.config.jax_platforms
-                    or os.environ.get("JAX_PLATFORMS", ""))
-    if platforms.split(",")[0] == "cpu":
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except Exception:
-            pass
     kwargs = {}
     if timeout_s is not None:
         kwargs["initialization_timeout"] = timeout_s

@@ -150,6 +150,12 @@ class _SlowClientAbort(ConnectionResetError):
     from an ordinary disconnect so the counters stay honest."""
 
 
+async def _discard(reader: asyncio.StreamReader) -> None:
+    """Read and drop until the peer closes (callers bound the wait)."""
+    while await reader.read(65536):
+        pass
+
+
 async def _read_request(reader: asyncio.StreamReader,
                         io_timeout_s: float):
     """Parse one HTTP/1.1 request head + Content-Length body.
@@ -359,6 +365,14 @@ class GatewayEdge:
                     503, {"error": "connection limit reached"},
                     extra={"Retry-After": "1"}))
                 await asyncio.wait_for(writer.drain(), timeout=1.0)
+                # lingering close: closing with the client's request
+                # still unread makes the kernel answer RST, which can
+                # destroy the 503 before the client reads it. Send FIN,
+                # then swallow what arrives until the client closes —
+                # bounded, and it holds a coroutine, never a worker
+                if writer.can_write_eof():
+                    writer.write_eof()
+                await asyncio.wait_for(_discard(reader), timeout=1.0)
             except (ConnectionError, asyncio.TimeoutError):
                 pass
             finally:

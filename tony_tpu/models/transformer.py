@@ -13,6 +13,7 @@ No reference analog (TonY has no model code); built TPU-first:
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any
 
@@ -61,7 +62,9 @@ class TransformerConfig:
     #     so the flash forward never re-runs) and only the MLP is
     #     rematted with dots kept. Fastest; costs the most HBM.
     remat_policy: str = "nothing"  # nothing | dots | attn_saved
-    mesh: Any = None  # required for the ring backend
+    # required for the ring and ulysses backends, and for the pallas
+    # backend on more than one device (its kernel rides a shard_map)
+    mesh: Any = None
     # architecture family knobs: the defaults are the Llama-style TPU
     # flagship (RMSNorm + RoPE + no biases + gelu); flipping them to
     # ("layer", "learned", True, "gelu_tanh") gives GPT-2 exactly —
@@ -153,7 +156,9 @@ class TransformerConfig:
     # on one chip in the single-chip order and all cross-chip ICI
     # traffic is pure data movement — the structural argument behind
     # the serving engine's mesh=1 == mesh=N byte-identical-streams
-    # contract. Training presets (dp/fsdp/tp) must leave this False:
+    # contract (which holds on the CPU backend; TPU chips round the
+    # narrower per-chip matmuls differently). Training presets
+    # (dp/fsdp/tp) must leave this False:
     # a replicate pin would all-gather batch-sharded activations.
     shard_activations: bool = False
 
@@ -223,15 +228,51 @@ def _attention(cfg: TransformerConfig, q, k, v, segment_ids=None):
                                  window=cfg.sliding_window,
                                  segment_ids=segment_ids)
     if cfg.attention_backend == "pallas":
-        from tony_tpu.ops.attention import flash_attention
-
-        return flash_attention(q, k, v, causal=True,
-                               block_q=cfg.attention_block_size,
-                               block_k=(cfg.attention_block_k
-                                        or cfg.attention_block_size),
-                               window=cfg.sliding_window,
-                               segment_ids=segment_ids)
+        return _pallas_attention(cfg, q, k, v, segment_ids)
     raise ValueError(f"unknown attention backend {cfg.attention_backend}")
+
+
+def _pallas_attention(cfg: TransformerConfig, q, k, v, segment_ids):
+    """The flash kernel, run per shard under ``cfg.mesh``. A pallas
+    call is opaque to GSPMD and the chip's compiler refuses to
+    partition one ("Mosaic kernels cannot be automatically
+    partitioned"), so on more than one device the model must be given
+    its mesh and the call rides a ``shard_map``. Attention is
+    independent per batch row and per head: the batch splits over the
+    data axes, the heads over the tensor axis (when it divides the kv
+    heads — contiguous chunks keep each q head beside its kv head), no
+    collective is needed, and the sequence stays whole (the ring and
+    ulysses backends are the sequence-parallel ones)."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from tony_tpu.ops.attention import flash_attention
+    from tony_tpu.parallel.mesh import TENSOR
+    from tony_tpu.parallel.sharding import _axis_size, batch_sharding
+
+    flash = functools.partial(
+        flash_attention, causal=True, block_q=cfg.attention_block_size,
+        block_k=cfg.attention_block_k or cfg.attention_block_size,
+        window=cfg.sliding_window)
+    mesh = cfg.mesh
+    batch = heads = None
+    if mesh is not None:
+        batch = batch_sharding(mesh).spec[0]
+        if q.shape[0] % _axis_size(mesh, batch):
+            batch = None  # e.g. init's one-row dummy: nothing to split
+        n_tensor = mesh.shape.get(TENSOR, 1)
+        if n_tensor > 1 and k.shape[2] % n_tensor == 0:
+            heads = TENSOR
+    if batch is None and heads is None:
+        return flash(q, k, v, segment_ids=segment_ids)
+    spec = P(batch, None, heads, None)
+    if segment_ids is None:
+        return shard_map(lambda q, k, v: flash(q, k, v), mesh=mesh,
+                         in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False)(q, k, v)
+    return shard_map(lambda q, k, v, s: flash(q, k, v, segment_ids=s),
+                     mesh=mesh, in_specs=(spec, spec, spec, P(batch, None)),
+                     out_specs=spec, check_vma=False)(q, k, v, segment_ids)
 
 
 class RMSNorm(nn.Module):
@@ -789,7 +830,7 @@ class QuantDense(nn.Module):
             from jax.sharding import PartitionSpec as P
 
             from tony_tpu.parallel.mesh import DATA, FSDP
-            from tony_tpu.utils.compat import shard_map
+            from jax import shard_map
 
             # manual over the WHOLE mesh (partial-manual shard_map needs
             # explicit-type meshes): batch rows ride the data/fsdp axes

@@ -88,24 +88,21 @@ def _discovered_chip_names() -> list:
     info-command subprocess, ``jax.devices()``), memoized per process:
     the chip does not change under a running process, and every
     ``Server`` construction — including the autoscaler's scale-up
-    path — resolves the roofline reference twice."""
+    path — resolves the roofline reference twice.
+
+    ``jax.devices()`` takes the chip, so only a process that computes
+    on it may come here: an engine (``serve.Server``) or the bench. A
+    router-only gateway, the submit client, the coordinator and the
+    agent build no cost model. A backend that fails to start raises."""
     global _DISCOVERED_NAMES
     if _DISCOVERED_NAMES is None:
-        names = []
-        try:
-            from tony_tpu.utils.tpu_info import TpuDiscoverer
+        import jax
 
-            names.append(TpuDiscoverer().get_device_information()
-                         .accelerator_type)
-        except Exception:  # noqa: BLE001 — discovery trouble: miss
-            pass
-        try:
-            import jax
+        from tony_tpu.utils.tpu_info import TpuDiscoverer
 
-            names.append(jax.devices()[0].device_kind)
-        except Exception:  # noqa: BLE001 — no jax / devices: miss
-            pass
-        _DISCOVERED_NAMES = names
+        _DISCOVERED_NAMES = [
+            TpuDiscoverer().get_device_information().accelerator_type,
+            jax.devices()[0].device_kind]
     return _DISCOVERED_NAMES
 
 

@@ -541,13 +541,10 @@ def _vmem(shape):
 
 
 def _compiler_params():
-    try:
-        from jax.experimental.pallas import tpu as pltpu
+    from jax.experimental.pallas import tpu as pltpu
 
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except Exception:
-        return None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _pick_block(limit: int, length: int) -> int:

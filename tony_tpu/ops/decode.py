@@ -4,13 +4,12 @@ The decode hot loop is HBM-bound (docs/PERF.md "Decode roofline"): every
 generated token re-reads the whole KV cache once. This kernel is the
 cache-side counterpart of the int8 weight path (ops/quant.py):
 
-- one grid step per (batch, kv_head, kv block): K/V tiles are DMA'd
-  HBM->VMEM once — sliced straight out of the cache's NATIVE
-  [B, S, KVH, D] layout by the BlockSpec index maps (the r13 relayout
-  fix: the old path materialized transposed copies of the FULL cache
-  before every call; the only relayout left is the GQA int8 path's
-  scale tensors, 4/D of the cache bytes, kept so each head instance
-  reads an exact per-head tile) — and consumed by an online-softmax
+- one grid step per (batch, kv-head block, kv block): K/V tiles are
+  DMA'd HBM->VMEM once — sliced straight out of the cache's NATIVE
+  [B, S, KVH, D] layout by the BlockSpec index maps as
+  [block_k, hb, D] tiles (no transposed copy of the cache is ever
+  materialized; only the int8 path's scale tensors, 4/D of the cache
+  bytes, are pre-transposed) — and consumed by an online-softmax
   accumulation held in VMEM scratch: no [S] score tensor round-trips
   to HBM, and the softmax/weighted-sum fuse into the tile pass (XLA's
   decode attention materializes scores + probabilities in HBM at
@@ -20,9 +19,10 @@ cache-side counterpart of the int8 weight path (ops/quant.py):
   cross HBM as int8 — HALF the cache traffic of bf16, the dominant
   decode bytes at long context — and dequantize in VMEM right before
   the MXU, exactly the ops/quant.py recipe for weights;
-- GQA: the q-head group [G, D] of each kv head rides one kernel
-  instance, so cache tiles are read ONCE per kv head (never repeated to
-  n_heads), preserving the GQA bandwidth saving end-to-end;
+- GQA: the q-head group [G, D] of each kv head contracts against that
+  head's tile inside the instance, so cache tiles are read ONCE per kv
+  head (never repeated to n_heads), preserving the GQA bandwidth
+  saving end-to-end;
 - cache positions at/after ``length`` (and behind the sliding window)
   are masked; blocks entirely outside [start, length) skip their FLOPs
   via ``@pl.when`` predication.
@@ -69,16 +69,27 @@ def _vmem(shape):
 
 def _decode_kernel(q_ref, k_ref, v_ref, len_ref, *rest,
                    block_k: int, scale: float, window: int,
-                   quant: bool):
-    """Grid (batch, kv_head, kv_block); K/V arrive in their NATIVE
-    [B, S, KVH, D] cache layout — the BlockSpec index maps slice one
-    head's [block_k, D] tile per instance (the r13 relayout fix: no
-    materialized cache-sized transpose). The int8 scales DO arrive
-    pre-transposed [B, KVH, S] (tiny — 4/D of the cache bytes): a
-    native-layout scale tile would carry ALL kvh lane columns and be
-    re-fetched once per head instance, a kvh-fold tax on the
-    hot-loop's HBM reads, where the transpose hands every instance an
-    exact (1, 1, block_k) per-head tile."""
+                   quant: bool, hb: int, group: int):
+    """Grid (batch, kv-head block, kv block). ``hb`` kv heads of one
+    batch row ride one instance: K/V arrive in their NATIVE
+    [B, S, KVH, D] cache layout as one [block_k, hb, D] DMA, and the
+    ``hb * group`` query rows of those heads (q heads are laid out
+    kv-head-major, so they are contiguous) fill the sublanes. All rows
+    share the batch, so ONE SMEM length serves the whole instance.
+    Per-kv-head score/value contractions are statically unrolled plain
+    2-D dots ([group, D] x [D, block_k]) — no batched dot_general, no
+    in-VMEM transpose, Mosaic-safe by construction.
+
+    Tile legality is the caller's job (``_head_block``): Mosaic wants
+    the last two block dims — here (hb, D) over (KVH, D) — to be the
+    full array dims or multiples of (8, 128). A block of ONE head over
+    a KVH > 1 cache is what the chip's compiler refuses, which is why
+    there is no per-head grid axis. int8 scales arrive pre-transposed
+    ``[B, KVH, S]`` as ``(1, hb, bk)`` tiles (sublane hb, lane bk —
+    legal by the same rule) and FOLD onto the score/probability rows
+    instead of dequantizing tiles: the per-(position, head) scale
+    distributes over the d-contraction, exactly the einsum path's
+    trick (models/transformer._decode_attention)."""
     if quant:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -99,116 +110,23 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, *rest,
     start = jnp.maximum(length - window, 0) if window > 0 else 0
 
     def _body():
-        q = q_ref[0, 0]        # [Gp, D]
-        k = k_ref[0, :, 0, :]  # [block_k, D] (int8 when quant)
-        v = v_ref[0, :, 0, :]
-        if quant:
-            kf = k.astype(jnp.float32) * ks_ref[0, 0][:, None]
-            vf = v.astype(jnp.float32) * vs_ref[0, 0][:, None]
-        else:
-            kf, vf = k, v
-        s = jax.lax.dot_general(
-            q, kf, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [Gp, block_k]
-        pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        visible = pos < length
-        if window > 0:
-            visible = visible & (pos >= start)
-        s = jnp.where(visible, s, NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_scr[:] = m_new
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p.astype(vf.dtype), vf, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    # skip FLOPs for blocks wholly past `length` or behind the window
-    # (their DMA is already issued by BlockSpec — static grid — so this
-    # saves compute, not traffic; the traffic win comes from int8 tiles)
-    in_range = ki * block_k < length
-    if window > 0:
-        in_range = in_range & (ki * block_k + block_k > start)
-
-    @pl.when(in_range)
-    def _run():
-        _body()
-
-    @pl.when(ki == nk - 1)
-    def _finalize():
-        l_safe = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-
-
-def _decode_kernel_mha(q_ref, k_ref, v_ref, len_ref, *rest,
-                       block_k: int, scale: float, window: int,
-                       quant: bool, hb: int):
-    """Head-blocked variant for MHA decode (group == 1).
-
-    The GQA kernel pads each kv head's single query row to 8 sublanes
-    and runs one grid instance per (batch x head) — at short cache
-    that is b*h tiny instances whose fixed cost (DMA setup, grid step)
-    beats the useful work, exactly where the XLA einsum used to win
-    (VERDICT r4 #1/#4: 0.89x at cache 512). Here ``hb`` HEADS of one
-    batch ride one instance (grid = (batch, kvh/hb, kv_block)): real
-    query rows fill the sublanes padding wasted, K/V tiles arrive in
-    their NATIVE [B, S, KVH, D] layout as one [block_k, hb, D] DMA
-    (the r13 relayout fix — no materialized transpose), and the
-    instance count drops hb-fold. All rows share the batch, so ONE
-    SMEM length serves the whole instance (the old flattened-row
-    variant assembled per-row length columns). Per-head score/value
-    contractions are statically unrolled plain 2-D dots — no batched
-    dot_general, no in-VMEM transpose, Mosaic-safe by construction.
-
-    Tile legality: ``hb`` is either the FULL kvh dim (kvh <= 8; a
-    full-dim block is always legal) or 8 (a sublane multiple) — the
-    caller falls back to the GQA kernel for any other head count (a
-    partial sublane tile only compiles in the CPU interpreter). int8
-    scales arrive pre-transposed ``[B, KVH, S]`` as ``(1, hb, bk)``
-    tiles (sublane hb, lane bk — legal by the same rule) and FOLD
-    onto the score/probability rows instead of dequantizing tiles:
-    the per-(position, head) scale distributes over the
-    d-contraction, exactly the einsum path's trick
-    (models/transformer._decode_attention)."""
-    if quant:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        o_ref, m_scr, l_scr, acc_scr = rest
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    length = len_ref[pl.program_id(0), 0]
-    start = jnp.maximum(length - window, 0) if window > 0 else 0
-
-    def _body():
-        q = q_ref[0].astype(jnp.float32)  # [hb, D]
+        q = q_ref[0].astype(jnp.float32)  # [hb * group, D]
         k = k_ref[0]                      # [block_k, hb, D]
         v = v_ref[0]
-        # statically unrolled per head (hb <= 8): each head's score is
-        # a plain [1, D] x [D, block_k] dot against its own K tile —
-        # same per-element reduction as the GQA kernel
         rows = []
         for hh in range(hb):
             s_h = jax.lax.dot_general(
-                q[hh:hh + 1, :], k[:, hh, :].astype(jnp.float32),
+                q[hh * group:(hh + 1) * group, :],
+                k[:, hh, :].astype(jnp.float32),
                 (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                preferred_element_type=jnp.float32)  # [group, block_k]
             if quant:
-                # fold the K scale onto the lane-major score row (it
+                # fold the K scale onto the lane-major score rows (it
                 # distributes over the d-contraction) — no
                 # sublane-major scale column is ever needed
                 s_h = s_h * ks_ref[0, hh:hh + 1, :]
             rows.append(s_h)
-        s = jnp.concatenate(rows, axis=0) * scale  # [hb, block_k]
+        s = jnp.concatenate(rows, axis=0) * scale  # [hb * group, block_k]
         pos = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
         visible = pos < length
@@ -223,7 +141,7 @@ def _decode_kernel_mha(q_ref, k_ref, v_ref, len_ref, *rest,
         m_scr[:] = m_new
         pv = []
         for hh in range(hb):
-            p_h = p[hh:hh + 1, :]
+            p_h = p[hh * group:(hh + 1) * group, :]
             if quant:
                 # likewise fold the V scale into the probabilities
                 p_h = p_h * vs_ref[0, hh:hh + 1, :]
@@ -233,6 +151,9 @@ def _decode_kernel_mha(q_ref, k_ref, v_ref, len_ref, *rest,
                 preferred_element_type=jnp.float32))
         acc_scr[:] = acc_scr[:] * corr + jnp.concatenate(pv, axis=0)
 
+    # skip FLOPs for blocks wholly past `length` or behind the window
+    # (their DMA is already issued by BlockSpec — static grid — so this
+    # saves compute, not traffic; the traffic win comes from int8 tiles)
     in_range = ki * block_k < length
     if window > 0:
         in_range = in_range & (ki * block_k + block_k > start)
@@ -250,6 +171,13 @@ def _decode_kernel_mha(q_ref, k_ref, v_ref, len_ref, *rest,
         valid = m_scr[:] > NEG_INF * 0.5
         o_ref[0] = jnp.where(valid, acc_scr[:] / l_safe,
                              0.0).astype(o_ref.dtype)
+
+
+def _head_block(kvh: int) -> int:
+    """kv heads per kernel instance: 8 (a sublane multiple) when it
+    divides ``kvh``, else the FULL kv-head dim (a full-dim block is
+    always tile-legal)."""
+    return 8 if kvh % 8 == 0 else kvh
 
 
 def _pick_block_k(limit: int, s: int) -> int:
@@ -283,7 +211,7 @@ def flash_decode(q, k, v, length, *, window: int = 0, block_k: int = 512,
       position ``length - 1``); positions >= length are masked. Lengths
       are PER-SLOT state: a serving batch may mix any lengths, and a
       length of 0 marks an EMPTY continuous-batching slot — its output
-      row is exact zeros (both kernels; see _finalize), never NaN, so
+      row is exact zeros (see _finalize), never NaN, so
       empty slots ride a live batch for free.
     window: sliding window (key visible iff 0 <= q_pos - k_pos < window).
     Returns [B, H, D] in q's dtype.
@@ -300,112 +228,45 @@ def flash_decode(q, k, v, length, *, window: int = 0, block_k: int = 512,
     if quant and (k_scale is None or v_scale is None):
         raise ValueError("int8 cache needs k_scale and v_scale")
     group = h // kvh
-    gp = -(-group // 8) * 8  # pad query rows to a legal sublane multiple
     scale = d ** -0.5
     if interpret is None:
         interpret = interpret_mode()
     bk = _pick_block_k(block_k, s)
+    hb = _head_block(kvh)
+    rows = hb * group  # query rows per instance
 
     from jax.experimental.pallas import tpu as pltpu
 
-    # K/V feed the kernels in their NATIVE [B, S, KVH, D] cache
-    # layout: the BlockSpec index maps slice per-(batch, head, block)
-    # tiles straight out of HBM — the r13 relayout fix (the old path
-    # materialized two transposed copies of the FULL cache per call,
-    # per layer, per token). Only the GQA path's int8 scales (4/D of
-    # the cache bytes) still pre-transpose — see that branch.
     len2 = jnp.broadcast_to(jnp.asarray(length, jnp.int32).reshape(-1, 1),
                             (b, 1))  # scalar length broadcasts per batch
-
-    if group == 1 and (kvh <= 8 or kvh % 8 == 0):
-        # MHA: hb heads of one batch per instance — real query rows
-        # fill the sublanes the GQA kernel pads, instances drop
-        # hb-fold, and one [block_k, hb, D] DMA feeds hb heads (the
-        # short-cache regime where per-instance cost dominated).
-        # hb is the FULL head dim (kvh <= 8: a full-dim block is
-        # always tile-legal) or 8 (a sublane multiple); other head
-        # counts (e.g. 12) fall through to the GQA kernel — their
-        # partial sublane tile only compiles in the CPU interpreter.
-        hb = kvh if kvh <= 8 else 8
-        kernel = functools.partial(
-            _decode_kernel_mha, block_k=bk, scale=scale, window=window,
-            quant=quant, hb=hb)
-        in_specs = [
-            pl.BlockSpec((1, hb, d), lambda bi, hi, ki: (bi, hi, 0)),
-            pl.BlockSpec((1, bk, hb, d),
-                         lambda bi, hi, ki: (bi, ki, hi, 0)),
-            pl.BlockSpec((1, bk, hb, d),
-                         lambda bi, hi, ki: (bi, ki, hi, 0)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ]
-        operands = [q, k, v, len2]
-        if quant:
-            # scales pre-transpose [B, S, KVH] -> [B, KVH, S] (tiny —
-            # 4/D of the cache bytes) so the tile is (1, hb, bk):
-            # sublane hb (full dim or 8), lane bk — Mosaic-legal at
-            # every head count this branch accepts. The kernel folds
-            # them onto scores/probabilities.
-            in_specs += [
-                pl.BlockSpec((1, hb, bk),
-                             lambda bi, hi, ki: (bi, hi, ki)),
-                pl.BlockSpec((1, hb, bk),
-                             lambda bi, hi, ki: (bi, hi, ki)),
-            ]
-            operands += [k_scale.transpose(0, 2, 1),
-                         v_scale.transpose(0, 2, 1)]
-        out = pl.pallas_call(
-            kernel,
-            out_shape=jax.ShapeDtypeStruct((b, kvh, d), q.dtype),
-            grid=(b, kvh // hb, s // bk),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, hb, d),
-                                   lambda bi, hi, ki: (bi, hi, 0)),
-            scratch_shapes=[_vmem((hb, 1)), _vmem((hb, 1)),
-                            _vmem((hb, d))],
-            interpret=interpret,
-        )(*operands)
-        return out  # [B, KVH, D] == [B, H, D] under MHA
-
-    # GQA — and the MHA head counts with no tile-legal head block
-    # (kvh > 8, kvh % 8 != 0): [B, H, D] -> [B, KVH, Gp, D] (a pure
-    # reshape + a tiny pad of the single-token q — no cache-sized
-    # relayout)
-    qr = q.reshape(b, kvh, group, d)
-    if gp != group:
-        qr = jnp.pad(qr, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
-
-    kernel = functools.partial(_decode_kernel, block_k=bk, scale=scale,
-                               window=window, quant=quant)
-    in_specs = [
-        pl.BlockSpec((1, 1, gp, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
-        pl.BlockSpec((1, bk, 1, d), lambda bi, hi, ki: (bi, ki, hi, 0)),
-        pl.BlockSpec((1, bk, 1, d), lambda bi, hi, ki: (bi, ki, hi, 0)),
-        pl.BlockSpec(memory_space=pltpu.SMEM),
-    ]
-    operands = [qr, k, v, len2]
+    kernel = functools.partial(
+        _decode_kernel, block_k=bk, scale=scale, window=window,
+        quant=quant, hb=hb, group=group)
+    # q heads are kv-head-major ([B, H, D] == [B, KVH, G, D] flattened),
+    # so the q rows of kv-head block hi are the contiguous rows
+    # [hi * rows, (hi + 1) * rows): q and the output need no reshape
+    q_spec = pl.BlockSpec((1, rows, d), lambda bi, hi, ki: (bi, hi, 0))
+    kv_spec = pl.BlockSpec((1, bk, hb, d),
+                           lambda bi, hi, ki: (bi, ki, hi, 0))
+    in_specs = [q_spec, kv_spec, kv_spec,
+                pl.BlockSpec(memory_space=pltpu.SMEM)]
+    operands = [q, k, v, len2]
     if quant:
-        # the ONE remaining relayout, scales only (tiny — 4/D of the
-        # cache bytes): [B, S, KVH] -> [B, KVH, S] hands each head
-        # instance an exact per-head (1, 1, bk) tile; native-layout
-        # scales would be re-fetched kvh times per block (see the
-        # kernel docstring). S in the lane dim also keeps the tile
-        # Mosaic-legal, the pre-r14 layout's argument.
-        ksr = k_scale.transpose(0, 2, 1)
-        vsr = v_scale.transpose(0, 2, 1)
-        in_specs += [
-            pl.BlockSpec((1, 1, bk), lambda bi, hi, ki: (bi, hi, ki)),
-            pl.BlockSpec((1, 1, bk), lambda bi, hi, ki: (bi, hi, ki)),
-        ]
-        operands += [ksr, vsr]
-    out = pl.pallas_call(
+        # the ONE relayout, scales only (tiny — 4/D of the cache
+        # bytes): [B, S, KVH] -> [B, KVH, S] so the tile is
+        # (1, hb, bk) — sublane hb, lane bk, Mosaic-legal wherever the
+        # K/V tile is. The kernel folds them onto scores/probabilities.
+        sc_spec = pl.BlockSpec((1, hb, bk), lambda bi, hi, ki: (bi, hi, ki))
+        in_specs += [sc_spec, sc_spec]
+        operands += [k_scale.transpose(0, 2, 1),
+                     v_scale.transpose(0, 2, 1)]
+    return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, gp, d), q.dtype),
-        grid=(b, kvh, s // bk),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        grid=(b, kvh // hb, s // bk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, gp, d),
-                               lambda bi, hi, ki: (bi, hi, 0, 0)),
-        scratch_shapes=[_vmem((gp, 1)), _vmem((gp, 1)), _vmem((gp, d))],
+        out_specs=q_spec,
+        scratch_shapes=[_vmem((rows, 1)), _vmem((rows, 1)),
+                        _vmem((rows, d))],
         interpret=interpret,
     )(*operands)
-    out = out[:, :, :group]
-    return out.reshape(b, h, d)

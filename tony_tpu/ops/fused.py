@@ -2,12 +2,14 @@
 
 HBM-bandwidth ops the XLA fuser usually handles; kept as pallas kernels
 both as the pattern reference for this repo and for the cases XLA splits
-(norm feeding multiple consumers). Interpreter fallback off-TPU.
+(norm feeding multiple consumers). ``interpret=None`` auto-selects:
+compiled on TPU, the pallas interpreter elsewhere.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -31,11 +33,12 @@ def _add_rmsnorm_kernel(x_ref, res_ref, scale_ref, o_ref, sum_ref, *, eps: float
                 ).astype(o_ref.dtype)
 
 
-def rmsnorm(x, scale, *, eps: float = 1e-6, block_rows: int = 256):
+def rmsnorm(x, scale, *, eps: float = 1e-6, block_rows: int = 256,
+            interpret: bool | None = None):
     """x: [..., D], scale: [D]."""
     shape = x.shape
     d = shape[-1]
-    rows = int(jnp.prod(jnp.array(shape[:-1]))) if len(shape) > 1 else 1
+    rows = math.prod(shape[:-1])
     x2 = x.reshape(rows, d)
     br = min(block_rows, rows)
     if rows % br:
@@ -49,17 +52,18 @@ def rmsnorm(x, scale, *, eps: float = 1e-6, block_rows: int = 256):
             pl.BlockSpec((d,), lambda i: (0,)),
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
-        interpret=_interp(),
+        interpret=_interp() if interpret is None else interpret,
     )(x2, scale)
     return out.reshape(shape)
 
 
-def add_rmsnorm(x, residual, scale, *, eps: float = 1e-6, block_rows: int = 256):
+def add_rmsnorm(x, residual, scale, *, eps: float = 1e-6,
+                block_rows: int = 256, interpret: bool | None = None):
     """Fused (x + residual) -> (normed, sum). Returns the residual stream sum
     too, as transformer blocks need it."""
     shape = x.shape
     d = shape[-1]
-    rows = int(jnp.prod(jnp.array(shape[:-1]))) if len(shape) > 1 else 1
+    rows = math.prod(shape[:-1])
     x2 = x.reshape(rows, d)
     r2 = residual.reshape(rows, d)
     br = min(block_rows, rows)
@@ -81,6 +85,6 @@ def add_rmsnorm(x, residual, scale, *, eps: float = 1e-6, block_rows: int = 256)
             pl.BlockSpec((br, d), lambda i: (i, 0)),
             pl.BlockSpec((br, d), lambda i: (i, 0)),
         ),
-        interpret=_interp(),
+        interpret=_interp() if interpret is None else interpret,
     )(x2, r2, scale)
     return normed.reshape(shape), summed.reshape(shape)

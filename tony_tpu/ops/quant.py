@@ -51,9 +51,9 @@ def _q8_matmul_kernel(x_ref, w_ref, s_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n",
-                                             "out_dtype"))
+                                             "out_dtype", "interpret"))
 def q8_matmul(x, w_q, scale, *, block_m: int = 128, block_n: int = 256,
-              out_dtype=None):
+              out_dtype=None, interpret: bool | None = None):
     """x: [m, k] float @ int8 weights [k, n] (+ scale [n]) -> [m, k]·W.
 
     Grid tiles (m, n); each block reads an int8 [k, bn] weight tile from
@@ -94,7 +94,7 @@ def q8_matmul(x, w_q, scale, *, block_m: int = 128, block_n: int = 256,
             pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        interpret=_interp(),
+        interpret=_interp() if interpret is None else interpret,
     )(x_in, w_q, scale.reshape(1, n))
     return out if m_pad == m else out[:m]
 

@@ -94,7 +94,7 @@ def _expert_ffn(params: dict, x: jnp.ndarray, cfg: "MoEConfig",
             # would all-gather the very weights EP exists to split)
             from jax.sharding import PartitionSpec as P
 
-            from tony_tpu.utils.compat import shard_map
+            from jax import shard_map
 
             ax = cfg.expert_axis
             w3, w2 = P(ax, None, None), P(ax, None)

@@ -97,7 +97,9 @@ RULES: dict[str, dict[str, Any]] = {
     #     contraction extents, identical order); all cross-chip ICI
     #     traffic is all-gather — pure data movement, bitwise. That is
     #     the structural argument behind the mesh=1 vs mesh=N
-    #     byte-identical-streams gate (tests/test_shard_serve.py).
+    #     byte-identical-streams gate (tests/test_shard_serve.py) —
+    #     a CPU-backend property: on v5e chips the streams diverged
+    #     (PR 24), the logits agreeing to rounding.
     "serve": {
         "batch": None,
         "heads": TENSOR, "kv_heads": TENSOR, "mlp": TENSOR,

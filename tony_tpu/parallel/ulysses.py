@@ -20,7 +20,7 @@ from __future__ import annotations
 import functools
 
 from jax import lax
-from tony_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from tony_tpu.parallel.mesh import SEQ

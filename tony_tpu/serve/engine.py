@@ -697,8 +697,12 @@ class Server:
     pools shard on the kv-head axis, and every dispatch runs GSPMD-
     partitioned with XLA-inserted ICI collectives — same dispatch
     count per token, no new host syncs, and byte-identical greedy +
-    seeded streams vs mesh=1 (all cross-chip traffic is all-gather;
-    every float reduction runs whole on one chip). Page tables and the
+    seeded streams vs mesh=1 on the CPU backend (all cross-chip
+    traffic is all-gather; every float reduction runs whole on one
+    chip). On TPU chips that identity was NOT seen (PR 24, v5e: the
+    narrower per-chip matmuls round differently; logits agree to
+    rounding, streams diverge at the first flipped argmax). Page
+    tables and the
     free-list allocator stay host-side and unchanged; prefix-store
     entries, CoW pages, handoff payloads and host-tier spills become
     sharded pytrees transparently. The goodput ledger prices sharded
@@ -756,7 +760,8 @@ class Server:
         # unchanged (a page id means the same thing on every chip);
         # dispatch counts per token are identical to single-chip — no
         # new host syncs. Greedy AND seeded streams are byte-identical
-        # to mesh=1 (tests/test_shard_serve.py pins the matrix).
+        # to mesh=1 on the CPU backend (tests/test_shard_serve.py pins
+        # the matrix); on TPU chips they agree to rounding only.
         self.mesh = mesh
         self.shard_rules = shard_rules
         self.kv_shards = 1

@@ -50,7 +50,7 @@ def main() -> None:
     from tony_tpu.profiler import op_totals_ms
     from tony_tpu.utils import compilecache
 
-    compilecache.enable(os.path.join(bench.REPO_DIR, ".jax_compile_cache"))
+    compilecache.enable()
     # the EXACT benchmarked step: config/trainer/env knobs live in
     # bench.flagship_lm_setup, shared with bench_transformer
     model, trainer, batch, accum, seq, _ = bench.flagship_lm_setup(True)
