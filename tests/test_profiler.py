@@ -192,7 +192,12 @@ class _FakeJaxProfiler:
         self.started = []
         self.stopped = 0
 
-    def start_trace(self, logdir):
+    class ProfileOptions:
+        python_tracer_level = 1
+
+    def start_trace(self, logdir, profiler_options=None):
+        # a serving capture runs without the Python call tracer
+        assert profiler_options.python_tracer_level == 0
         self.started.append(logdir)
 
     def stop_trace(self):
@@ -247,8 +252,8 @@ def test_serve_profiler_start_failure_degrades(tmp_path, monkeypatch):
 
     from tony_tpu.profiler import ServeProfiler
 
-    class _Broken:
-        def start_trace(self, logdir):
+    class _Broken(_FakeJaxProfiler):
+        def start_trace(self, logdir, profiler_options=None):
             raise RuntimeError("no backend")
 
     monkeypatch.setattr(jax, "profiler", _Broken())

@@ -135,6 +135,7 @@ from tony_tpu.gateway.admission import DEFAULT_TIER_WEIGHTS as _DEFAULT_WEIGHTS
 from tony_tpu.obs import Histogram, RequestTrace, TraceBuffer
 from tony_tpu.obs.alerts import AlertBus, default_rules
 from tony_tpu.obs.goodput import merge_ledgers
+from tony_tpu.obs.phases import HostPhases
 from tony_tpu.obs.timeline import DispatchTimeline
 from tony_tpu.serve import PoolExhausted, QueueFull, Request, Server
 
@@ -541,6 +542,10 @@ class _Replica:
         self._tickets: dict[int, Ticket] = {}  # engine id -> ticket
         self._next_id = 0
         self._tl_cursor = 0  # dispatch-timeline read position (tracing)
+        # this thread's host phase ledger (obs/phases.py) is the
+        # engine's: loop.* phases and the engine's own partition ONE
+        # wall clock, the scheduler thread's
+        self.phases = server.phases
         self._probe_first = False  # scale-up: earn admission via probe
         # orders the failure-claim against the breaker (ISSUE-20):
         # _fail_replica holds this across the ticket steal + failover
@@ -653,6 +658,7 @@ class _Replica:
             self._thread.join(timeout)
 
     def _loop(self) -> None:
+        phases = self.phases
         if self._probe_first:
             # scale-up path: prove the engine works (and pay its first
             # compiles) through a real probe generation before joining
@@ -666,10 +672,13 @@ class _Replica:
                 epoch = self.epoch
                 while not self.queue and not self._server_busy() \
                         and not self._stop and self.epoch == epoch:
-                    self.cv.wait(timeout=self.gateway._beat_interval_s)
+                    with phases.phase("loop.idle_wait"):
+                        self.cv.wait(
+                            timeout=self.gateway._beat_interval_s)
                     # beat WHILE idle too — an idle replica that only
                     # beat on work would look stalled to the watchdog
-                    self.gateway._beat(self)
+                    with phases.phase("loop.beat"):
+                        self.gateway._beat(self)
                 if self._stop and not self.queue \
                         and not self._server_busy():
                     self._exited = True
@@ -681,7 +690,8 @@ class _Replica:
                     self.gateway._unwatch(self)
                     return
                 stale = self.epoch != epoch
-            self.gateway._beat(self)
+            with phases.phase("loop.beat"):
+                self.gateway._beat(self)
             if stale:
                 # the watchdog (or a probe race) declared us failed
                 # while we were idle — clean up and re-earn admission
@@ -695,7 +705,8 @@ class _Replica:
                 # migrates the moment it reaches a live decode slot
                 self._migrate_out(epoch)
             try:
-                self._admit_from_queue(epoch)
+                with phases.phase("loop.admit_queue"):
+                    self._admit_from_queue(epoch)
                 with self.cv:
                     stale = self.epoch != epoch
                 # declared failed during admission: the engine holds
@@ -709,8 +720,11 @@ class _Replica:
                     if busy:
                         # one WORKING iteration: the on-demand serving
                         # profiler counts it (near-free attribute read
-                        # while no capture is armed)
-                        self.gateway.profiler.poll()
+                        # while no capture is armed; starting and
+                        # stopping one blocks this thread for seconds,
+                        # which the phase makes visible)
+                        with phases.phase("loop.profile"):
+                            self.gateway.profiler.poll()
                     now = time.monotonic()
                     # INSIDE the try: an exception in the delivery half
                     # (a metrics/history consumer, say) must take the
@@ -718,9 +732,12 @@ class _Replica:
                     # it would kill this thread with state still
                     # HEALTHY, a permanently-lost replica no probe can
                     # ever resurrect
-                    self._attach_dispatch_spans(epoch)
-                    self._stream_deltas(now, epoch)
-                    self._deliver(finished, now, epoch)
+                    with phases.phase("loop.spans"):
+                        self._attach_dispatch_spans(epoch)
+                    with phases.phase("loop.stream"):
+                        self._stream_deltas(now, epoch)
+                    with phases.phase("loop.deliver"):
+                        self._deliver(finished, now, epoch)
             except Exception as e:
                 # a failed replica must not strand its tickets with no
                 # terminal event — but unlike the old shed-everything
@@ -1321,6 +1338,15 @@ class _Replica:
         if include_dispatch and server is not None \
                 and server.timeline is not None:
             out["dispatch"] = server.timeline.summary()
+        # the scheduler thread's host phase ledger (obs/phases.py),
+        # beside the dispatch block it explains. ``host`` is taken in
+        # this row by the process sample (rss, HBM), hence the key; a
+        # remote replica's is its AGENT's ledger, as of the last obs
+        # pull (absent until one lands)
+        if include_dispatch and server is not None:
+            ledger = server.host_phases()
+            if ledger is not None:
+                out["host_phases"] = ledger
         return out
 
 
@@ -3745,14 +3771,22 @@ class Gateway:
         if replica_rows is not None:
             dispatch_blocks = [row["dispatch"] for row in replica_rows
                                if "dispatch" in row]
+            host_blocks = [row["host_phases"] for row in replica_rows
+                           if "host_phases" in row]
         else:
             dispatch_blocks = [s.timeline.summary() for s in servers
                                if s.timeline is not None]
+            host_blocks = [h for h in (s.host_phases() for s in servers)
+                           if h is not None]
         return {
             # fleet dispatch timeline: per-kind count / host-wall ms /
             # compile split / tokens, merged across replicas — the
             # /stats block ROADMAP 4's dispatch-overhead work reads
             "dispatch": DispatchTimeline.merge(dispatch_blocks),
+            # fleet host phase ledger: every scheduler thread's wall
+            # clock by named phase, summed — what the host did around
+            # the dispatches above (obs/phases.py)
+            "host": HostPhases.merge(host_blocks),
             "prefills": total("prefills"),
             "decode_steps": total("decode_steps"),
             "dispatches": total("dispatches"),

@@ -96,6 +96,12 @@ class _EdgeStats:
         self.client_disconnects = 0
         self.keepalives_sent = 0
         self.write_buffer_hwm = 0
+        # emit lag: from the replica thread handing a token event to
+        # this loop (call_soon_threadsafe) to the stream coroutine
+        # having written it — what the edge adds to every token gap
+        self.emit_lag_count = 0
+        self.emit_lag_ns = 0
+        self.emit_lag_max_ns = 0
         self.t_start = time.monotonic()
         # accepts/s over a short sliding window (deque of accept
         # timestamps would be O(rate); a two-sample rate is enough)
@@ -110,6 +116,13 @@ class _EdgeStats:
             self.accept_rate = ((self.accepts - self._rate_n)
                                 / (now - self._rate_t))
             self._rate_t, self._rate_n = now, self.accepts
+
+    def on_emitted(self, t_handoff_ns: int) -> None:
+        lag = time.perf_counter_ns() - t_handoff_ns
+        self.emit_lag_count += 1
+        self.emit_lag_ns += lag
+        if lag > self.emit_lag_max_ns:
+            self.emit_lag_max_ns = lag
 
     def snapshot(self) -> dict:
         now = time.monotonic()
@@ -133,6 +146,11 @@ class _EdgeStats:
             "client_disconnects": self.client_disconnects,
             "keepalives_sent": self.keepalives_sent,
             "write_buffer_hwm_bytes": self.write_buffer_hwm,
+            "emit_lag": {
+                "count": self.emit_lag_count,
+                "ms": round(self.emit_lag_ns / 1e6, 3),
+                "max_ms": round(self.emit_lag_max_ns / 1e6, 3),
+            },
             "uptime_s": round(now - self.t_start, 3),
         }
 
@@ -483,7 +501,9 @@ class GatewayEdge:
         # the per-request event queue: replica threads push via
         # call_soon_threadsafe, this coroutine pops. ``aborted`` is the
         # slow-client detach: once set, further events are dropped at
-        # the callback (no unbounded queue behind a dead reader).
+        # the callback (no unbounded queue behind a dead reader). Each
+        # event rides with the instant it was handed over, for
+        # ``edge.emit_lag``.
         q: asyncio.Queue = asyncio.Queue()
         aborted = threading.Event()
 
@@ -491,7 +511,8 @@ class GatewayEdge:
             if aborted.is_set():
                 return
             try:
-                loop.call_soon_threadsafe(q.put_nowait, event)
+                loop.call_soon_threadsafe(
+                    q.put_nowait, (time.perf_counter_ns(), event))
             except RuntimeError:
                 aborted.set()  # loop closed mid-shutdown
 
@@ -612,7 +633,7 @@ class GatewayEdge:
         no executor thread parked on ticket.result(), so ten thousand
         concurrent unary requests cost queue entries, not threads."""
         while True:
-            kind, *rest = await q.get()
+            _, (kind, *rest) = await q.get()
             if kind == "tokens":
                 continue  # unary: deltas accumulate server-side
             if kind == "done":
@@ -637,7 +658,7 @@ class GatewayEdge:
             while True:
                 try:
                     timeout = self.keepalive_s if headers_sent else None
-                    kind, *rest = await asyncio.wait_for(
+                    t_handoff, (kind, *rest) = await asyncio.wait_for(
                         q.get(), timeout=timeout)
                 except asyncio.TimeoutError:
                     st.keepalives_sent += 1
@@ -651,6 +672,7 @@ class GatewayEdge:
                         {"id": ticket.request.id,
                          "request_id": ticket.request.id,
                          "token_ids": rest[0]}))
+                    st.on_emitted(t_handoff)
                 elif kind == "done":
                     res, metrics = rest
                     if not headers_sent:

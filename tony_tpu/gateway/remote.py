@@ -99,6 +99,7 @@ import time
 from collections import deque
 from types import SimpleNamespace
 
+from tony_tpu.obs.phases import HostPhases
 from tony_tpu.obs.timeline import record_from_doc
 from tony_tpu.serve.agent import result_from_doc
 from tony_tpu.serve.engine import PoolExhausted, QueueFull, Request
@@ -497,6 +498,13 @@ class RemoteServer:
         self.obs_pull_errors = 0
         self._last_obs: float | None = None
         self._obs_goodput: dict | None = None
+        # the AGENT's host phase ledger as pulled (what /stats shows
+        # for this replica), and this stub thread's own: the replica
+        # loop books its loop.* phases into ``phases`` exactly as it
+        # does on a local engine, where they show in a capture of the
+        # gateway process
+        self._obs_host: dict | None = None
+        self.phases = HostPhases()
         self._clock_off_ms = 0.0
         self._clock_unc_ms = 0.0
         self._clock_samples = 0
@@ -690,9 +698,12 @@ class RemoteServer:
                                  summary=summary
                                  if isinstance(summary, dict) else {})
         goodput = doc.get("goodput")
+        host = doc.get("host")
         with self._stats_lock:
             if isinstance(goodput, dict):
                 self._obs_goodput = goodput
+            if isinstance(host, dict):
+                self._obs_host = host
             self.obs_pulls += 1
             self._last_obs = time.monotonic()
 
@@ -1150,6 +1161,13 @@ class RemoteServer:
         with self._stats_lock:
             g = self._obs_goodput
         return dict(g) if g is not None else None
+
+    def host_phases(self):
+        """The agent engine's host phase ledger, as of the last obs
+        pull (None until one lands, like ``goodput``)."""
+        with self._stats_lock:
+            h = self._obs_host
+        return dict(h) if h is not None else None
 
     def reset(self) -> None:
         """The breaker's recovery step, remote flavor: bump the epoch

@@ -11,6 +11,10 @@ layers, each consumable on its own:
   bucket / host-wall duration, compile split from steady state) — the
   ``/stats`` ``dispatches`` block and the sensor for dispatch-overhead
   work;
+- ``phases``: the scheduler thread's wall clock split into named host
+  phases (``/stats`` ``engine.host``), each also a ``tony.*`` span in
+  a ``jax.profiler`` capture, where ``profiler/xplane.idle_gaps``
+  names the device's idle gaps by them;
 - ``prom`` + ``export``: dependency-free Prometheus text exposition of
   the gateway's counters, gauges, and latency histograms
   (``GET /metrics``).
@@ -32,6 +36,7 @@ from tony_tpu.obs.export import prometheus_text
 from tony_tpu.obs.goodput import (CostModel, detect_hbm_gbps,
                                   detect_peak_flops, ledger,
                                   merge_ledgers)
+from tony_tpu.obs.phases import HostPhases
 from tony_tpu.obs.prom import (DEFAULT_TIME_BUCKETS_S, Histogram,
                                MetricFamily, escape_label_value, render)
 from tony_tpu.obs.timeline import DispatchRecord, DispatchTimeline
@@ -46,6 +51,7 @@ __all__ = [
     "DispatchRecord",
     "DispatchTimeline",
     "Histogram",
+    "HostPhases",
     "MetricFamily",
     "RequestTrace",
     "Rule",

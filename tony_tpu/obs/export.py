@@ -357,6 +357,15 @@ def prometheus_text(gateway) -> str:
         counter("tony_edge_keepalives_sent_total",
                 "Stream keepalive frames sent to quiet clients",
                 edge["keepalives_sent"])
+        lag = edge.get("emit_lag") or {}
+        counter("tony_edge_emit_lag_events_total",
+                "Token events written to streams (the count behind "
+                "tony_edge_emit_lag_seconds_total)",
+                lag.get("count", 0))
+        counter("tony_edge_emit_lag_seconds_total",
+                "Seconds between a replica thread handing a token "
+                "event to the edge loop and the stream having written "
+                "it, summed", round(lag.get("ms", 0.0) / 1e3, 6))
 
     # the queue block (ISSUE-9): the autoscaler's primary sensor,
     # scrapable standalone
@@ -629,6 +638,22 @@ def prometheus_text(gateway) -> str:
             "Analytic FLOPs estimate by kind (obs/goodput.py cost "
             "model)"),
     }
+    # the scheduler threads' host phase ledger (obs/phases.py): one
+    # family per quantity, a ``phase`` label per leaf; ``unnamed`` is
+    # what no phase covers, so the wall series sums to the thread's
+    # whole clock
+    host_phase = {
+        "tony_host_phase_count_total": MetricFamily(
+            "tony_host_phase_count_total", "counter",
+            "Times the scheduler thread entered each host phase"),
+        "tony_host_phase_seconds_total": MetricFamily(
+            "tony_host_phase_seconds_total", "counter",
+            "Scheduler-thread wall seconds by host phase"),
+        "tony_host_phase_cpu_seconds_total": MetricFamily(
+            "tony_host_phase_cpu_seconds_total", "counter",
+            "Scheduler-thread CPU seconds by host phase (wall minus "
+            "CPU is time off the CPU: blocked, or waiting for the GIL)"),
+    }
     # host gauges are PROCESS-level (replicas are threads of one
     # process, every /stats row carries the identical block): exported
     # UNLABELED, once — a replica label would make the idiomatic
@@ -693,6 +718,19 @@ def prometheus_text(gateway) -> str:
                 agg.get("est_bytes", 0), kl)
             disp["tony_dispatch_est_flops_total"].add(
                 agg.get("est_flops", 0), kl)
+        ledger = row.get("host_phases") or {}
+        for phase, agg in (ledger.get("phases") or {}).items():
+            pl = {**labels, "phase": phase}
+            host_phase["tony_host_phase_count_total"].add(
+                agg["count"], pl)
+            host_phase["tony_host_phase_seconds_total"].add(
+                round(agg["ms"] / 1e3, 6), pl)
+            host_phase["tony_host_phase_cpu_seconds_total"].add(
+                round(agg["cpu_ms"] / 1e3, 6), pl)
+        if ledger:
+            host_phase["tony_host_phase_seconds_total"].add(
+                round(ledger.get("unnamed_ms", 0.0) / 1e3, 6),
+                {**labels, "phase": "unnamed"})
     fams.extend(rep_counter.values())
     fams.extend(rep_gauge.values())
     fams.append(state_fam)
@@ -703,6 +741,7 @@ def prometheus_text(gateway) -> str:
         fams.extend(f for f in obs_gauge.values() if f.samples)
         fams.extend(f for f in obs_counter.values() if f.samples)
     fams.extend(disp.values())
+    fams.extend(host_phase.values())
     fams.extend([host_rss, host_hbm, host_util])
 
     for key, name, help_text in _HISTOGRAMS:

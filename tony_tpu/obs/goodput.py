@@ -110,9 +110,11 @@ def chip_lookup(table) -> float:
     """Resolve a per-chip constant from the accelerator name
     (``TPU_ACCELERATOR_TYPE`` env — read fresh, it is the cheap
     override — then ``TpuDiscoverer``'s accelerator type and the jax
-    device kind, both memoized per process). 0.0 when unknown — CPU
-    boxes and exotic chips must degrade to "no utilization estimate",
-    never to a wrong one."""
+    device kind, both memoized per process). Off the TPU it is 0.0,
+    "no utilization estimate" (``utilization: null``). ON a TPU whose
+    kind the table does not hold it raises: a roofline share against no
+    peak would read as a chip doing nothing, and the table is one line
+    to extend (``TONY_HBM_GBPS`` covers the bandwidth meanwhile)."""
     names = [os.environ.get("TPU_ACCELERATOR_TYPE", "")]
     names.extend(_discovered_chip_names())
     for name in names:
@@ -120,6 +122,12 @@ def chip_lookup(table) -> float:
         for key, val in table:
             if key in low:
                 return val
+    import jax
+
+    if jax.devices()[0].platform == "tpu":
+        raise LookupError(
+            f"no entry for this TPU ({names}) in obs/goodput.py's chip "
+            f"tables (keys: {[k for k, _ in table]})")
     return 0.0
 
 

@@ -266,7 +266,15 @@ class ServeProfiler:
             import jax
 
             os.makedirs(logdir, exist_ok=True)
-            jax.profiler.start_trace(logdir)
+            # without the Python call tracer: it hooks every call of
+            # every thread for the capture's length, and writing its
+            # events out held this scheduler thread in stop_trace for
+            # more than 18 s after a 6 s capture on the v5e's host
+            # (PERF.md, PR 27). The program's own ``tony.*`` spans and
+            # XLA's are TraceMe events, which the host tracer records.
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(logdir, profiler_options=options)
         except Exception as e:  # noqa: BLE001 — a broken
             # profiler must not take the serving loop with it
             log.exception("profile request ignored: start_trace failed")

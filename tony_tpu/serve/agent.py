@@ -84,8 +84,9 @@ behind ``gateway/remote.RemoteServer``):
                       ``seq > cursor`` still in the ring (wire form of
                       ``obs.timeline.DispatchRecord``, timestamps in
                       THIS process's monotonic clock), the lifetime
-                      per-kind timeline summary, and the goodput
-                      ledger — everything the gateway's obs-puller
+                      per-kind timeline summary, the goodput ledger
+                      and the stepper's host phase ledger (``host``,
+                      obs/phases.py) — everything the gateway's obs-puller
                       needs to make this host as observable as an
                       in-process replica. Pull-based and cursor-
                       incremental so a slow gateway costs the agent
@@ -586,7 +587,8 @@ class ReplicaAgent:
 
     def obs(self, cursor: int) -> dict:
         """GET /v1/obs payload: incremental timeline records past
-        ``cursor``, the lifetime summary, and the goodput ledger.
+        ``cursor``, the lifetime summary, the goodput ledger and the
+        host phase ledger.
         Degrades to an empty channel with the timeline off — an agent
         booted ``timeline=False`` is unobservable, not broken."""
         from tony_tpu.obs.timeline import record_doc
@@ -594,7 +596,8 @@ class ReplicaAgent:
         tl = self.server.timeline
         if tl is None:
             return {"cursor": 0, "records": [], "summary": {},
-                    "goodput": None, "epoch": self.epoch,
+                    "goodput": None, "host": self.server.host_phases(),
+                    "epoch": self.epoch,
                     "t_mono": time.monotonic()}
         new, new_cursor = tl.take_new(max(0, int(cursor)))
         return {
@@ -602,6 +605,7 @@ class ReplicaAgent:
             "records": [record_doc(r) for r in new],
             "summary": tl.summary(),
             "goodput": self.server.goodput(),
+            "host": self.server.host_phases(),
             "epoch": self.epoch,
             "t_mono": time.monotonic(),
         }
@@ -648,7 +652,8 @@ class ReplicaAgent:
                 cmds, self._cmds = self._cmds, []
                 busy = bool(self.server.n_active or self.server.n_pending)
                 if not cmds and (not busy or self.failed is not None):
-                    self._cond.wait(timeout=0.05)
+                    with self.server.phases.phase("loop.idle_wait"):
+                        self._cond.wait(timeout=0.05)
                     continue
             for kind, done in cmds:
                 if kind == "reset":
@@ -670,7 +675,8 @@ class ReplicaAgent:
                 # one WORKING iteration: the on-demand profile capture
                 # counts it (near-free attribute read while un-armed) —
                 # the agent-side twin of the gateway replica loop's poll
-                self.profiler.poll()
+                with self.server.phases.phase("loop.profile"):
+                    self.profiler.poll()
                 with self._cond:  # snapshot: submits mutate the dict
                     seen = {t.id: len(t.tokens)
                             for t in self._tickets.values()
@@ -692,7 +698,7 @@ class ReplicaAgent:
                     self._cond.notify_all()
                 continue
             now = time.monotonic()
-            with self._cond:
+            with self.server.phases.phase("loop.deliver"), self._cond:
                 for rid, new in progress.items():
                     t = self._tickets.get(rid)
                     # ``new`` is the TAIL past what we already hold

@@ -57,6 +57,7 @@ from tony_tpu.models.generate import (_is_eos, init_cache,
                                       single_decode_step)
 from tony_tpu.obs.goodput import (CostModel, detect_hbm_gbps,
                                   detect_peak_flops, ledger)
+from tony_tpu.obs.phases import HostPhases
 from tony_tpu.obs.timeline import DispatchRecord, DispatchTimeline
 from tony_tpu.serve.faults import FaultPlan
 from tony_tpu.serve.migrate import SessionSnapshot, StaleDelta, \
@@ -925,6 +926,13 @@ class Server:
         # cheap enough to stay on in production
         self.timeline = DispatchTimeline() if timeline else None
         self._compiled: set = set()  # (kind, shape-bucket) pairs seen
+        # host phase ledger (obs/phases.py): the stepping thread's wall
+        # clock split into named leaves (decode.prepare / enqueue /
+        # wait / emit / record, admit.host / wait / emit, ...), each
+        # also a ``tony.*`` annotation in a profiler capture. Owned by
+        # whoever drives step(); a gateway replica's loop books its own
+        # phases into the same ledger.
+        self.phases = HostPhases()
         # goodput attribution (obs/goodput.py): wall-clock anchor for
         # the ledger plus the analytic cost model that stamps
         # est_bytes/est_flops on every timeline record. The roofline
@@ -1129,6 +1137,11 @@ class Server:
         return ledger(self.timeline.summary(), wall_ms,
                       hbm_gbps=self.hbm_gbps,
                       peak_flops=self.peak_flops)
+
+    def host_phases(self) -> dict:
+        """The stepping thread's host phase ledger (``/stats``
+        ``host_phases`` per replica, ``engine.host`` merged)."""
+        return self.phases.snapshot()
 
     def mesh_info(self) -> dict | None:
         """Sharded-replica topology + per-chip residency (None on a
@@ -1391,7 +1404,9 @@ class Server:
                         self.prefix_hit_tokens += hit_tokens
                         self.prefill_tokens_saved += saved
                     if self.timeline is not None:
+                        self.phases.switch("prefill_chunk.wait")
                         jax.block_until_ready(row)  # close the record
+                        self.phases.switch("prefill_chunk.record")
                         tags = {"prompt_len": len(p), "chunk": 1}
                         if off:
                             tags["offset"] = int(off)
@@ -1431,7 +1446,9 @@ class Server:
             self.prefix_hits += 1
             self.prefix_hit_tokens += hit_tokens
             self.prefill_tokens_saved += saved
+        self.phases.switch("admit.wait")
         tok = int(tok)  # host sync: the admit dispatch is done here
+        self.phases.switch("admit.emit")
         if self.timeline is not None:
             tags = {"prompt_len": len(p)}
             if lookup_ms is not None:
@@ -1660,7 +1677,9 @@ class Server:
             self.prefix_hits += 1
             self.prefix_hit_tokens += hit_tokens
             self.prefill_tokens_saved += saved
+        self.phases.switch("admit.wait")
         tok = int(tok)  # host sync: the admit dispatch is done here
+        self.phases.switch("admit.emit")
         if self.timeline is not None:
             tags = {"prompt_len": len(p)}
             if lookup_ms is not None:
@@ -1724,19 +1743,22 @@ class Server:
         for slot in sorted(self._prefilling):
             st = self._prefilling[slot]
             remaining = len(st.request.prompt) - st.done
+            rid = st.request.id
             if remaining > self.prefill_chunk:
-                if self.paged:
-                    self._prefill_chunk_paged(slot, st)
-                else:
-                    self._prefill_chunk_unpaged(slot, st)
+                with self.phases.phase("prefill_chunk.host", rid=rid):
+                    if self.paged:
+                        self._prefill_chunk_paged(slot, st)
+                    else:
+                        self._prefill_chunk_unpaged(slot, st)
                 continue
             # final chunk: the fused suffix-prefill admit samples the
             # first token (or hands off) and un-parks the slot
             del self._prefilling[slot]
-            if self.paged:
-                self._finalize_prefill_paged(slot, st, finished)
-            else:
-                self._finalize_prefill_unpaged(slot, st, finished)
+            with self.phases.phase("admit.host", rid=rid):
+                if self.paged:
+                    self._finalize_prefill_paged(slot, st, finished)
+                else:
+                    self._finalize_prefill_unpaged(slot, st, finished)
 
     def _prefill_chunk_paged(self, slot: int, st: _PrefillState, *,
                              t0: float | None = None, occ: int = 0,
@@ -1773,7 +1795,9 @@ class Server:
         if self.timeline is not None:
             # close the record at a real sync: without it the chunk
             # would bill its device time to whatever syncs next
+            self.phases.switch("prefill_chunk.wait")
             jax.block_until_ready(cache)
+            self.phases.switch("prefill_chunk.record")
             tags = {"prompt_len": len(p), "chunk": st.chunks,
                     "view_tokens": view_tokens}
             if forked:
@@ -1804,7 +1828,9 @@ class Server:
         st.done += take
         st.chunks += 1
         if self.timeline is not None:
+            self.phases.switch("prefill_chunk.wait")
             jax.block_until_ready(row)
+            self.phases.switch("prefill_chunk.record")
             self._record_dispatch(
                 "prefill_chunk", t0, (time.monotonic() - t0) * 1e3,
                 occ, take, 0, ("prefill_chunk", take),
@@ -1852,7 +1878,9 @@ class Server:
         if self.prefix is not None:
             self.prefix.insert(p, pages=s.slot_pages(slot, len(p)),
                                logits=last)
+        self.phases.switch("admit.wait")
         tok = int(tok)
+        self.phases.switch("admit.emit")
         if self.timeline is not None:
             self._record_dispatch(
                 "prefill", t0, (time.monotonic() - t0) * 1e3, occ, lb,
@@ -1912,7 +1940,9 @@ class Server:
             self.prefix.insert(p, row, last)
         else:
             cache, tok, key = out
+        self.phases.switch("admit.wait")
         tok = int(tok)
+        self.phases.switch("admit.emit")
         if self.timeline is not None:
             self._record_dispatch(
                 "prefill", t0, (time.monotonic() - t0) * 1e3, occ, lb,
@@ -2094,7 +2124,9 @@ class Server:
             self.prefix.insert(p, pages=s.slot_pages(slot, n_tok),
                                logits=jnp.asarray(logits))
         self.handoffs_in += 1
+        self.phases.switch("admit.wait")
         tok = int(tok)
+        self.phases.switch("admit.emit")
         if self.timeline is not None:
             self._record_dispatch(
                 "handoff_admit", t0, (time.monotonic() - t0) * 1e3,
@@ -2157,7 +2189,9 @@ class Server:
         self.handoffs_in += 1
         self.migrate_bytes_avoided += \
             (n_alias - fork) * pool.page_nbytes
+        self.phases.switch("admit.wait")
         tok = int(tok)
+        self.phases.switch("admit.emit")
         if self.timeline is not None:
             self._record_dispatch(
                 "handoff_admit", t0, (time.monotonic() - t0) * 1e3,
@@ -2612,8 +2646,14 @@ class Server:
         pool's fine ``_mu`` — unless ``serialize_dispatch=True`` pins
         the old pool-wide single-writer discipline as the A/B
         control."""
-        with self._dispatch_lock:
+        with self.phases.rest("step.other"), self._dispatch_lock:
             return self._step_locked()
+
+    def _next_seq(self) -> int:
+        """The sequence number the next timeline record will take: the
+        id a dispatch's host phases carry, so that a span in a capture
+        leads to its ``DispatchRecord`` (0 with the timeline off)."""
+        return self.timeline.seq + 1 if self.timeline is not None else 0
 
     def _step_locked(self) -> list[Result]:
         if self.fault_plan is not None:
@@ -2624,7 +2664,9 @@ class Server:
                 if not self.pending:
                     break
                 req = self.pending.popleft()
-            if not self._admit_one(req, finished):
+            with self.phases.phase("admit.host", rid=req.id):
+                admitted = self._admit_one(req, finished)
+            if not admitted:
                 # paged pool cannot grant the reservation right now:
                 # requeue at the FRONT (FIFO order preserved) and stop
                 # admitting — live slots finishing will free pages
@@ -2645,9 +2687,21 @@ class Server:
         speculation on, a round where any slot drafts runs ONE verify
         dispatch (``_verify_round``); otherwise the plain chunk path."""
         if self.speculate_k > 0:
-            drafts = self._collect_drafts()
+            with self.phases.phase("verify.draft"):
+                drafts = self._collect_drafts()
             if drafts is not None:
-                return self._verify_round(drafts)
+                with self.phases.phase("verify.prepare",
+                                       seq=self._next_seq()):
+                    return self._verify_round(drafts)
+        with self.phases.phase("decode.prepare", seq=self._next_seq()):
+            return self._chunk_round()
+
+    def _chunk_round(self) -> list[Result]:
+        """The plain chunk path of ``_decode_round``, inside its open
+        ``decode.prepare`` leaf: the leaf is switched to ``enqueue``
+        (host-to-device transfers and the jit call), ``wait`` (the host
+        sync on the tokens), ``emit`` (the token walk) and ``record``
+        (the timeline record with its cost and goodput stamps)."""
         finished: list[Result] = []
         s = self.slots
         k = self._chunk_size()
@@ -2695,6 +2749,7 @@ class Server:
         # tree: enqueue ONE dispatch against the current version and
         # reassign — the host sync (np.asarray below) runs OUTSIDE the
         # lock, so co-located engines' device work overlaps
+        self.phases.switch("decode.enqueue")
         with self._tree_lock:
             cache, toks, rng = _decode_chunk(
                 self.model, self.params, s.cache,
@@ -2707,6 +2762,7 @@ class Server:
             s.cache = cache
         self.steps += k
         self.dispatches += 1
+        self.phases.switch("decode.wait")
         toks = np.asarray(toks)  # [b, k]
         # np.array, not asarray: device arrays view as read-only and the
         # next admit writes its slot's key in place
@@ -2716,6 +2772,7 @@ class Server:
             # latency a request actually experienced; tokens landed are
             # counted below once the EOS/budget walk trims overshoot
             dur_ms = (time.monotonic() - t0) * 1e3
+        self.phases.switch("decode.emit")
         landed = 0
 
         for slot in range(s.batch_size):
@@ -2772,6 +2829,7 @@ class Server:
                 self._donate(live, slot)
             self._live[slot] = None
             s.evict(slot)
+        self.phases.switch("decode.record")
         if self.timeline is not None:
             tags = {"requests": riders}
             if view_tokens:
@@ -2946,6 +3004,7 @@ class Server:
             occ = s.n_active
             riders = [lv.request.id for lv in self._live
                       if lv is not None]
+        self.phases.switch("verify.enqueue")
         with self._tree_lock:
             out = _verify_chunk(
                 self.model, self.params, s.cache, jnp.asarray(toks),
@@ -2956,6 +3015,7 @@ class Server:
                 table, window=window, n_steps=k_cont,
                 eos_ids=self.eos_ids if fused else ())
             s.cache = out[0]
+        self.phases.switch("verify.wait")
         if fused:
             _, emit, accepted, cont, rng = out
             cont = np.asarray(cont)
@@ -2970,6 +3030,7 @@ class Server:
         s.rng = np.array(rng, np.uint32)
         if self.timeline is not None:
             dur_ms = (time.monotonic() - t0) * 1e3  # closes at the sync
+        self.phases.switch("verify.emit")
         landed = 0
         cont_fed = 0  # live (non-frozen) continuation positions
 
@@ -3067,6 +3128,7 @@ class Server:
                 self._donate(live, slot)
             self._live[slot] = None
             s.evict(slot)
+        self.phases.switch("verify.record")
         if self.timeline is not None:
             drafted_n = int(draft_len.sum())
             accepted_n = int(accepted.sum())

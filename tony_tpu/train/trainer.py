@@ -204,7 +204,7 @@ class Trainer:
             # call is opaque to GSPMD — bare pjit would all-gather)
             param_specs = jax.tree.map(lambda s: s.spec, shardings.params)
 
-        def step_fn(state: TrainState, batch):
+        def train_step(state: TrainState, batch):
             # under the carry, forward/backward run through the bf16
             # copy the previous update emitted: per-(micro)batch grads
             # arrive in compute dtype (the one numerics change — one
@@ -245,7 +245,7 @@ class Trainer:
             metric_sh["grad_norm"] = NamedSharding(self.mesh, P())
         # b_sh is a pytree prefix: one sharding broadcast over the batch tree
         return jax.jit(
-            step_fn,
+            train_step,
             in_shardings=(shardings, b_sh),
             out_shardings=(shardings, metric_sh),
             donate_argnums=(0,) if self.donate else (),
