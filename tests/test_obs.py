@@ -642,6 +642,12 @@ def test_metrics_exposition_format_and_stats_consistency(tiny):
                 total += row[key]
             assert kv[rollup_key] == total, (key, kv)
         assert kv["used"] + kv["free"] == kv["total"]
+        # the KV tree block rides the same per-replica counts: every
+        # writer dispatch consumed the tree it was given
+        tree = snap["engine"]["kv_tree"]
+        assert tree["kept"] == 0 and tree["donated"] > 0
+        assert tree["donated"] == sum(row["kv_tree_donated"]
+                                      for row in snap["replicas"])
         # ISSUE-10: the goodput gauges carry the same ledger /stats
         # engine.goodput does. The ledger is TIME-dependent (idle
         # grows between two snapshots), so the exported values are

@@ -394,13 +394,18 @@ def test_slotcache_row_copy_isolated(tiny):
     row = init_cache(model, params, 1)
     row = jax.tree_util.tree_map(
         lambda x: jnp.full_like(x, 3) if x.ndim >= 3 else x, row)
-    before = jax.tree_util.tree_leaves(slots.cache)
+    held = jax.tree_util.tree_leaves(slots.cache)
+    # copied to numpy BEFORE the writer runs: it donates the tree it is
+    # given (np.asarray would be a view that pins the buffer on the CPU
+    # and so costs the writer its donation)
+    before = [np.array(leaf) for leaf in held]
     slots.admit(1, length=1, last_token=0, temperature=0.0, top_k=0,
                 rng_key=jax.random.PRNGKey(0), row_cache=row)
+    assert all(leaf.is_deleted() for leaf in held)
+    assert (slots.tree_donated, slots.tree_kept) == (1, 0)
     for old, new in zip(before, jax.tree_util.tree_leaves(slots.cache)):
         if new.ndim >= 4:  # KV buffers [b, S, kvh, dh]
-            np.testing.assert_array_equal(np.asarray(new[0]),
-                                          np.asarray(old[0]))
+            np.testing.assert_array_equal(np.asarray(new[0]), old[0])
             assert (np.asarray(new[1]) == 3).all()
 
 

@@ -116,6 +116,10 @@ def test_pools_stay_sharded_across_serving(tiny, mesh4):
             spec = tuple(leaf.sharding.spec)
             assert "tensor" in spec, (name, spec)
     assert found >= 4  # k + v per layer
+    # and every writer consumed the sharded tree it was given: the
+    # donation holds under the pools' NamedShardings too
+    c = s.counters()
+    assert c["kv_tree_kept"] == 0 and c["kv_tree_donated"] > 0, c
 
 
 def test_scan_layers_int8_kv_sharded_parity(tiny, mesh4):

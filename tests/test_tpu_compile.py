@@ -208,3 +208,57 @@ def test_rmsnorm_compiles_under_jit(tpu_compile, fn):
             functools.partial(fused.add_rmsnorm, interpret=False),
             x, x, scale)
     _assert_kernel(hlo)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_serving_step_writes_the_page_pool_in_place(topo, tpu_compile,
+                                                    program):
+    """The engine's decode chunk and paged prefill, compiled for the
+    chip over the serving cells' page pool (1,024 pages x 64 tokens x
+    8 kv heads x 128, bf16; one layer, a narrow MLP and vocabulary to
+    keep the compile short): the pool is donated, so the program holds
+    NO op that copies a whole pool leaf — before the donation every
+    step carried one ``copy(%cache__block_i__cached_key|value)`` per
+    layer and K/V, half its device time (PERF.md, PR 28) — and every
+    byte it returns aliases an argument."""
+    import re
+
+    from tony_tpu.models import Transformer, TransformerConfig
+    from tony_tpu.serve import engine
+    from tony_tpu.serve.slots import paged_cache
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    def A(shape, dtype=I32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    model = Transformer(TransformerConfig(
+        vocab_size=4096, d_model=1024, n_heads=8, n_kv_heads=8, n_layers=1,
+        d_ff=2048, max_seq_len=2048, dtype=BF16, norm="rms",
+        gated_mlp=True, tied_embeddings=False))
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), I32))["params"]))
+    pool = on_chip(jax.eval_shape(
+        lambda p: paged_cache(model, p, 1024, 64), params))
+    b, cols = 8, 8
+    if program == "decode":
+        lowered = engine._decode_chunk.lower(
+            model, params, pool, A((b,)), A((b,)), A((b,), F32), A((b,)),
+            A((b, 2), jnp.uint32), A((b,)), A((b, cols)), n_steps=2,
+            eos_ids=(2,), freeze=True)
+    else:
+        lowered = engine._paged_prefill_admit.lower(
+            model, params, pool, A((1, 128)), A((1, 128)), A(()),
+            A((1, cols)), A((), F32), A(()), A((2,), jnp.uint32))
+    compiled = lowered.compile()
+    whole_leaf = re.compile(r"= bf16\[1024,64,8,128\]\S* copy\(")
+    assert not [ln for ln in compiled.as_text().splitlines()
+                if whole_leaf.search(ln)]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * 1024 * 64 * 8 * 128 * 2

@@ -3896,4 +3896,12 @@ class Gateway:
                 "bytes_resident": total("kv_bytes_resident"),
                 "tokens_resident": total("kv_tokens_resident"),
             },
+            # writer dispatches by what became of the KV tree they
+            # were given (serve/slots.SlotCache.cache): every writer
+            # donates, so ``kept`` counts whole-tree copies on the
+            # device and must read 0 after warm-up
+            "kv_tree": {
+                "donated": total("kv_tree_donated"),
+                "kept": total("kv_tree_kept"),
+            },
         }

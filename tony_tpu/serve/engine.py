@@ -63,8 +63,9 @@ from tony_tpu.serve.faults import FaultPlan
 from tony_tpu.serve.migrate import SessionSnapshot, StaleDelta, \
     snapshot_from_doc
 from tony_tpu.serve.prefix import PrefixStore
-from tony_tpu.serve.slots import (PagePool, SlotCache, _gather_pages,
-                                  _read_slot, _scatter_pages,
+from tony_tpu.serve.slots import (PagePool, SlotCache, _copy_page,
+                                  _gather_pages, _read_slot,
+                                  _scatter_pages,
                                   cache_batch_axis, default_page_size,
                                   paged_view, paged_write_back)
 from tony_tpu.serve.tier import (HostPageTier, decode_array,
@@ -160,7 +161,8 @@ def _prefill(model, params, prompt, length, offset=None, row=None):
     return vars_["cache"], last[:, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("model", "with_row"))
+@functools.partial(jax.jit, static_argnames=("model", "with_row"),
+                   donate_argnames=("cache",))
 def _prefill_admit(model, params, cache, prompt, length, slot, temp,
                    top_k, key, offset=None, row=None, *, with_row=False):
     """The fused admit: prefill [1, Lb] (optionally a suffix seeded
@@ -171,7 +173,10 @@ def _prefill_admit(model, params, cache, prompt, length, slot, temp,
     proxy sizes). Compiles once per prefill bucket; slot / length /
     offset / sampling knobs are traced. ``with_row=True`` additionally
     returns the prefilled row and its last-position logits so the
-    engine can donate them to the prefix store."""
+    engine can donate them to the prefix store. ``cache`` is DONATED
+    (every program that returns the tree's successor does — the rule
+    is ``SlotCache.cache``'s); the carried ``row`` is not: a prefix
+    entry's row outlives this admit."""
     from tony_tpu.serve.slots import write_slot_row
 
     new_row, last = _prefill(model, params, prompt, length, offset, row)
@@ -198,7 +203,8 @@ def _sample_first(logits, temp, top_k, key):
     return tok[0].astype(jnp.int32), key[0]
 
 
-@functools.partial(jax.jit, static_argnames=("model",))
+@functools.partial(jax.jit, static_argnames=("model",),
+                   donate_argnames=("cache",))
 def _paged_prefill_admit(model, params, cache, window, positions, length,
                          table, temp, top_k, key):
     """The paged fused admit: a prefill is ONE multi-token per-slot
@@ -207,8 +213,10 @@ def _paged_prefill_admit(model, params, cache, window, positions, length,
     [1, Lb] its absolute positions (padding = -1, whose writes DROP —
     unlike the unpaged bucket, no junk is ever written past the
     prompt), ``table`` [1, max_pages] the slot's page table. K/V land
-    straight in the slot's pages (no separate row + slot-copy), the
-    last REAL position's logits feed the first-token sample. Returns
+    straight in the slot's pages (no separate row + slot-copy; the
+    pool is DONATED, so the scatter writes the caller's buffers in
+    place), the last REAL position's logits feed the first-token
+    sample. Returns
     ``(cache, token, rng, last_logits [1, V])`` — the logits go to the
     prefix store so the next exact hit skips everything."""
     cache, logits = multi_decode_step(model, params, cache, window,
@@ -221,7 +229,8 @@ def _paged_prefill_admit(model, params, cache, window, positions, length,
     return cache, tok[0].astype(jnp.int32), key[0], last
 
 
-@functools.partial(jax.jit, static_argnames=("model",))
+@functools.partial(jax.jit, static_argnames=("model",),
+                   donate_argnames=("cache",))
 def _paged_prefill_chunk(model, params, cache, window, positions, table):
     """One INTERMEDIATE chunk of a chunked prefill: a multi-token
     window written straight into the slot's pages at absolute
@@ -230,13 +239,19 @@ def _paged_prefill_chunk(model, params, cache, window, positions, table):
     sampling here would be junk work). Compiles once per chunk bucket
     x view span — and the chunk budget is quantized to the bucket
     grid, so in practice ONE chunk program serves a whole serving
-    session."""
+    session. Returns ``(cache, mark)``: ``mark`` is one element read
+    off a written pool leaf, there for the host to sync on — the tree
+    itself may be donated onward (by a co-located engine on a shared
+    pool) before the host gets to wait on it."""
     cache, _ = multi_decode_step(model, params, cache, window,
                                  positions, page_table=table)
-    return cache
+    leaf = next(x for path, x
+                in jax.tree_util.tree_flatten_with_path(cache)[0]
+                if cache_batch_axis(path, x) is not None)
+    return cache, leaf[(0,) * leaf.ndim]
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnames=("cache",))
 def _hit_admit(cache, row, slot, logits, temp, top_k, key):
     """Exact-prompt prefix hit: NO prefill at all — copy the stored row
     into ``slot`` and sample the first continuation from the stored
@@ -360,7 +375,8 @@ def _frozen_body(model, params, temps, top_ks, eos_ids: tuple):
 
 
 @functools.partial(jax.jit, static_argnames=("model", "n_steps",
-                                             "eos_ids", "freeze"))
+                                             "eos_ids", "freeze"),
+                   donate_argnames=("cache",))
 def _decode_chunk(model, params, cache, tok, positions, temps, top_ks,
                   rngs, rem=None, table=None, *, n_steps: int,
                   eos_ids: tuple = (), freeze: bool = False):
@@ -371,6 +387,12 @@ def _decode_chunk(model, params, cache, tok, positions, temps, top_ks,
     (cache, tokens [b, n_steps], rngs). ``n_steps`` is static (the
     scheduler quantizes it to powers of two, so at most
     log2(chunk_steps)+1 programs ever compile).
+
+    ``cache`` is DONATED: the returned tree is the caller's own
+    buffers with the chunk's K/V written IN PLACE (each leaf aliases
+    its output), so a step moves ``b x n_steps`` cache entries and not
+    a second copy of the whole tree — and the tree passed in is dead
+    the moment this is enqueued (``SlotCache.cache``).
 
     ``freeze`` (the ISSUE-13 in-dispatch EOS mode, the engine default)
     threads a per-slot ``done`` flag + remaining budget ``rem`` [b]
@@ -427,7 +449,8 @@ def _decode_chunk(model, params, cache, tok, positions, temps, top_ks,
 
 
 @functools.partial(jax.jit, static_argnames=("model", "window",
-                                             "n_steps", "eos_ids"))
+                                             "n_steps", "eos_ids"),
+                   donate_argnames=("cache",))
 def _verify_chunk(model, params, cache, toks, positions, draft_len,
                   temps, top_ks, rngs, rem=None, table=None, *,
                   window: int, n_steps: int = 0, eos_ids: tuple = ()):
@@ -875,6 +898,10 @@ class Server:
             if self.serialize_dispatch else threading.RLock()
         self._tree_lock = self.slots.pool.lock if shared \
             else self._dispatch_lock
+        # the pool tree this engine's pages and prefix entries live in
+        # (PagePool.tree_epoch; see _check_tree)
+        self._tree_epoch = self.slots.pool.tree_epoch if self.paged \
+            else 0
         cache_leaves = jax.tree_util.tree_leaves(self.slots.cache)
         self._kv_bytes_total = sum(
             int(np.prod(x.shape)) * x.dtype.itemsize for x in cache_leaves)
@@ -1096,6 +1123,16 @@ class Server:
                     "evicted prefix-store pages")
             self.host_tier = HostPageTier(int(kv_host_mb * (1 << 20)))
             self.prefix.on_evict = self._spill_entry
+        if self.paged and self.prefix is not None:
+            # compile the copy-on-write fork NOW (page 0 onto itself:
+            # an identity write): its first use is otherwise under
+            # whichever admission first matches a stored prefix
+            # mid-page — one chance first-token match among unrelated
+            # prompts is enough — and stalls every live stream for the
+            # compile (0.7 s on the v5e, PERF.md PR 28)
+            with self._tree_lock:
+                self.slots.cache = _copy_page(
+                    self.slots.cache, jnp.int32(0), jnp.int32(0))
 
     # ----------------------------------------------------- observability
 
@@ -1361,10 +1398,12 @@ class Server:
                 # its last-position logits — zero prefill work. (A
                 # LONGER entry can also match the full prompt, but its
                 # logits sit at the wrong position — partial path.)
-                cache, tok, key = _hit_admit(
-                    s.cache, entry.row, jnp.int32(slot), entry.logits,
-                    jnp.float32(req.temperature), jnp.int32(req.top_k),
-                    jax.random.PRNGKey(req.seed))
+                with self._tree_lock:
+                    s.cache, tok, key = _hit_admit(
+                        s.cache, entry.row, jnp.int32(slot),
+                        entry.logits, jnp.float32(req.temperature),
+                        jnp.int32(req.top_k),
+                        jax.random.PRNGKey(req.seed))
                 hit_tokens, saved = len(p), full_bucket
                 d_kind, d_bucket = "hit_admit", 0
             else:
@@ -1422,21 +1461,21 @@ class Server:
                 lb = bucket_len(len(suffix), max_len, self.min_bucket)
                 padded = np.zeros((1, lb), np.int32)
                 padded[0, :len(suffix)] = suffix
-                out = _prefill_admit(
-                    self.model, self.params, s.cache,
-                    jnp.asarray(padded), jnp.int32(len(suffix)),
-                    jnp.int32(slot), jnp.float32(req.temperature),
-                    jnp.int32(req.top_k), jax.random.PRNGKey(req.seed),
-                    jnp.int32(off) if self.prefix is not None else None,
-                    entry.row if entry is not None else None,
-                    with_row=self.prefix is not None)
+                with self._tree_lock:
+                    s.cache, tok, key, *row_last = _prefill_admit(
+                        self.model, self.params, s.cache,
+                        jnp.asarray(padded), jnp.int32(len(suffix)),
+                        jnp.int32(slot), jnp.float32(req.temperature),
+                        jnp.int32(req.top_k),
+                        jax.random.PRNGKey(req.seed),
+                        jnp.int32(off) if self.prefix is not None
+                        else None,
+                        entry.row if entry is not None else None,
+                        with_row=self.prefix is not None)
                 self.prefills += 1
                 d_bucket = lb
                 if self.prefix is not None:
-                    cache, tok, key, row, last = out
-                    self.prefix.insert(p, row, last)
-                else:
-                    cache, tok, key = out
+                    self.prefix.insert(p, *row_last)
                 if entry is not None:
                     hit_tokens, saved = off, full_bucket - lb
         finally:
@@ -1475,9 +1514,7 @@ class Server:
             finished.append(Result(req.id, list(req.prompt), [tok],
                                    reason, hit_tokens, saved,
                                    prefill_chunks=chunks))
-            s.cache = cache
             return True
-        s.cache = cache
         s.admit(slot, len(p), tok, req.temperature, req.top_k, key)
         self._spec_ema[slot] = 1.0  # new tenant: drafting re-enabled
         self._live[slot] = _Live(req, [tok], hit_tokens, saved,
@@ -1650,7 +1687,7 @@ class Server:
                 # shared) tree — enqueue only; the host sync below
                 # runs outside the lock
                 with self._tree_lock:
-                    cache, tok, key, last = _paged_prefill_admit(
+                    s.cache, tok, key, last = _paged_prefill_admit(
                         self.model, self.params, s.cache,
                         jnp.asarray(window), jnp.asarray(positions),
                         jnp.int32(len(suffix)),
@@ -1658,7 +1695,6 @@ class Server:
                         jnp.float32(req.temperature),
                         jnp.int32(req.top_k),
                         jax.random.PRNGKey(req.seed))
-                    s.cache = cache
                 self.prefills += 1
                 d_bucket = lb
                 if self.prefix is not None:
@@ -1783,11 +1819,10 @@ class Server:
                    s.max_pages)
         view_tokens = cols * ps
         with self._tree_lock:
-            cache = _paged_prefill_chunk(
+            s.cache, mark = _paged_prefill_chunk(
                 self.model, self.params, s.cache, jnp.asarray(window),
                 jnp.asarray(positions),
                 jnp.asarray(s.page_table[slot:slot + 1, :cols]))
-            s.cache = cache
         self.prefills += 1
         self.prefill_chunk_dispatches += 1
         st.done += take
@@ -1796,7 +1831,7 @@ class Server:
             # close the record at a real sync: without it the chunk
             # would bill its device time to whatever syncs next
             self.phases.switch("prefill_chunk.wait")
-            jax.block_until_ready(cache)
+            jax.block_until_ready(mark)
             self.phases.switch("prefill_chunk.record")
             tags = {"prompt_len": len(p), "chunk": st.chunks,
                     "view_tokens": view_tokens}
@@ -1864,13 +1899,12 @@ class Server:
         cols = min(_bucket_pow2(-(-len(p) // ps)), s.max_pages)
         view_tokens = cols * ps
         with self._tree_lock:
-            cache, tok, key, last = _paged_prefill_admit(
+            s.cache, tok, key, last = _paged_prefill_admit(
                 self.model, self.params, s.cache, jnp.asarray(window),
                 jnp.asarray(positions), jnp.int32(len(suffix)),
                 jnp.asarray(s.page_table[slot:slot + 1, :cols]),
                 jnp.float32(req.temperature), jnp.int32(req.top_k),
                 jax.random.PRNGKey(req.seed))
-            s.cache = cache
         self.prefills += 1
         self.prefill_chunk_dispatches += 1
         st.chunks += 1
@@ -1925,21 +1959,19 @@ class Server:
         lb = bucket_len(len(suffix), max_len, self.min_bucket)
         padded = np.zeros((1, lb), np.int32)
         padded[0, :len(suffix)] = suffix
-        out = _prefill_admit(
-            self.model, self.params, s.cache, jnp.asarray(padded),
-            jnp.int32(len(suffix)), jnp.int32(slot),
-            jnp.float32(req.temperature), jnp.int32(req.top_k),
-            jax.random.PRNGKey(req.seed), jnp.int32(off), st.row,
-            with_row=self.prefix is not None)
+        with self._tree_lock:
+            s.cache, tok, key, *row_last = _prefill_admit(
+                self.model, self.params, s.cache, jnp.asarray(padded),
+                jnp.int32(len(suffix)), jnp.int32(slot),
+                jnp.float32(req.temperature), jnp.int32(req.top_k),
+                jax.random.PRNGKey(req.seed), jnp.int32(off), st.row,
+                with_row=self.prefix is not None)
         self.prefills += 1
         self.prefill_chunk_dispatches += 1
         st.chunks += 1
         self.prefill_chunked += 1
         if self.prefix is not None:
-            cache, tok, key, row, last = out
-            self.prefix.insert(p, row, last)
-        else:
-            cache, tok, key = out
+            self.prefix.insert(p, *row_last)
         self.phases.switch("admit.wait")
         tok = int(tok)
         self.phases.switch("admit.emit")
@@ -1951,7 +1983,6 @@ class Server:
                       "offset": int(off)},
                 work=lb, fed=len(suffix),
                 est=self.cost.prefill(lb, off))
-        s.cache = cache
         if tok in self.eos_ids or req.max_new_tokens == 1:
             reason = "eos" if tok in self.eos_ids else "length"
             finished.append(Result(req.id, list(req.prompt), [tok],
@@ -1962,6 +1993,17 @@ class Server:
         self._spec_ema[slot] = 1.0
         self._live[slot] = _Live(req, [tok], st.hit_tokens, st.saved,
                                  prefill_chunks=st.chunks)
+
+    def _gather(self, idx: list):
+        """Enqueue the gather of pool pages ``idx`` (already padded to
+        their bucket) against the live tree. Reading the tree and
+        enqueueing happen in ONE tree-lock window: every writer
+        donates, so a reference read here and used after a co-located
+        engine's dispatch would name deleted arrays. The payload is
+        its own buffers and outlives any later writer."""
+        with self._tree_lock:
+            return _gather_pages(self.slots.cache,
+                                 jnp.asarray(idx, jnp.int32))
 
     # ------------------------------------------------ role-split handoff
 
@@ -2006,8 +2048,7 @@ class Server:
             return
         idx = _padded_pages(pages)
         n_pad = len(idx)
-        payload = _gather_pages(self.slots.cache,
-                                jnp.asarray(idx, jnp.int32))
+        payload = self._gather(idx)
         res.handoff = {"n_tokens": int(n_tok), "pages": payload,
                        "logits": jnp.asarray(logits)}
         finished.append(res)
@@ -2354,9 +2395,7 @@ class Server:
             n = -(-n_tok // pool.page_size)
             pages = [int(pg) for pg in s.page_table[slot, :n]]
             if wire:
-                idx = _padded_pages(pages)
-                payload = _gather_pages(self.slots.cache,
-                                        jnp.asarray(idx, jnp.int32))
+                payload = self._gather(_padded_pages(pages))
                 jax.block_until_ready(payload)
                 self.migrations_remote += 1
                 self.migrate_pages_moved += n
@@ -2550,12 +2589,13 @@ class Server:
         idx = _padded_pages(pages)
         n_pad = len(idx)
         t0 = time.monotonic()
-        payload = _gather_pages(self.slots.cache,
-                                jnp.asarray(idx, jnp.int32))
-        # DISPATCH only: the gather snapshots the pre-eviction cache
-        # value (cache buffers are never donated, so later page reuse
-        # cannot touch it), and the device->host sync runs on the
-        # tier's copy thread — decode rounds proceed during the spill
+        # DISPATCH only: the gather is enqueued against the
+        # pre-eviction tree, and the runtime orders every later
+        # writer's donation after it, so page reuse cannot touch what
+        # it reads; the payload is buffers of its own, and the
+        # device->host sync runs on the tier's copy thread — decode
+        # rounds proceed during the spill
+        payload = self._gather(idx)
         tier.spill_async(tokens, payload, n, entry.logits)
         if self.timeline is not None:
             self._record_dispatch(
@@ -2658,6 +2698,7 @@ class Server:
     def _step_locked(self) -> list[Result]:
         if self.fault_plan is not None:
             self.fault_plan.on_dispatch()
+        self._check_tree()
         finished: list[Result] = []
         while self._free_slots():
             with self._pending_lock:
@@ -2751,7 +2792,7 @@ class Server:
         # lock, so co-located engines' device work overlaps
         self.phases.switch("decode.enqueue")
         with self._tree_lock:
-            cache, toks, rng = _decode_chunk(
+            s.cache, toks, rng = _decode_chunk(
                 self.model, self.params, s.cache,
                 jnp.asarray(s.last_token), jnp.asarray(s.positions()),
                 jnp.asarray(s.temperature), jnp.asarray(s.top_k),
@@ -2759,7 +2800,6 @@ class Server:
                 jnp.asarray(rem) if rem is not None else None, table,
                 n_steps=k, eos_ids=self.eos_ids if freeze else (),
                 freeze=freeze)
-            s.cache = cache
         self.steps += k
         self.dispatches += 1
         self.phases.switch("decode.wait")
@@ -3006,7 +3046,7 @@ class Server:
                       if lv is not None]
         self.phases.switch("verify.enqueue")
         with self._tree_lock:
-            out = _verify_chunk(
+            s.cache, emit, accepted, *cont, rng = _verify_chunk(
                 self.model, self.params, s.cache, jnp.asarray(toks),
                 jnp.asarray(positions), jnp.asarray(draft_len),
                 jnp.asarray(s.temperature), jnp.asarray(s.top_k),
@@ -3014,14 +3054,8 @@ class Server:
                 jnp.asarray(rem) if fused else None,
                 table, window=window, n_steps=k_cont,
                 eos_ids=self.eos_ids if fused else ())
-            s.cache = out[0]
         self.phases.switch("verify.wait")
-        if fused:
-            _, emit, accepted, cont, rng = out
-            cont = np.asarray(cont)
-        else:
-            _, emit, accepted, rng = out
-            cont = None
+        cont = np.asarray(cont[0]) if fused else None
         self.steps += window + k_cont
         self.dispatches += 1
         self.spec_rounds += 1
@@ -3180,7 +3214,8 @@ class Server:
             return
         if not self.prefix.wants(seq, self._row_nbytes):
             return
-        row = _read_slot(self.slots.cache, jnp.int32(slot))
+        with self._tree_lock:
+            row = _read_slot(self.slots.cache, jnp.int32(slot))
         self.prefix.insert(seq, row)
 
     def drain(self) -> list[Result]:
@@ -3195,6 +3230,7 @@ class Server:
             # lock PER ITERATION: on a shared pool, co-tenant engines
             # keep stepping between this engine's drain rounds
             with self._dispatch_lock:
+                self._check_tree()
                 self._advance_prefills(finished)
                 if self.slots.n_active:
                     finished.extend(self._decode_round())
@@ -3230,6 +3266,11 @@ class Server:
             # (must stay 0)
             "frozen_steps": self.frozen_steps,
             "freeze_faults": self.freeze_faults,
+            # writer dispatches that consumed the KV tree they were
+            # given / left it alive (SlotCache.cache): kept must stay
+            # 0 — each one is a whole-tree copy on the device
+            "kv_tree_donated": self.slots.tree_donated,
+            "kv_tree_kept": self.slots.tree_kept,
             "spec_rounds": self.spec_rounds,
             "spec_drafted": self.spec_drafted,
             "spec_accepted": self.spec_accepted,
@@ -3302,11 +3343,22 @@ class Server:
 
     def reset(self) -> None:
         """Hard reset after a failed ``step()``: drop pending and
-        in-flight bookkeeping and free every slot (pure host work — the
-        next admit overwrites device rows). Dropped requests never get
-        a Result; the caller sheds them. ``slots.reset()`` alone leaves
-        the engine inconsistent (``_live`` ghosts would decode garbage
-        and emit phantom results), so external callers use this."""
+        in-flight bookkeeping and free every slot. Dropped requests
+        never get a Result; the caller sheds them. ``slots.reset()``
+        alone leaves the engine inconsistent (``_live`` ghosts would
+        decode garbage and emit phantom results), so external callers
+        use this.
+
+        While the device tree is alive this is pure host work (the
+        next admit overwrites device rows): a fault that
+        ``serve/faults.py`` injects, or any exception raised on the
+        host side of a dispatch, leaves it so. A donating program that
+        fails at RUN time may take the tree with it; then a zeroed one
+        is allocated here and what pointed into the old one goes — the
+        prefix store's by-reference page entries (unpaged entries are
+        rows of their own and stay, as does the host tier). On a
+        shared pool the co-located engines learn of it by the pool's
+        ``tree_epoch`` (``_check_tree``)."""
         with self._dispatch_lock:
             with self._pending_lock:
                 self.pending.clear()
@@ -3320,6 +3372,27 @@ class Server:
             for rid in list(self._migrate_pins):
                 self._release_migrate_pin(rid)
             self.slots.reset()
+            with self._tree_lock:
+                if self.slots.renew_tree():
+                    log.warning("the KV tree was consumed by a failed "
+                                "dispatch: allocated a fresh one")
+            if self.paged and self._tree_epoch \
+                    != self.slots.pool.tree_epoch:
+                self._tree_epoch = self.slots.pool.tree_epoch
+                if self.prefix is not None:
+                    self.prefix.clear()  # pages of a tree that is gone
+
+    def _check_tree(self) -> None:
+        """Refuse to step over page content that is gone: a co-located
+        engine's failed dispatch consumed the shared pool's tree and
+        its ``reset()`` allocated the next one. Raising hands this
+        engine's sessions to the caller's recovery, whose ``reset()``
+        catches this engine up."""
+        if self.paged and self._tree_epoch != self.slots.pool.tree_epoch:
+            raise RuntimeError(
+                "the shared KV pool's tree was lost to a co-located "
+                "engine's failed dispatch; this engine's pages hold "
+                "nothing — reset() it")
 
     def run(self, requests: Iterable[Request] = ()) -> Iterator[Result]:
         """Submit ``requests`` and drive the loop until everything

@@ -325,6 +325,19 @@ class PrefixStore:
             self._evict(victim)
             return True
 
+    def clear(self) -> None:
+        """Drop every entry WITHOUT the eviction hook: for a store
+        whose pool lost its device tree, where a spill would copy
+        zeros into the host tier under real tokens. Page pins go back
+        to the pool as in any eviction."""
+        with self._lock:
+            hook, self.on_evict = self.on_evict, None
+            try:
+                for entry in list(self._entries.values()):
+                    self._evict(entry)
+            finally:
+                self.on_evict = hook
+
     def _insert_node(self, tokens: np.ndarray) -> _Node:
         node, consumed = self.root, 0
         while consumed < len(tokens):

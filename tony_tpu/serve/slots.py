@@ -13,6 +13,7 @@ next admit, so no device work is ever spent clearing it).
 
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Any
 
@@ -57,10 +58,11 @@ def write_slot_row(cache: Any, row: Any, slot) -> Any:
     return jax.tree_util.tree_map_with_path(write, cache, row)
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnames=("cache",))
 def _write_slot(cache: Any, row: Any, slot) -> Any:
     """Jitted ``write_slot_row``; ``slot`` is traced — every admit
-    reuses one compiled program."""
+    reuses one compiled program. ``cache`` is DONATED (the writers'
+    rule, see ``SlotCache.cache``): the row lands in place."""
     return write_slot_row(cache, row, slot)
 
 
@@ -174,6 +176,12 @@ def kv_page_nbytes(cfg, page_size: int) -> int:
     return cfg.n_layers * per
 
 
+def tree_consumed(cache: Any) -> bool:
+    """Whether a donating program took ``cache``'s buffers: donation
+    deletes every leaf of the tree at once, so the first one tells."""
+    return jax.tree_util.tree_leaves(cache)[0].is_deleted()
+
+
 def page_nbytes(cache: Any) -> int:
     """Bytes ONE page occupies across a paged cache tree's pool leaves
     (all layers; scales included) — the unit the allocator's stats and
@@ -207,8 +215,10 @@ def copy_page(cache: Any, src, dst) -> Any:
     return jax.tree_util.tree_map_with_path(cp, cache)
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnames=("cache",))
 def _copy_page(cache: Any, src, dst) -> Any:
+    """Jitted ``copy_page``; ``cache`` is DONATED — one page moves,
+    the pool stays where it is."""
     return copy_page(cache, src, dst)
 
 
@@ -251,18 +261,20 @@ def scatter_pages(cache: Any, payload: Any, idx) -> Any:
         ax = cache_batch_axis(path, leaf)
         if ax is None:
             return leaf  # dest counters win; payload's ride-alongs drop
-        p2 = jnp.moveaxis(leaf, ax, 0)
-        v2 = jnp.moveaxis(jnp.asarray(pleaf).astype(leaf.dtype), ax, 0)
-        p2 = p2.at[idx].set(v2, mode="drop")
-        return jnp.moveaxis(p2, 0, ax)
+        # indexed on the page axis where it lies (scan_layers' stacked
+        # leaves carry a layers axis before it): moving the axis to the
+        # front and back costs a transposed copy of the whole leaf
+        return leaf.at[(slice(None),) * ax + (idx,)].set(
+            jnp.asarray(pleaf).astype(leaf.dtype), mode="drop")
 
     return jax.tree_util.tree_map_with_path(sc, cache, payload)
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnames=("cache",))
 def _scatter_pages(cache: Any, payload: Any, idx) -> Any:
     """Jitted ``scatter_pages``; ``idx`` traced — one program per
-    page-count bucket."""
+    page-count bucket. ``cache`` is DONATED (the payload is not: a
+    handoff doc or a host-tier row outlives the scatter)."""
     return scatter_pages(cache, payload, idx)
 
 
@@ -331,11 +343,9 @@ def paged_write_back(pool: Any, view: Any, table, start, n_steps: int,
         page = jnp.take_along_axis(table, safe // ps, axis=1)
         page = jnp.where(valid, page, n_pg)  # drop via OOB
         off = safe % ps
-        v2 = jnp.moveaxis(vleaf, (ax, ax + 1), (0, 1))
-        vals = v2[rows, safe]                # [b, n_steps, ..rest]
-        p2 = jnp.moveaxis(pleaf, (ax, ax + 1), (0, 1))
-        p2 = p2.at[page, off].set(vals, mode="drop")
-        return jnp.moveaxis(p2, (0, 1), (ax, ax + 1))
+        lead = (slice(None),) * ax           # scan_layers' layers axis
+        vals = vleaf[lead + (rows, safe)]    # [.., b, n_steps, ..rest]
+        return pleaf.at[lead + (page, off)].set(vals, mode="drop")
 
     return jax.tree_util.tree_map_with_path(wb, pool, view)
 
@@ -392,6 +402,21 @@ class PagePool:
       result. What it prevents is two engines reading the SAME version
       and both reassigning (the second would silently drop the first's
       writes).
+
+    Every writer DONATES the version it was given (the device follows
+    the chain: the successor is the same buffers, written in place —
+    no second pool, no copy), so an old version is a tree of deleted
+    arrays the moment its writer was enqueued. The tree lock therefore
+    guards READERS too: a gather must take its reference and be
+    enqueued inside one lock window (a gather enqueued BEFORE a
+    donating dispatch is safe — the runtime orders the donation after
+    pending reads; a Python reference kept ACROSS one raises "Array
+    has been deleted"). ``tree_epoch`` counts the trees this pool has
+    held: a donating program that fails at run time may take the tree
+    with it, the engine that notices allocates the next one
+    (``SlotCache.renew_tree``), and every engine lent the pool compares
+    epochs before it steps — page CONTENT from an older epoch is gone,
+    whatever the refcounts say.
     """
 
     def __init__(self, model, params, n_pages: int, page_size: int,
@@ -411,6 +436,7 @@ class PagePool:
         self._mu = threading.RLock()
         self.cache = paged_cache(model, params, n_pages, page_size,
                                  mesh=mesh)
+        self.tree_epoch = 0  # trees lost to a failed dispatch so far
         self.page_nbytes = page_nbytes(self.cache)
         self.refcount = np.zeros(self.n_pages, np.int32)
         # LIFO free list: recently freed pages are re-issued first
@@ -552,6 +578,10 @@ class SlotCache:
         self.max_seq_len = model.cfg.max_seq_len
         self.pool = pool
         self._cache = None
+        # writer dispatches whose input tree was consumed / survived
+        # (see the ``cache`` property)
+        self.tree_donated = 0
+        self.tree_kept = 0
         if pool is not None:
             if not pool.shared:
                 # take OWNERSHIP of the device tree: the live pools are
@@ -588,6 +618,16 @@ class SlotCache:
 
     @property
     def cache(self) -> Any:
+        """The LIVE version of the device tree (the pool's, when the
+        pool is shared). One rule for every program that takes the
+        tree and returns its successor: it DONATES the tree, and its
+        caller assigns the successor here before releasing the tree
+        lock — so this attribute is the only reference to the only
+        version, and the write lands in place instead of in a second
+        pool. Assigning counts whether the version replaced was indeed
+        consumed (``tree_donated`` / ``tree_kept``: one ``is_deleted()``
+        on one leaf, no device sync) — ``kept`` growing means a writer
+        lost its donation and pays a whole-tree copy per dispatch."""
         pool = self.pool
         if pool is not None and pool.shared:
             return pool.cache
@@ -595,11 +635,37 @@ class SlotCache:
 
     @cache.setter
     def cache(self, value: Any) -> None:
+        old = self.cache
+        if old is not None and value is not None:
+            if tree_consumed(old):
+                self.tree_donated += 1
+            else:
+                self.tree_kept += 1
+        self._store(value)
+
+    def _store(self, value: Any) -> None:
         pool = self.pool
         if pool is not None and pool.shared:
             pool.cache = value
         else:
             self._cache = value
+
+    def renew_tree(self) -> bool:
+        """Replace a tree that a failed donating dispatch consumed by
+        a zeroed one of the same shapes and shardings; False (and
+        nothing done) while the tree is alive. With the old tree went
+        every page's and slot's CONTENT, so the caller drops what
+        pointed into it, and a paged pool's ``tree_epoch`` moves so
+        that co-located engines notice. Takes the caller's tree lock
+        for granted."""
+        old = self.cache
+        if not tree_consumed(old):
+            return False
+        self._store(jax.tree.map(
+            lambda x: jnp.zeros(x.shape, x.dtype, device=x.sharding), old))
+        if self.pool is not None:
+            self.pool.tree_epoch += 1
+        return True
 
     def free_slots(self) -> list[int]:
         return [i for i in range(self.batch_size) if not self.active[i]]
