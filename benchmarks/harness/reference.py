@@ -1,23 +1,23 @@
-"""The plain reference: both architectures' forward passes, the training
-loss, its gradients and AdamW, in straightforward ``jax.numpy`` float32
-at ``highest`` matmul precision. No kernels, no cache, no batching, and
+"""The plain reference: the forward pass, the training loss, its
+gradients and AdamW, in straightforward ``jax.numpy`` float32 at
+``highest`` matmul precision. No kernels, no cache, no batching, and
 nothing imported from ``tony_tpu``: weights come from ``weights.leaf``.
 
-Written from the published equations:
-
-- Mistral (Llama family): ``h += Attn(RMSNorm(h)); h += W_o(silu(W_g x)
-  * W_i x)``, grouped-query attention, rotary embedding over the whole
-  head (half-split ``rotate_half`` convention), untied head.
-- GPT-NeoX (Pythia): ``h += Attn(LN1(h)) + MLP(LN2(h))`` (parallel
-  residual), LayerNorm with bias, biases on every dense, rotary over the
-  first ``rotary_pct`` of each head, erf GELU, untied head.
+One layer's mathematics (``block``), the final norm and the head
+(``logits``) are the model family's, written from its published
+equations in ``benchmarks/families/<model_type>.py``; everything here is
+general over them and over the family's leaf lists: a row goes through
+the embedding (the global leaf ``embed``, a gather), every block in
+turn, then ``logits``. The training path scans the blocks over their
+stacked leaves, so every layer of a family has the same leaves.
 
 ``quant="int8"`` (or ``"fp8"``, e4m3) is the CONTROL: the same mathematics
 with every dense layer computed in that type, forward and backward
 (operands and incoming cotangents rounded, symmetric, scaled by the absmax
 over the contracted dims) — the precisions below the bf16 the
-configurations state. The benchmark's runs never use
-it; ``tests/`` and ``control.py`` do.
+configurations state; a family's ``block`` takes its matmuls through
+``dense`` here so that the control reaches them. The benchmark's runs never
+use it; ``tests/`` and ``control.py`` do.
 """
 
 from __future__ import annotations
@@ -86,92 +86,34 @@ def dense(x, w, n: int = 1, bias=None, quant: str = ""):
     return y if bias is None else y + bias
 
 
-def norm(a: W.Arch, x, p: dict, name: str):
-    if a.layer_norm:
-        mu = jnp.mean(x, -1, keepdims=True)
-        var = jnp.mean((x - mu) ** 2, -1, keepdims=True)
-        return (x - mu) / jnp.sqrt(var + a.eps) * p[name + ".scale"] \
-            + p[name + ".bias"]
-    return x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + a.eps) \
-        * p[name + ".scale"]
-
-
-def rope(a: W.Arch, x):
-    """x [L, heads, head_dim]; rotate the first ``rotary_dims`` of each
-    head: (x1, x2) -> (x1 cos - x2 sin, x2 cos + x1 sin)."""
-    r = a.rotary_dims
-    half = r // 2
-    inv = 1.0 / (a.theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    ang = jnp.arange(x.shape[0], dtype=jnp.float32)[:, None] * inv
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2, rest = x[..., :half], x[..., half:r], x[..., r:]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], -1)
-
-
-def attention(a: W.Arch, q, k, v):
-    """Causal softmax attention of one row; q [L, H, hd], k/v [L, KVH,
-    hd]; query head h reads kv head h // (H / KVH)."""
-    n = q.shape[0]
-    g = a.heads // a.kv_heads
-    q = q.reshape(n, a.kv_heads, g, a.head_dim)
-    s = jnp.einsum("qhgd,khd->hgqk", q, k, precision=HIGHEST) \
-        / jnp.sqrt(jnp.float32(a.head_dim))
-    causal = jnp.tril(jnp.ones((n, n), bool))
-    s = jnp.where(causal, s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("hgqk,khd->qhgd", p, v, precision=HIGHEST)
-    return out.reshape(n, a.heads, a.head_dim)
-
-
-def block(a: W.Arch, p: dict, x, quant: str = ""):
-    """One decoder block on one row x [L, D]."""
-    d = functools.partial(dense, quant=quant)
-    h = norm(a, x, p, "ln1")
-    q = rope(a, d(h, p["q"], bias=p.get("q.bias")))
-    k = rope(a, d(h, p["k"], bias=p.get("k.bias")))
-    v = d(h, p["v"], bias=p.get("v.bias"))
-    att = d(attention(a, q, k, v), p["o"], 2, bias=p.get("o.bias"))
-    if not a.parallel_residual:
-        x = x + att
-    h = norm(a, x, p, "ln2")
-    if a.gated:
-        m = jax.nn.silu(d(h, p["wg"])) * d(h, p["wi"])
-    else:
-        act = {"gelu": functools.partial(jax.nn.gelu, approximate=False),
-               "silu": jax.nn.silu}[a.act]
-        m = act(d(h, p["wi"], bias=p.get("wi.bias")))
-    m = d(m, p["wo"], bias=p.get("wo.bias"))
-    return x + att + m if a.parallel_residual else x + m
-
-
 # ---------------------------------------------------------------- serving
 
 @functools.partial(jax.jit, static_argnums=(0, 4, 5))
-def _serve_layer(a: W.Arch, x, key, layer, dtype, quant):
+def _serve_layer(a, x, key, layer, dtype, quant):
     p = {n: v.astype(jnp.float32)
          for n, v in W.layer_weights(a, key, layer, dtype).items()}
-    return jax.lax.map(lambda row: block(a, p, row, quant), x)
+    return jax.lax.map(
+        lambda row: W.family(a.family).block(a, p, row, quant), x)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 3))
-def _serve_embed(a: W.Arch, key, tokens, dtype):
+def _serve_embed(a, key, tokens, dtype):
     return W.leaf(a, key, -1, "embed", dtype).astype(jnp.float32)[tokens]
 
 
 @functools.partial(jax.jit, static_argnums=(0, 5, 6))
-def _serve_head(a: W.Arch, x, key, pos, target, dtype, quant):
+def _serve_head(a, x, key, pos, target, dtype, quant):
     g = {n: v.astype(jnp.float32)
          for n, v in W.global_weights(a, key, dtype).items()
          if n != "embed"}
     h = jnp.take_along_axis(x, pos[:, :, None], axis=1)  # [N, P, D]
-    logits = dense(norm(a, h, g, "ln_f"), g["head"].T, quant=quant)
+    logits = W.family(a.family).logits(a, g, h, quant)
     best = jnp.max(logits, -1)
     at = jnp.take_along_axis(logits, target[:, :, None], -1)[..., 0]
     return best, at, jnp.argmax(logits, -1)
 
 
-def serve_logits(a: W.Arch, seed: int, tokens, pos, target, *,
+def serve_logits(a, seed: int, tokens, pos, target, *,
                  dtype=jnp.bfloat16, quant: str = ""):
     """Full forward of ``tokens`` [N, L] (right-padded; causal, so the
     padding never reaches a real position) with the served weights
@@ -188,14 +130,14 @@ def serve_logits(a: W.Arch, seed: int, tokens, pos, target, *,
 
 # --------------------------------------------------------------- training
 
-def init_train_params(a: W.Arch, seed: int) -> dict:
+def init_train_params(a, seed: int) -> dict:
     """float32 master weights: globals, and each block leaf stacked over
     the layers."""
     return _first_params(a, W.root_key(seed))
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
-def _first_params(a: W.Arch, key) -> dict:
+def _first_params(a, key) -> dict:
     # the key is an ARGUMENT: a constant would make every seed a new
     # program to compile
     layers = [W.layer_weights(a, key, i, jnp.float32)
@@ -205,22 +147,22 @@ def _first_params(a: W.Arch, key) -> dict:
                   for n in layers[0]}}
 
 
-def row_loss(a: W.Arch, params: dict, row, quant: str = ""):
+def row_loss(a, params: dict, row, quant: str = ""):
     """Sum of next-token cross-entropies of one row [S] (float32)."""
+    F = W.family(a.family)
     x = params["g"]["embed"][row]
 
     @jax.checkpoint
     def body(x, p):
-        return block(a, p, x, quant), None
+        return F.block(a, p, x, quant), None
 
     x, _ = jax.lax.scan(body, x, params["l"])
-    h = norm(a, x, params["g"], "ln_f")[:-1]
-    logits = dense(h, params["g"]["head"].T, quant=quant)
+    logits = F.logits(a, params["g"], x[:-1], quant)
     logp = jax.nn.log_softmax(logits, -1)
     return -jnp.sum(jnp.take_along_axis(logp, row[1:, None], -1))
 
 
-def loss_and_grads(a: W.Arch, params: dict, batch, quant: str = ""):
+def loss_and_grads(a, params: dict, batch, quant: str = ""):
     """Mean loss over every target of ``batch`` [B, S] and its gradient,
     one row at a time (gradient accumulation: the mean is linear)."""
     n_targets = batch.shape[0] * (batch.shape[1] - 1)
@@ -261,7 +203,7 @@ def leaf_norms(tree: dict) -> dict:
     return out
 
 
-def train_reference(a: W.Arch, seed: int, job: dict, n_steps: int = 3, *,
+def train_reference(a, seed: int, job: dict, n_steps: int = 3, *,
                     quant: str = "", rows: int | None = None,
                     other_grads: dict | None = None,
                     keep_grads: bool = False) -> dict:
@@ -337,7 +279,7 @@ def _leaf_rms(tree: dict):
     return jnp.stack(out)
 
 
-def stacked(a: W.Arch, by_name: dict) -> dict:
+def stacked(a, by_name: dict) -> dict:
     """``{"embed": x, "3/q": y, ...}`` -> the reference's tree."""
     import numpy as np
 
@@ -347,7 +289,7 @@ def stacked(a: W.Arch, by_name: dict) -> dict:
                   for n, _, _ in W.layer_leaves(a)}}
 
 
-def change_norms(a: W.Arch, seed: int, params: dict, moved: dict) -> dict:
+def change_norms(a, seed: int, params: dict, moved: dict) -> dict:
     """Per-leaf norm of ``params`` minus the seed's initial weights over
     the elements that ``moved`` keeps; the initial weights are made
     again inside the one program that subtracts them."""
@@ -356,7 +298,7 @@ def change_norms(a: W.Arch, seed: int, params: dict, moved: dict) -> dict:
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
-def _change(a: W.Arch, p, keep, key):
+def _change(a, p, keep, key):
     return leaf_norms(jax.tree.map(
         lambda x, x0, k: jnp.where(k, x - x0, 0.0), p,
         _first_params(a, key), keep))

@@ -11,6 +11,7 @@ Ops`` holds one event per executed op (a pallas kernel's name carries
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
@@ -95,7 +96,9 @@ def _op(name: str) -> str:
 
 def reduce(lines: list, queries: dict | None = None, top: int = 10) -> dict:
     """``queries`` is ``{key: {"line": ..., "match": regex}}``; each gives
-    ``{"count", "total_s", "union_s"}`` averaged over the device planes.
+    ``{"count", "total_s", "union_s"}`` averaged over the device planes. A
+    query with a ``"program"`` regex counts only the events that start
+    inside an execution of a program of that name.
     Always: ``busy_s`` (union of op intervals), ``window_s`` (first op or
     program start to the last end), per-program and per-op totals, the
     longest idle gaps named by the programs on either side."""
@@ -131,6 +134,17 @@ def reduce(lines: list, queries: dict | None = None, top: int = 10) -> dict:
             pat = re.compile(q["match"])
             hit = [(s, s + d) for nm, s, d in evs.get(q["line"], [])
                    if pat.search(nm)]
+            if "program" in q:
+                prog = re.compile(q["program"])
+                runs = sorted((s, s + d) for nm, s, d in mod_evs
+                              if prog.search(nm))
+                starts = [s for s, _ in runs]
+
+                def inside(t):
+                    i = bisect.bisect_right(starts, t)
+                    return i > 0 and t < runs[i - 1][1]
+
+                hit = [h for h in hit if inside(h[0])]
             found[key]["count"] += len(hit)
             found[key]["total_s"] += sum(e - s for s, e in hit) / 1e9
             found[key]["union_s"] += union_ns(hit) / 1e9

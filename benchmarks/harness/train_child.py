@@ -14,6 +14,7 @@ the same three steps.
 from __future__ import annotations
 
 import argparse
+import collections
 import gc
 import json
 import os
@@ -152,10 +153,12 @@ def main(argv=None) -> int:
             b["tokens"][:rows // 2], b_sh)} for b in batches]
 
     # the first three steps: through the window's own call and feed
-    prog = {"losses": []}
+    prog, warm_s = {"losses": []}, []
     for i in range(job["warmup_steps"]):
+        t_step = time.monotonic()
         state, metrics = step_fn(state, batches[i % n_pool])
         prog["losses"].append(float(metrics["loss"]))
+        warm_s.append(time.monotonic() - t_step)
         if i == 0:   # Adam's first moment after one step is (1 - b1) g
             prog["grad_norms"] = {
                 n: v / (1.0 - opt["b1"])
@@ -176,11 +179,20 @@ def main(argv=None) -> int:
         **meter.report()})
 
     # ------------------------------------------------------- the window
+    # The host's cores are shared and the machine stands still now and
+    # then, for a second or two. So that the chip stays fed meanwhile,
+    # steps are dispatched ``dispatch_ahead_s`` of device time ahead of
+    # the one waited for (by the warm steps' time: the first compiles or
+    # loads); no loss is read in the window. When the time is up nothing
+    # more is sent, all that was sent is waited for, and the clock is read
+    # after that wait: all of that work counts, over all of that time.
+    ahead = max(1, min(job["max_steps_in_flight"], round(
+        job["dispatch_ahead_s"] / min(warm_s[1:] or warm_s))))
     compiled = meter.requests
     trace_dir, traced = None, {}
-    step_ms, n_steps, prev = [], 0, None
-    trace_at = (args.seconds * 0.35, args.seconds * 0.35
-                + min(job["trace_seconds"], args.seconds * 0.3))
+    step_ms, n_steps, sent = [], 0, collections.deque()
+    trace_at = (args.seconds * 0.35,
+                min(job["trace_seconds"], args.seconds * 0.3))
     t_open = time.monotonic()
     while True:
         t_step = time.monotonic()
@@ -189,7 +201,8 @@ def main(argv=None) -> int:
             jax.block_until_ready(state.step)
             trace_dir = C.start_trace("train")
             traced = {"t0": time.monotonic(), "step0": n_steps}
-        if trace_dir and "t1" not in traced and now >= trace_at[1]:
+        if trace_dir and "t1" not in traced \
+                and t_step - traced["t0"] >= trace_at[1]:
             jax.block_until_ready(state.step)
             traced.update(t1=time.monotonic(), step1=n_steps)
             C.stop_trace()
@@ -198,9 +211,12 @@ def main(argv=None) -> int:
         state, metrics = step_fn(state, batches[
             (job["warmup_steps"] + n_steps) % n_pool])
         n_steps += 1
-        if prev is not None:  # at most two steps in flight
-            jax.block_until_ready(prev)
-        prev = metrics["loss"]
+        sent.append(metrics["loss"])
+        # inside a traced span one step ahead, so that the span closes on
+        # the second: the device is as busy, and a stall there costs no
+        # end-to-end metric
+        while len(sent) > (1 if trace_dir and "t1" not in traced else ahead):
+            jax.block_until_ready(sent.popleft())
         step_ms.append((time.monotonic() - t_step) * 1e3)
     jax.block_until_ready(state.step)
     window_s = time.monotonic() - t_open
@@ -208,7 +224,8 @@ def main(argv=None) -> int:
     window = {
         "steps": n_steps, "window_s": window_s,
         "tokens": n_steps * rows * seq, "last_loss": last_loss,
-        "step_host_ms": step_ms, "compiles_in_window":
+        "step_host_ms": step_ms, "steps_in_flight": ahead,
+        "compiles_in_window":
         meter.requests - compiled, "memory_peak_bytes": C.memory_peak_bytes(),
         "memory_in_use_bytes": C.memory_in_use_bytes()}
     if traced:
@@ -219,7 +236,7 @@ def main(argv=None) -> int:
     C.emit("window", **window)
 
     # the program's state goes before the reference runs
-    del state, step_fn, batches, pool, trainer, model, metrics, prev
+    del state, step_fn, batches, pool, trainer, model, metrics, sent
     gc.collect()
     jax.clear_caches()
     gc.collect()

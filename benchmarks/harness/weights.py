@@ -2,8 +2,9 @@
 adapter and the plain reference. Nothing here comes from ``tony_tpu``.
 
 A configuration file carries the published ``config.json`` keys of its
-model. ``arch`` turns them into the handful of sizes every other module
-of the benchmark uses. ``leaf`` makes ONE weight from ``(seed, layer,
+model. ``arch`` hands them to the model family's file
+(``benchmarks/families/<model_type>.py``), which turns them into its own
+sizes and lists its leaves. ``leaf`` makes ONE weight from ``(seed, layer,
 name)`` with ``jax.random``: each value is a function of the key and the
 element's index alone, so a leaf made alone (the reference, layer by
 layer) is bit-identical to the same leaf made inside the one jitted
@@ -16,85 +17,45 @@ the comparison that decides ``correct``.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+import importlib
 
 
-@dataclass(frozen=True)
-class Arch:
-    family: str          # "mistral" (RMSNorm, SwiGLU, GQA) | "gpt_neox"
-    d: int               # hidden_size
-    layers: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    ff: int              # intermediate_size
-    vocab: int
-    max_len: int
-    eps: float
-    theta: float
-    rotary_dims: int     # leading dims of each head that rotate
-    gated: bool          # SwiGLU (wg, wi, wo) against wi, wo
-    bias: bool           # biases on every dense and norm (GPT-NeoX)
-    layer_norm: bool     # LayerNorm against RMSNorm
-    parallel_residual: bool
-    act: str             # "silu" | "gelu" (erf)
+def family(model_type: str):
+    """The module that holds everything the benchmark knows of one model
+    family, ``benchmarks/families/<model_type>.py`` (README, "Adding
+    things"). Takes a configuration's ``model_type`` or an ``Arch``'s
+    ``family``."""
+    name = "benchmarks.families." + model_type
+    try:
+        return importlib.import_module(name)
+    except ModuleNotFoundError as e:
+        if e.name != name:
+            raise
+        raise SystemExit(
+            f"benchmarks/families/{model_type}.py: no such file (model_type "
+            f"{model_type!r} is a family the benchmark does not have yet)"
+        ) from None
 
 
-def arch(cfg: dict, rehearsal: bool = False) -> Arch:
+def arch(cfg: dict, rehearsal: bool = False):
     """The sizes of a configuration file (its ``rehearsal`` block laid
-    over them for the CPU rehearsal)."""
+    over them for the CPU rehearsal), as its family's frozen dataclass.
+    The general files read ``family``, ``d``, ``layers``, ``vocab`` and
+    ``max_len`` from it and nothing else."""
     c = dict(cfg)
     if rehearsal:
         c.update(cfg["rehearsal"])
-    family = c["model_type"]
-    heads = c["num_attention_heads"]
-    head_dim = c.get("head_dim") or c["hidden_size"] // heads
-    if family == "mistral":
-        if c.get("sliding_window"):
-            raise ValueError("sliding_window is not in the reference")
-        return Arch(family, c["hidden_size"], c["num_hidden_layers"], heads,
-                    c["num_key_value_heads"], head_dim,
-                    c["intermediate_size"], c["vocab_size"],
-                    c["max_position_embeddings"], c["rms_norm_eps"],
-                    float(c["rope_theta"]), head_dim, True, False, False,
-                    False, c["hidden_act"])
-    if family == "gpt_neox":
-        return Arch(family, c["hidden_size"], c["num_hidden_layers"], heads,
-                    heads, head_dim, c["intermediate_size"], c["vocab_size"],
-                    c["max_position_embeddings"], c["layer_norm_eps"],
-                    float(c["rotary_emb_base"]),
-                    int(head_dim * c["rotary_pct"]), False, True, True,
-                    bool(c["use_parallel_residual"]), c["hidden_act"])
-    raise ValueError(f"no reference for model_type {family!r}")
+    return family(c["model_type"]).arch(c)
 
 
-# kind: "w" matrix, "s" norm scale, "b" bias
-def layer_leaves(a: Arch) -> list[tuple[str, tuple, str]]:
-    """(name, shape, kind) of one block's weights, in a fixed order."""
-    out = [("ln1.scale", (a.d,), "s"), ("ln2.scale", (a.d,), "s"),
-           ("q", (a.d, a.heads, a.head_dim), "w"),
-           ("k", (a.d, a.kv_heads, a.head_dim), "w"),
-           ("v", (a.d, a.kv_heads, a.head_dim), "w"),
-           ("o", (a.heads, a.head_dim, a.d), "w"),
-           ("wi", (a.d, a.ff), "w"), ("wo", (a.ff, a.d), "w")]
-    if a.gated:
-        out.append(("wg", (a.d, a.ff), "w"))
-    if a.bias:
-        out += [("ln1.bias", (a.d,), "b"), ("ln2.bias", (a.d,), "b"),
-                ("q.bias", (a.heads, a.head_dim), "b"),
-                ("k.bias", (a.kv_heads, a.head_dim), "b"),
-                ("v.bias", (a.kv_heads, a.head_dim), "b"),
-                ("o.bias", (a.d,), "b"), ("wi.bias", (a.ff,), "b"),
-                ("wo.bias", (a.d,), "b")]
-    return out
+def layer_leaves(a, layer=0) -> list[tuple[str, tuple, str]]:
+    """(name, shape, kind) of one block's weights, in a fixed order;
+    kind: "w" matrix, "s" norm scale, "b" bias."""
+    return family(a.family).layer_leaves(a, layer)
 
 
-def global_leaves(a: Arch) -> list[tuple[str, tuple, str]]:
-    out = [("embed", (a.vocab, a.d), "w"), ("head", (a.vocab, a.d), "w"),
-           ("ln_f.scale", (a.d,), "s")]
-    if a.bias:
-        out.append(("ln_f.bias", (a.d,), "b"))
-    return out
+def global_leaves(a) -> list[tuple[str, tuple, str]]:
+    return family(a.family).global_leaves(a)
 
 
 def root_key(seed: int, stream: int = 0):
@@ -107,7 +68,7 @@ def root_key(seed: int, stream: int = 0):
     return jax.random.fold_in(key, stream)
 
 
-def leaf(a: Arch, seed, layer, name: str, dtype):
+def leaf(a, seed, layer, name: str, dtype):
     """One weight. ``layer`` is the block's index (may be traced), or -1
     for the embedding, the head and the final norm. ``seed`` is an int
     or a key from ``root_key``."""
@@ -115,7 +76,7 @@ def leaf(a: Arch, seed, layer, name: str, dtype):
     import jax.numpy as jnp
 
     specs = global_leaves(a) if isinstance(layer, int) and layer < 0 \
-        else layer_leaves(a)
+        else layer_leaves(a, layer)
     idx, (_, shape, kind) = next(
         (i, s) for i, s in enumerate(specs) if s[0] == name)
     key = seed if hasattr(seed, "dtype") else root_key(seed)
@@ -125,15 +86,16 @@ def leaf(a: Arch, seed, layer, name: str, dtype):
     return x.astype(dtype)
 
 
-def layer_weights(a: Arch, seed, layer, dtype) -> dict:
-    return {n: leaf(a, seed, layer, n, dtype) for n, _, _ in layer_leaves(a)}
+def layer_weights(a, seed, layer, dtype) -> dict:
+    return {n: leaf(a, seed, layer, n, dtype)
+            for n, _, _ in layer_leaves(a, layer)}
 
 
-def global_weights(a: Arch, seed, dtype) -> dict:
+def global_weights(a, seed, dtype) -> dict:
     return {n: leaf(a, seed, -1, n, dtype) for n, _, _ in global_leaves(a)}
 
 
-def all_weights(a: Arch, seed, dtype) -> dict:
+def all_weights(a, seed, dtype) -> dict:
     """Every weight, ``{"g": {...}, "layers": [{...}, ...]}`` — call it
     under ONE ``jax.jit`` so the model is made on the device in one
     program."""
@@ -143,7 +105,7 @@ def all_weights(a: Arch, seed, dtype) -> dict:
                        for i in range(a.layers)]}
 
 
-def token_batch(a: Arch, seed, step, rows: int, seq: int):
+def token_batch(a, seed, step, rows: int, seq: int):
     """Training batch ``step``: ``rows`` x ``seq`` ids, every row
     different, every step different."""
     import jax

@@ -1,5 +1,9 @@
 """Operations and bytes from shapes: what the algorithm needs, whatever
-implements it. Pure arithmetic on an ``Arch`` — no jax.
+implements it. Pure arithmetic on an ``Arch`` — no jax. What depends on
+the family's mathematics (its cache, its attention, which weights a token
+meets) is counted in the family's own file and forwarded here by name, so
+that the readers call one place; what follows from the leaf lists alone
+is counted here.
 
 Conventions: a multiply-add is 2 FLOPs; recomputed operations do not
 count; causal attention counts only the keys at or before the query.
@@ -7,7 +11,7 @@ count; causal attention counts only the keys at or before the query.
 
 from __future__ import annotations
 
-from .weights import Arch, global_leaves, layer_leaves
+from .weights import family, global_leaves, layer_leaves
 
 
 def _size(shape) -> int:
@@ -17,68 +21,45 @@ def _size(shape) -> int:
     return n
 
 
-def layer_params(a: Arch) -> int:
-    return sum(_size(s) for _, s, _ in layer_leaves(a))
+def layer_params(a, layer: int = 0) -> int:
+    return sum(_size(s) for _, s, _ in layer_leaves(a, layer))
 
 
-def layer_matmul_params(a: Arch) -> int:
-    return sum(_size(s) for _, s, k in layer_leaves(a) if k == "w")
+def layer_matmul_params(a, layer: int = 0) -> int:
+    return sum(_size(s) for _, s, k in layer_leaves(a, layer) if k == "w")
 
 
-def n_params(a: Arch) -> int:
-    return a.layers * layer_params(a) \
+def n_params(a) -> int:
+    return sum(layer_params(a, i) for i in range(a.layers)) \
         + sum(_size(s) for _, s, _ in global_leaves(a))
 
 
-def kv_bytes_per_token(a: Arch, itemsize: int = 2) -> int:
-    """K and V of every layer for one position."""
-    return 2 * a.kv_heads * a.head_dim * a.layers * itemsize
+def serve_flops(a, prompt_len: int, n_out: int) -> float:
+    """Model FLOPs of serving one request: every position fed to the
+    model, the head for each sampled token."""
+    n = prompt_len + n_out - 1
+    return sum(serve_token_flops(a, p, p >= prompt_len - 1) for p in range(n))
 
 
-def train_flops_per_token(a: Arch, seq: int) -> float:
-    """Forward + backward of one token of a ``seq``-long row: 6 FLOPs a
-    matmul weight (every block and the head; the embedding is a
-    gather), and causal attention's two matmuls forward and four
-    backward over seq/2 keys on average."""
-    dense = 6 * (a.layers * layer_matmul_params(a) + a.vocab * a.d)
-    attn = 6 * 2 * (seq / 2) * a.heads * a.head_dim * a.layers
-    return dense + attn
+def kv_bytes_per_token(a, itemsize: int = 2) -> int:
+    return family(a.family).kv_bytes_per_token(a, itemsize)
 
 
-def flash_flops_per_step(a: Arch, rows: int, seq: int) -> float:
-    """Causal attention's own work in one training step, forward (QK^T,
-    PV) and backward (dV, dP, dQ, dK): six matmuls of seq x seq/2 x
-    head_dim per head, per row, per layer. The forward's recomputation
-    inside a flash backward is not counted."""
-    return 6 * 2 * (seq * seq / 2) * a.head_dim * a.heads * rows * a.layers
+def train_flops_per_token(a, seq: int) -> float:
+    return family(a.family).train_flops_per_token(a, seq)
 
 
-def serve_flops(a: Arch, prompt_len: int, n_out: int) -> float:
-    """Model FLOPs of serving one request: every position through every
-    block (2 a weight, attention over the positions so far), and the
-    head for each sampled token."""
-    n = prompt_len + n_out - 1          # positions fed to the model
-    dense = 2 * a.layers * layer_matmul_params(a) * n
-    attn = 4 * a.heads * a.head_dim * a.layers * n * (n + 1) / 2
-    return dense + attn + 2 * a.vocab * a.d * n_out
+def flash_flops_per_step(a, rows: int, seq: int) -> float:
+    return family(a.family).flash_flops_per_step(a, rows, seq)
 
 
-def serve_token_flops(a: Arch, position: int, sampled: bool) -> float:
-    """One position's share of ``serve_flops``."""
-    return 2 * a.layers * layer_matmul_params(a) \
-        + 4 * a.heads * a.head_dim * a.layers * (position + 1) \
-        + (2 * a.vocab * a.d if sampled else 0)
+def serve_token_flops(a, position: int, sampled: bool) -> float:
+    return family(a.family).serve_token_flops(a, position, sampled)
 
 
-def decode_step_bytes(a: Arch, live_tokens: float, itemsize: int = 2) -> float:
-    """Least bytes one decode step reads: every block's weights, the
-    final norm and the head once (the embedding is a gather of a few
-    rows), and the K/V of the ``live_tokens`` positions the batch
-    attends to."""
-    weights = a.layers * layer_params(a) + a.vocab * a.d + a.d
-    return weights * itemsize + live_tokens * kv_bytes_per_token(a, itemsize)
+def decode_step_bytes(a, live_tokens: float, itemsize: int = 2) -> float:
+    return family(a.family).decode_step_bytes(a, live_tokens, itemsize)
 
 
-def decode_step_flops(a: Arch, batch: float, live_tokens: float) -> float:
-    return batch * (2 * a.layers * layer_matmul_params(a) + 2 * a.vocab * a.d) \
-        + 4 * a.heads * a.head_dim * a.layers * live_tokens
+def decode_step_flops(a, batch: float, live_tokens: float) -> float:
+    return family(a.family).decode_step_flops(a, batch, live_tokens)

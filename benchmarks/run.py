@@ -8,9 +8,12 @@ configuration (``configs/<name>.json``) and a traffic mix or job
 (``traffic/<name>.json``, whose ``kind`` picks the child); each per-layer
 metric of the cell is ``layer_metrics/<metric>.json``, which names a
 reader module (``readers/<reader>.py``) and its arguments; the limits of
-the comparison that decides ``correct`` are ``limits/<cell>.json``. A
-name that resolves to no file is refused. Adding a cell, a mix, a
-configuration or a metric is adding files and ``BENCHMARK.json`` entries.
+the comparison that decides ``correct`` are ``limits/<cell>.json``; the
+configuration's ``model_type`` is its family, ``families/<model_type>.py``
+(sizes, leaves, plain reference, the program's tree, the counts). A name
+that resolves to no file is refused. Adding a cell, a mix, a
+configuration, a family or a metric is adding files and ``BENCHMARK.json``
+entries.
 
 This parent NEVER imports jax (one process per chip): it starts the child
 that holds the chip, takes ``setup_s`` from its own start to the child's
@@ -276,6 +279,8 @@ def run_train(spec: dict, args, child_argv: list) -> dict:
     values = {"train_tokens_per_s": window["tokens"] / window["window_s"],
               "step_host_p50_ms": loadgen.percentile(steps, 0.5)
               if steps else math.nan,
+              "step_host_max_ms": max(steps, default=math.nan),
+              "steps_in_flight": window["steps_in_flight"],
               "steps": window["steps"], "window_s": window["window_s"]}
     note("window: " + json.dumps(values))
     numbers = dict(final["check"]["numbers"])
@@ -339,6 +344,7 @@ def main(argv=None) -> int:
     if args.dry:
         print(json.dumps({
             "workload": args.workload, "config": spec["config"]["name"],
+            "family": W.arch(spec["config"]).family,
             "traffic": spec["cell"]["traffic"],
             "kind": spec["traffic"]["kind"],
             "end_to_end": [m["name"] for m in spec["end_to_end"]],

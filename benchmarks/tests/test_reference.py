@@ -3,6 +3,7 @@ control against the reference, and whole rehearsal runs — sound, and with
 the timed path broken underneath."""
 
 import json
+import math
 import os
 import subprocess
 import sys
@@ -12,6 +13,7 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CONFIGS = ("mistral-7b-v0.3-l10", "pythia-1.4b-l6")
+PINS = json.load(open(os.path.join(ROOT, "benchmarks", "tests", "pins.json")))
 
 
 def toy(name):
@@ -51,6 +53,45 @@ def test_reference_agrees_with_the_program_at_toy_size(name):
                                  dtype=jnp.float32)
     assert np.abs(np.asarray(best) - logits.max(-1)).max() < 1e-5
     assert float(jnp.max(best - at)) < 1e-5
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_weights_and_reference_are_what_they_were_before_the_families_moved(
+        name):
+    """Taken on PR 29's parent: every leaf's sum and sum of squares
+    (exact: ``fsum`` over float64), so the same seed still makes
+    bit-identical weights (a leaf's place in its family's list is folded
+    into its key); and the plain reference's readings of them, to
+    float32 rounding."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.harness import reference as R, weights as W
+
+    a, seed = toy(name), PINS["seed"]
+    now = {}
+    for layer, specs in ((1, W.layer_leaves(a, 1)), (-1, W.global_leaves(a))):
+        for n, _, _ in specs:
+            x = np.asarray(W.leaf(a, seed, layer, n, jnp.float32),
+                           np.float64).ravel()
+            now[f"{layer}/{n}"] = [math.fsum(x), math.fsum(x * x)]
+    assert now == PINS["leaves"][name]
+    was = PINS["reference"][name]
+    toks = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (2, 48), 0,
+                                         a.vocab))
+    pos = np.tile(np.arange(48, dtype=np.int32)[None], (2, 1))
+    best, at, argmax = R.serve_logits(
+        a, seed, toks, pos, (toks[:, ::-1] % a.vocab).astype(np.int32))
+    assert float(np.asarray(best, np.float64).sum()) == pytest.approx(
+        was["serve_best_sum"], rel=1e-6)
+    assert float(np.asarray(at, np.float64).sum()) == pytest.approx(
+        was["serve_at_sum"], rel=1e-5)
+    assert int(np.asarray(argmax).sum()) == was["serve_argmax_sum"]
+    with open(os.path.join(ROOT, "benchmarks", "traffic", "pretrain-2k.json")) as f:
+        job = json.load(f)
+    job.update(job["rehearsal"])
+    assert R.train_reference(a, seed, job, 3)["losses"] == pytest.approx(
+        was["train_losses"], rel=1e-6)
 
 
 def test_int8_control_reads_above_the_bf16_program_serving():
