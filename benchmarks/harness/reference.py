@@ -8,8 +8,11 @@ One layer's mathematics (``block``), the final norm and the head
 equations in ``benchmarks/families/<model_type>.py``; everything here is
 general over them and over the family's leaf lists: a row goes through
 the embedding (the global leaf ``embed``, a gather), every block in
-turn, then ``logits``. The training path scans the blocks over their
-stacked leaves, so every layer of a family has the same leaves.
+turn, then ``logits``. Both halves go by ``weights.runs``, the
+consecutive layers of one KIND (one run for a family that names no
+kinds): the serving walk compiles one layer program a kind, with the
+layer's index traced; the training tree holds each run's leaves stacked,
+and ``row_loss`` scans one run after the other.
 
 ``quant="int8"`` (or ``"fp8"``, e4m3) is the CONTROL: the same mathematics
 with every dense layer computed in that type, forward and backward
@@ -86,14 +89,24 @@ def dense(x, w, n: int = 1, bias=None, quant: str = ""):
     return y if bias is None else y + bias
 
 
+def _block(a, kind: str):
+    """The family's ``block`` for layers of ``kind`` as ``(p, x, quant)``;
+    only a family that names kinds is told which."""
+    F = W.family(a.family)
+    if hasattr(F, "layer_kind"):
+        return lambda p, x, quant: F.block(a, p, x, quant, kind=kind)
+    return lambda p, x, quant: F.block(a, p, x, quant)
+
+
 # ---------------------------------------------------------------- serving
 
-@functools.partial(jax.jit, static_argnums=(0, 4, 5))
-def _serve_layer(a, x, key, layer, dtype, quant):
+@functools.partial(jax.jit, static_argnums=(0, 4, 5, 6))
+def _serve_layer(a, x, key, layer, dtype, quant, kind):
+    # static in the kind, traced in the index: one program a kind
     p = {n: v.astype(jnp.float32)
-         for n, v in W.layer_weights(a, key, layer, dtype).items()}
-    return jax.lax.map(
-        lambda row: W.family(a.family).block(a, p, row, quant), x)
+         for n, v in W.layer_weights(a, key, layer, dtype, kind).items()}
+    block = _block(a, kind)
+    return jax.lax.map(lambda row: block(p, row, quant), x)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 3))
@@ -122,8 +135,9 @@ def serve_logits(a, seed: int, tokens, pos, target, *,
     logit, the logit of ``target`` [N, P], and the argmax."""
     key = W.root_key(seed)
     x = _serve_embed(a, key, jnp.asarray(tokens), dtype)
-    for layer in range(a.layers):
-        x = _serve_layer(a, x, key, jnp.int32(layer), dtype, quant)
+    for kind, first, stop in W.runs(a):
+        for layer in range(first, stop):
+            x = _serve_layer(a, x, key, jnp.int32(layer), dtype, quant, kind)
     return _serve_head(a, x, key, jnp.asarray(pos), jnp.asarray(target),
                        dtype, quant)
 
@@ -131,8 +145,9 @@ def serve_logits(a, seed: int, tokens, pos, target, *,
 # --------------------------------------------------------------- training
 
 def init_train_params(a, seed: int) -> dict:
-    """float32 master weights: globals, and each block leaf stacked over
-    the layers."""
+    """float32 master weights: globals under ``"g"``, and under ``"l"``
+    one dict a run (``weights.runs``) of its block leaves, each stacked
+    over the run's layers."""
     return _first_params(a, W.root_key(seed))
 
 
@@ -143,21 +158,30 @@ def _first_params(a, key) -> dict:
     layers = [W.layer_weights(a, key, i, jnp.float32)
               for i in range(a.layers)]
     return {"g": W.global_weights(a, key, jnp.float32),
-            "l": {n: jnp.stack([lw[n] for lw in layers])
-                  for n in layers[0]}}
+            "l": [{n: jnp.stack([lw[n] for lw in layers[first:stop]])
+                   for n in layers[first]}
+                  for _, first, stop in W.runs(a)]}
+
+
+def row_hidden(a, params: dict, row, quant: str = ""):
+    """One row [S] through the embedding and every block, run by run:
+    [S, d] (float32)."""
+    x = params["g"]["embed"][row]
+    for (kind, _, _), run in zip(W.runs(a), params["l"]):
+        block = _block(a, kind)
+
+        @jax.checkpoint
+        def body(x, p):
+            return block(p, x, quant), None
+
+        x, _ = jax.lax.scan(body, x, run)   # traced here, with this block
+    return x
 
 
 def row_loss(a, params: dict, row, quant: str = ""):
     """Sum of next-token cross-entropies of one row [S] (float32)."""
-    F = W.family(a.family)
-    x = params["g"]["embed"][row]
-
-    @jax.checkpoint
-    def body(x, p):
-        return F.block(a, p, x, quant), None
-
-    x, _ = jax.lax.scan(body, x, params["l"])
-    logits = F.logits(a, params["g"], x[:-1], quant)
+    x = row_hidden(a, params, row, quant)
+    logits = W.family(a.family).logits(a, params["g"], x[:-1], quant)
     logp = jax.nn.log_softmax(logits, -1)
     return -jnp.sum(jnp.take_along_axis(logp, row[1:, None], -1))
 
@@ -192,14 +216,15 @@ def adamw(params, grads, mu, nu, step, *, lr, b1, b2, eps, weight_decay):
     return pick(0), pick(1), pick(2)
 
 
-def leaf_norms(tree: dict) -> dict:
-    """{leaf name: norm}: block leaves by layer (``"3/q"``), globals by
-    name — the program's leaves, one for one."""
+def leaf_norms(a, tree: dict) -> dict:
+    """{leaf name: norm}: block leaves by their layer's absolute index
+    (``"3/q"``), globals by name — the program's leaves, one for one."""
     out = {n: jnp.sqrt(jnp.sum(v * v)) for n, v in tree["g"].items()}
-    for n, v in tree["l"].items():
-        per = jnp.sqrt(jnp.sum((v * v).reshape(v.shape[0], -1), -1))
-        for i in range(v.shape[0]):
-            out[f"{i}/{n}"] = per[i]
+    for (_, first, _), run in zip(W.runs(a), tree["l"]):
+        for n, v in run.items():
+            per = jnp.sqrt(jnp.sum((v * v).reshape(v.shape[0], -1), -1))
+            for i in range(v.shape[0]):
+                out[f"{first + i}/{n}"] = per[i]
     return out
 
 
@@ -232,11 +257,11 @@ def train_reference(a, seed: int, job: dict, n_steps: int = 3, *,
         extra = None
         if first:
             floor = 1e-3 * jnp.median(_leaf_rms(grads))
-            extra = {"norms": leaf_norms(grads), "moved": jax.tree.map(
+            extra = {"norms": leaf_norms(a, grads), "moved": jax.tree.map(
                 lambda g: jnp.abs(g) >= floor, grads)}
             if other is not None:
                 extra["diff"] = leaf_norms(
-                    jax.tree.map(jnp.subtract, other, grads))
+                    a, jax.tree.map(jnp.subtract, other, grads))
             if keep_grads:
                 extra["grads"] = grads
         params, mu, nu = adamw(params, grads, mu, nu,
@@ -274,8 +299,10 @@ def train_reference(a, seed: int, job: dict, n_steps: int = 3, *,
 def _leaf_rms(tree: dict):
     """Root mean square of every leaf (block leaves layer by layer)."""
     out = [jnp.sqrt(jnp.mean(v * v)) for v in tree["g"].values()]
-    for v in tree["l"].values():
-        out.extend(jnp.sqrt(jnp.mean((v * v).reshape(v.shape[0], -1), -1)))
+    for run in tree["l"]:
+        for v in run.values():
+            out.extend(
+                jnp.sqrt(jnp.mean((v * v).reshape(v.shape[0], -1), -1)))
     return jnp.stack(out)
 
 
@@ -284,9 +311,10 @@ def stacked(a, by_name: dict) -> dict:
     import numpy as np
 
     return {"g": {n: by_name[n] for n, _, _ in W.global_leaves(a)},
-            "l": {n: np.stack([by_name[f"{i}/{n}"]
-                               for i in range(a.layers)])
-                  for n, _, _ in W.layer_leaves(a)}}
+            "l": [{n: np.stack([by_name[f"{i}/{n}"]
+                                for i in range(first, stop)])
+                   for n, _, _ in W.layer_leaves(a, first)}
+                  for _, first, stop in W.runs(a)]}
 
 
 def change_norms(a, seed: int, params: dict, moved: dict) -> dict:
@@ -299,6 +327,6 @@ def change_norms(a, seed: int, params: dict, moved: dict) -> dict:
 
 @functools.partial(jax.jit, static_argnums=(0,))
 def _change(a, p, keep, key):
-    return leaf_norms(jax.tree.map(
+    return leaf_norms(a, jax.tree.map(
         lambda x, x0, k: jnp.where(k, x - x0, 0.0), p,
         _first_params(a, key), keep))

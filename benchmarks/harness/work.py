@@ -29,9 +29,17 @@ def layer_matmul_params(a, layer: int = 0) -> int:
     return sum(_size(s) for _, s, k in layer_leaves(a, layer) if k == "w")
 
 
+def params(a) -> int:
+    """Every block's weights, whatever kinds the layers are of."""
+    return sum(layer_params(a, i) for i in range(a.layers))
+
+
+def matmul_params(a) -> int:
+    return sum(layer_matmul_params(a, i) for i in range(a.layers))
+
+
 def n_params(a) -> int:
-    return sum(layer_params(a, i) for i in range(a.layers)) \
-        + sum(_size(s) for _, s, _ in global_leaves(a))
+    return params(a) + sum(_size(s) for _, s, _ in global_leaves(a))
 
 
 def serve_flops(a, prompt_len: int, n_out: int) -> float:

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import collections
 import importlib
 import json
 import math
@@ -342,9 +343,13 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
     spec = resolve(args.workload)
     if args.dry:
+        a = W.arch(spec["config"])
         print(json.dumps({
             "workload": args.workload, "config": spec["config"]["name"],
-            "family": W.arch(spec["config"]).family,
+            "family": a.family,
+            # how many layers of each kind: a depth cut against its period
+            "layer_kinds": dict(collections.Counter(
+                W.layer_kind(a, i) for i in range(a.layers))),
             "traffic": spec["cell"]["traffic"],
             "kind": spec["traffic"]["kind"],
             "end_to_end": [m["name"] for m in spec["end_to_end"]],
