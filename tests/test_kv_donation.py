@@ -30,7 +30,7 @@ from tony_tpu.serve import PagePool, Request, Server
 from tony_tpu.serve import engine as E
 from tony_tpu.serve.faults import FaultPlan, InjectedFault
 from tony_tpu.serve.migrate import gather_local
-from tony_tpu.serve.slots import paged_cache, tree_consumed
+from tony_tpu.serve.slots import STATE_COLS, paged_cache, tree_consumed
 
 
 def _model(scan_layers=False, kv_int8=False):
@@ -131,9 +131,9 @@ def test_every_cache_leaf_aliases(program, scan_layers, kv_int8):
     table = i32(b, cols)
     if program == "decode":
         lowered = E._decode_chunk.lower(
-            model, params, cache, i32(b), i32(b), jnp.zeros(b), i32(b),
-            jnp.zeros((b, 2), jnp.uint32), i32(b), table, n_steps=4,
-            eos_ids=(2,), freeze=True)
+            model, params, cache, i32(b, STATE_COLS),
+            i32(b, 1 + STATE_COLS), table, n_steps=4, eos_ids=(2,),
+            freeze=True)
     elif program == "verify":
         lowered = E._verify_chunk.lower(
             model, params, cache, i32(b, 3), i32(b, 3), i32(b),

@@ -23,6 +23,8 @@ mid-stream windows the tests need actually exist on a model this
 small.
 """
 
+import itertools
+import threading
 import time
 
 import jax
@@ -76,6 +78,26 @@ def _control(tiny, prompt, budget, *, temperature=0.0, top_k=0,
     srv.submit(Request(list(prompt), budget, id="c",
                        temperature=temperature, top_k=top_k, seed=seed))
     return list(srv.run())[0].tokens
+
+
+def _hold_after(srv, n_steps, gate):
+    """From its step ``n_steps + 1`` on, and for as long as it has a
+    live slot and ``gate`` is closed, ``srv.step()`` does nothing: the
+    loop that drives it keeps turning (and sees a retire or a freeze),
+    the stream stands. It then cannot run to its end on the wall clock
+    while the test's own thread is kept off the CPU: the mid-stream
+    window stays open until the test has used it, however the host
+    stalls."""
+    real, count = srv.step, itertools.count()
+
+    def step():
+        if next(count) >= n_steps and srv.n_active and not gate.is_set():
+            time.sleep(0.01)
+            return []
+        return real()
+
+    srv.step = step
+    return srv
 
 
 def _wait(cond, timeout=30.0, msg="condition"):
@@ -205,14 +227,21 @@ def test_cross_host_migration_token_exact(tiny):
     control."""
     prompt, budget = _prompt(), 40
     expect = _control(tiny, prompt, budget)
+    # whichever side holds the stream stands after six steps until the
+    # freeze has come: a test thread kept off the CPU for the second
+    # the stream lasts on the clock would otherwise find the session
+    # finished where it was, and the counters below never settle
+    moved = threading.Event()
     http = _start_agent(tiny, fault_plan=_slow(), prefix_cache_mb=4)
-    gw = Gateway([_mk(tiny, fault_plan=_slow()),
+    _hold_after(http.agent.server, 6, moved)
+    gw = Gateway([_hold_after(_mk(tiny, fault_plan=_slow()), 6, moved),
                   _stub(http.address)]).start()
     try:
         t = gw.submit(GenRequest(list(prompt), max_new_tokens=budget,
                                  id="wire"))
         _wait_emitted(t, 3)
         assert gw.remove_replica(t.replica, timeout=60)
+        moved.set()
         res = t.result(timeout=120)
         assert list(res.tokens) == list(expect)
         assert gw.snapshot()["shed"] == {}
@@ -226,6 +255,7 @@ def test_cross_host_migration_token_exact(tiny):
         mig = gw.snapshot()["engine"]["migrations"]
         assert mig["remote"] >= 1
     finally:
+        moved.set()
         assert gw.drain(timeout=60)
         http.stop()
 

@@ -225,7 +225,7 @@ def test_serving_step_writes_the_page_pool_in_place(topo, tpu_compile,
 
     from tony_tpu.models import Transformer, TransformerConfig
     from tony_tpu.serve import engine
-    from tony_tpu.serve.slots import paged_cache
+    from tony_tpu.serve.slots import STATE_COLS, paged_cache
 
     one_chip = SingleDeviceSharding(topo.devices[0])
 
@@ -249,8 +249,8 @@ def test_serving_step_writes_the_page_pool_in_place(topo, tpu_compile,
     b, cols = 8, 8
     if program == "decode":
         lowered = engine._decode_chunk.lower(
-            model, params, pool, A((b,)), A((b,)), A((b,), F32), A((b,)),
-            A((b, 2), jnp.uint32), A((b,)), A((b, cols)), n_steps=2,
+            model, params, pool, A((b, STATE_COLS)),
+            A((b, 1 + STATE_COLS)), A((b, cols)), n_steps=2,
             eos_ids=(2,), freeze=True)
     else:
         lowered = engine._paged_prefill_admit.lower(

@@ -3904,4 +3904,15 @@ class Gateway:
                 "donated": total("kv_tree_donated"),
                 "kept": total("kv_tree_kept"),
             },
+            # the decode round's device-resident state
+            # (serve/slots.SlotCache): chunk rounds, those that sent
+            # the device no state at all, the rows sent by the others
+            # (one per admission and per eviction), page tables sent,
+            # and copies of the rng keys back to the host (none while
+            # the traffic is greedy)
+            "decode_rounds": total("decode_rounds"),
+            "decode_rounds_clean": total("decode_rounds_clean"),
+            "decode_rows_patched": total("decode_rows_patched"),
+            "decode_table_sends": total("decode_table_sends"),
+            "decode_rng_pulls": total("decode_rng_pulls"),
         }

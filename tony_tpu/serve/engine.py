@@ -55,6 +55,7 @@ from tony_tpu.models.generate import (_is_eos, init_cache,
                                       multi_decode_step,
                                       normalize_eos_ids,
                                       single_decode_step)
+from tony_tpu.models.transformer import _serve_replicate
 from tony_tpu.obs.goodput import (CostModel, detect_hbm_gbps,
                                   detect_peak_flops, ledger)
 from tony_tpu.obs.phases import HostPhases
@@ -65,9 +66,10 @@ from tony_tpu.serve.migrate import SessionSnapshot, StaleDelta, \
 from tony_tpu.serve.prefix import PrefixStore
 from tony_tpu.serve.slots import (PagePool, SlotCache, _copy_page,
                                   _gather_pages, _read_slot,
-                                  _scatter_pages,
+                                  _scatter_pages, apply_patch,
                                   cache_batch_axis, default_page_size,
-                                  paged_view, paged_write_back)
+                                  pack_state, paged_view,
+                                  paged_write_back, unpack_state)
 from tony_tpu.serve.tier import (HostPageTier, decode_array,
                                  decode_payload, pad_host_pages,
                                  payload_pages)
@@ -377,33 +379,48 @@ def _frozen_body(model, params, temps, top_ks, eos_ids: tuple):
 @functools.partial(jax.jit, static_argnames=("model", "n_steps",
                                              "eos_ids", "freeze"),
                    donate_argnames=("cache",))
-def _decode_chunk(model, params, cache, tok, positions, temps, top_ks,
-                  rngs, rem=None, table=None, *, n_steps: int,
-                  eos_ids: tuple = (), freeze: bool = False):
+def _decode_chunk(model, params, cache, state, patch, table=None, *,
+                  n_steps: int, eos_ids: tuple = (), freeze: bool = False):
     """The resident serving step: ``n_steps`` decode micro-steps for
     EVERY slot as one lax.scan dispatch (empty slots compute garbage
     that nothing reads — the price of a never-recompiled static shape).
     Per-slot sampling and rng advance ride inside the scan; returns
-    (cache, tokens [b, n_steps], rngs). ``n_steps`` is static (the
+    (cache, tokens [b, n_steps], state). ``n_steps`` is static (the
     scheduler quantizes it to powers of two, so at most
     log2(chunk_steps)+1 programs ever compile).
+
+    ``state`` [b, STATE_COLS] int32 is the round's small carry, which
+    LIVES ON THE DEVICE between rounds (``SlotCache.state``): per slot
+    the last token, the position (-1 = empty), the remaining budget,
+    ``top_k``, and ``temperature`` and the rng key bit-cast. ``patch``
+    [b, 1 + STATE_COLS] is the host's word on it: a mask column, then
+    the row to take in the resident one's place (``apply_patch``, one
+    ``where`` before the scan) — the rows admission, eviction or a
+    host-fed round changed, or an all-clear patch that never left the
+    device. What the scan carries out (token, position, budget, key) is
+    the state handed back, whatever ``n_steps`` was; the sampling knobs
+    pass through. One program serves a clean and a patched round.
 
     ``cache`` is DONATED: the returned tree is the caller's own
     buffers with the chunk's K/V written IN PLACE (each leaf aliases
     its output), so a step moves ``b x n_steps`` cache entries and not
     a second copy of the whole tree — and the tree passed in is dead
-    the moment this is enqueued (``SlotCache.cache``).
+    the moment this is enqueued (``SlotCache.cache``). ``state``,
+    ``patch`` and ``table`` are NOT: they are a few hundred bytes, the
+    all-clear patch and the table are used again, and a dispatch that
+    fails must leave the last state alive.
 
     ``freeze`` (the ISSUE-13 in-dispatch EOS mode, the engine default)
-    threads a per-slot ``done`` flag + remaining budget ``rem`` [b]
-    through the scan (``_frozen_body``): a slot that samples EOS or
-    exhausts its budget mid-chunk stops writing K/V (sentinel
-    position), stops advancing rng, and re-emits its final token — so
-    ``chunk_steps`` can grow without the trailing positions becoming
-    the ``overshoot`` waste bucket, and the host trim becomes a
-    consistency check. ``eos_ids`` is static per engine (one compile).
+    threads a per-slot ``done`` flag + the remaining budget through the
+    scan (``_frozen_body``): a slot that samples EOS or exhausts its
+    budget mid-chunk stops writing K/V (sentinel position), stops
+    advancing rng, and re-emits its final token — so ``chunk_steps``
+    can grow without the trailing positions becoming the ``overshoot``
+    waste bucket, and the host trim becomes a consistency check.
+    ``eos_ids`` is static per engine (one compile). Without it the
+    budget column is carried through unread.
 
-    ``table`` [b, max_pages] switches to the paged cache layout — but
+    ``table`` [b, cols] switches to the paged cache layout — but
     NOT by gathering inside every micro-step: the slot view is
     gathered from the pools ONCE (``paged_view``), the whole scan runs
     the plain unpaged per-slot program against it (bitwise-identical
@@ -413,16 +430,17 @@ def _decode_chunk(model, params, cache, tok, positions, temps, top_ks,
     unwritten tail positions copy their own gathered content back —
     an identity write). The table is fixed across the chunk, so the
     host pre-extends it to cover every position the chunk will write
-    (engine ``_decode_round``)."""
+    (engine ``_chunk_round``)."""
     max_len = model.cfg.max_seq_len
+    state = apply_patch(state, patch)
+    tok, positions, rem, top_ks, temps, rngs = unpack_state(state)
     pool_cache, start = cache, positions
     if table is not None:
         cache = paged_view(cache, table, max_len)
 
     if freeze:
         body = _frozen_body(model, params, temps, top_ks, eos_ids)
-        carry = (cache, tok, positions, rngs,
-                 positions < 0, jnp.asarray(rem, jnp.int32))
+        carry = (cache, tok, positions, rngs, positions < 0, rem)
     else:
         def body(carry, _):
             cache, tok, positions, rngs = carry
@@ -441,11 +459,17 @@ def _decode_chunk(model, params, cache, tok, positions, temps, top_ks,
     else:
         carry, tok1 = body(carry, None)
         toks = tok1[:, None]
-    cache, rngs = carry[0], carry[3]
+    cache, tok, positions, rngs = carry[:4]
+    if freeze:
+        rem = carry[5]
     if table is not None:
         cache = paged_write_back(pool_cache, cache, table, start,
                                  n_steps, max_len)
-    return cache, toks, rngs
+    # under a mesh pinned replicated, as the host sends it: the
+    # successor meets the same executable and no round re-shards it
+    state = _serve_replicate(
+        model.cfg, pack_state(state, tok, positions, rem, rngs))
+    return cache, toks, state
 
 
 @functools.partial(jax.jit, static_argnames=("model", "window",
@@ -876,7 +900,8 @@ class Server:
                 # on one chip and OOM exactly the configurations the
                 # mesh unlocks
                 pool = PagePool(model, params, n_pages, ps, mesh=mesh)
-            self.slots = SlotCache(model, params, batch_size, pool=pool)
+            self.slots = SlotCache(model, params, batch_size, pool=pool,
+                                   mesh=mesh)
         else:
             self.slots = SlotCache(model, params, batch_size, mesh=mesh)
         # dispatch concurrency (ISSUE-19): every engine owns ITS OWN
@@ -2411,6 +2436,8 @@ class Server:
                 temperature=float(s.temperature[slot]),
                 top_k=int(s.top_k[slot]),
                 seed=int(req.seed),
+                # the key as the device holds it now (``s.rng`` pulls
+                # it back where chunk rounds have moved it)
                 rng=np.array(s.rng[slot], np.uint32),
                 spec_ema=float(self._spec_ema[slot]),
                 n_tokens=n_tok,
@@ -2740,9 +2767,16 @@ class Server:
     def _chunk_round(self) -> list[Result]:
         """The plain chunk path of ``_decode_round``, inside its open
         ``decode.prepare`` leaf: the leaf is switched to ``enqueue``
-        (host-to-device transfers and the jit call), ``wait`` (the host
+        (what the host must send, and the jit call), ``wait`` (the host
         sync on the tokens), ``emit`` (the token walk) and ``record``
-        (the timeline record with its cost and goodput stamps)."""
+        (the timeline record with its cost and goodput stamps).
+
+        The program's small inputs are on the device already
+        (``SlotCache.state``): this round sends the rows the host
+        changed since the last one as one packed patch, the page table
+        if its live columns changed, and in most rounds neither; it
+        copies back the tokens only, from which the mirrors follow the
+        device as before."""
         finished: list[Result] = []
         s = self.slots
         k = self._chunk_size()
@@ -2752,7 +2786,7 @@ class Server:
             # live slot to cover the positions this chunk will write
             # (capped at the slot's own budget — overshoot past a
             # finish writes through the sentinel and drops). The table
-            # ships COLUMN-SLICED to a power-of-two bucket of the live
+            # is read COLUMN-SLICED to a power-of-two bucket of the live
             # extent: the gathered view — and every micro-step's
             # attention read over it — is O(actual tokens), not
             # O(max_seq_len); the dropped columns held junk whose
@@ -2769,19 +2803,15 @@ class Server:
                     hi = max(hi, int(s.lengths[slot]) + k)
             cols = min(_bucket_pow2(-(-hi // s.pool.page_size)),
                        s.max_pages)
-            table = jnp.asarray(s.page_table[:, :cols])
+            table = s.device_table(cols)
         view_tokens = cols * s.pool.page_size if self.paged else 0
         freeze = self.in_dispatch_eos
-        rem = None
-        if freeze:
-            # per-slot remaining budgets: the device freezes a slot the
-            # moment it samples EOS or exhausts this, so every emitted
-            # (non-frozen) position is a token the request keeps
-            rem = np.zeros(s.batch_size, np.int32)
-            for slot, live in enumerate(self._live):
-                if live is not None:
-                    rem[slot] = live.request.max_new_tokens \
-                        - len(live.generated)
+        # per-slot remaining budgets, for the rows the patch carries:
+        # the device freezes a slot the moment it samples EOS or
+        # exhausts this, so every emitted (non-frozen) position is a
+        # token the request keeps
+        rem = [live.request.max_new_tokens - len(live.generated)
+               if live is not None else 0 for live in self._live]
         if self.timeline is not None:
             t0 = time.monotonic()
             occ = s.n_active
@@ -2791,22 +2821,17 @@ class Server:
         # reassign — the host sync (np.asarray below) runs OUTSIDE the
         # lock, so co-located engines' device work overlaps
         self.phases.switch("decode.enqueue")
+        patch = s.decode_patch(rem)
         with self._tree_lock:
-            s.cache, toks, rng = _decode_chunk(
-                self.model, self.params, s.cache,
-                jnp.asarray(s.last_token), jnp.asarray(s.positions()),
-                jnp.asarray(s.temperature), jnp.asarray(s.top_k),
-                jnp.asarray(s.rng),
-                jnp.asarray(rem) if rem is not None else None, table,
+            s.cache, toks, state = _decode_chunk(
+                self.model, self.params, s.cache, s.state, patch, table,
                 n_steps=k, eos_ids=self.eos_ids if freeze else (),
                 freeze=freeze)
+        s.advance(state)
         self.steps += k
         self.dispatches += 1
         self.phases.switch("decode.wait")
         toks = np.asarray(toks)  # [b, k]
-        # np.array, not asarray: device arrays view as read-only and the
-        # next admit writes its slot's key in place
-        s.rng = np.array(rng, np.uint32)
         if self.timeline is not None:
             # duration closes at the host sync (np.asarray above), the
             # latency a request actually experienced; tokens landed are
@@ -3037,7 +3062,7 @@ class Server:
                     hi = max(hi, upto)
             cols = min(_bucket_pow2(-(-hi // s.pool.page_size)),
                        s.max_pages)
-            table = jnp.asarray(s.page_table[:, :cols])
+            table = s.device_table(cols)
         view_tokens = cols * s.pool.page_size if self.paged else 0
         if self.timeline is not None:
             t0 = time.monotonic()
@@ -3045,6 +3070,9 @@ class Server:
             riders = [lv.request.id for lv in self._live
                       if lv is not None]
         self.phases.switch("verify.enqueue")
+        # this round is fed from the mirrors (``s.rng`` pulls the keys
+        # back if a chunk round moved them), and hands the next chunk
+        # round every live row to send (``host_fed`` below)
         with self._tree_lock:
             s.cache, emit, accepted, *cont, rng = _verify_chunk(
                 self.model, self.params, s.cache, jnp.asarray(toks),
@@ -3061,7 +3089,7 @@ class Server:
         self.spec_rounds += 1
         emit = np.asarray(emit)
         accepted = np.asarray(accepted)
-        s.rng = np.array(rng, np.uint32)
+        s.host_fed(rng)
         if self.timeline is not None:
             dur_ms = (time.monotonic() - t0) * 1e3  # closes at the sync
         self.phases.switch("verify.emit")
@@ -3271,6 +3299,14 @@ class Server:
             # 0 — each one is a whole-tree copy on the device
             "kv_tree_donated": self.slots.tree_donated,
             "kv_tree_kept": self.slots.tree_kept,
+            # the decode round's resident state (SlotCache): chunk
+            # rounds, those that sent no state, dirty rows sent, page
+            # tables sent, and copies of the rng keys back to the host
+            "decode_rounds": self.slots.rounds,
+            "decode_rounds_clean": self.slots.rounds_clean,
+            "decode_rows_patched": self.slots.rows_patched,
+            "decode_table_sends": self.slots.table_sends,
+            "decode_rng_pulls": self.slots.rng_pulls,
             "spec_rounds": self.spec_rounds,
             "spec_drafted": self.spec_drafted,
             "spec_accepted": self.spec_accepted,

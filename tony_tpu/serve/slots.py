@@ -1,14 +1,18 @@
 """SlotCache: batch_size resident KV-cache slots + per-slot decode state.
 
 The device side is ONE fixed-shape cache pytree (``init_cache`` at
-``batch_size``) that the resident decode step updates in place; the
-host side is a handful of small per-slot arrays (length, last token,
-sampling knobs, rng) the scheduler reads and writes between steps.
-Admit copies a freshly prefilled single-row cache into a free slot with
-one jitted dynamic-update-slice per leaf (slot index traced — one
-compile total); evict is pure host bookkeeping (the row's stale K/V is
-masked by the slot's length going inactive and fully overwritten by the
-next admit, so no device work is ever spent clearing it).
+``batch_size``) that the resident decode step updates in place, and
+beside it the decode round's small carry (``SlotCache.state``: last
+token, position, budget, sampling knobs, rng key per slot), which the
+step also takes and returns; the host side is a handful of small
+per-slot arrays (length, last token, sampling knobs, rng) the scheduler
+reads and writes between steps, and from which it sends the device only
+the rows it changed itself. Admit copies a freshly prefilled single-row
+cache into a free slot with one jitted dynamic-update-slice per leaf
+(slot index traced — one compile total); evict is pure host
+bookkeeping (the row's stale K/V is masked by the slot's length going
+inactive and fully overwritten by the next admit, so no device work is
+ever spent clearing it).
 """
 
 from __future__ import annotations
@@ -32,7 +36,8 @@ def cache_batch_axis(path, leaf) -> int | None:
     scan_layers models prepend an n_layers axis, which this arithmetic
     skips (keying on axis 0 would slice the LAYERS axis). Index counters
     (cache_index/pos_index) carry no batch dim: per-slot decode neither
-    reads nor advances them (positions live host-side)."""
+    reads nor advances them (positions live per slot, in
+    ``SlotCache``'s mirrors and resident state)."""
     name = str(path[-1].key if hasattr(path[-1], "key") else path[-1])
     if name in ("cached_key", "cached_value"):
         return leaf.ndim - 4
@@ -350,6 +355,41 @@ def paged_write_back(pool: Any, view: Any, table, start, n_steps: int,
     return jax.tree_util.tree_map_with_path(wb, pool, view)
 
 
+# ------------------------------------------------- resident decode state
+
+# columns of the packed per-slot decode state, ``[slots, STATE_COLS]``
+# int32 (``SlotCache.state``); a patch carries one mask column before
+# them. Temperature and the two rng words travel bit-cast.
+_TOK, _POS, _REM, _TOPK, _TEMP, _RNG = 0, 1, 2, 3, 4, 5
+STATE_COLS = 7
+
+
+def unpack_state(state):
+    """``(tok, positions, rem, top_ks, temps, rngs)`` of a packed
+    decode state, in the dtypes the decode body computes in
+    (traceable)."""
+    return (state[:, _TOK], state[:, _POS], state[:, _REM],
+            state[:, _TOPK],
+            jax.lax.bitcast_convert_type(state[:, _TEMP], jnp.float32),
+            jax.lax.bitcast_convert_type(state[:, _RNG:], jnp.uint32))
+
+
+def pack_state(state, tok, positions, rem, rngs):
+    """The successor of ``state`` after a chunk: the carry the scan
+    hands out laid over the columns it owns (the sampling knobs are the
+    host's alone and pass through). Traceable."""
+    return jnp.concatenate(
+        [jnp.stack([tok, positions, rem], axis=1),
+         state[:, _TOPK:_RNG],
+         jax.lax.bitcast_convert_type(rngs, jnp.int32)], axis=1)
+
+
+def apply_patch(state, patch):
+    """Lay the host's patch (``[slots, 1 + STATE_COLS]``: a mask
+    column, then the row) over the resident state: one ``where``."""
+    return jnp.where(patch[:, :1] != 0, patch[:, 1:], state)
+
+
 class PagePool:
     """Block-granular KV-cache pages + a host-side free-list allocator.
 
@@ -558,8 +598,31 @@ class PagePool:
 class SlotCache:
     """``batch_size`` cache slots + per-slot length/rng/EOS-side state.
 
-    Host arrays are numpy (the scheduler mutates them every iteration);
-    the cache pytree stays on device across the whole serve session.
+    The cache pytree stays on device across the whole serve session,
+    and so does the decode round's carry: ``state`` is one packed
+    ``[slots, STATE_COLS]`` int32 device array (last token, position
+    with -1 for an empty slot, remaining budget, ``top_k``, and
+    ``temperature`` and the two rng words bit-cast) that the chunk
+    program takes and returns (``engine._decode_chunk``). It is NOT
+    donated: a dispatch that fails leaves the last version alive.
+
+    The host arrays are numpy and stay the scheduler's truth for
+    everything off the round's critical path (admission, the token
+    walk, the verify round, the migration snapshot, ``/stats``); the
+    engine still updates them from each round's tokens. They feed the
+    program only where the HOST changed a row: ``admit`` and ``evict``
+    (and ``host_fed``, after a round that ran on host-fed inputs) mark
+    the row ``dirty``, and the next chunk round sends ONE packed patch
+    (``decode_patch``) that the program lays over the resident state
+    before its scan; a round with no dirty row sends nothing, it reuses
+    an all-clear patch that already lives on the device. The paged
+    table goes by the same rule (``device_table``: sent again only when
+    the columns the round reads changed). The rng key is the one value
+    the host cannot follow: while a sampled row is clean the device's
+    key is the truth, and reading ``rng`` pulls it back first (a greedy
+    row's key never moves, so a greedy batch never pulls). Counters:
+    ``rounds`` / ``rounds_clean`` / ``rows_patched`` / ``table_sends``
+    / ``rng_pulls``.
 
     With ``pool`` (a ``PagePool``) the cache is PAGED: ``self.cache``
     is the pool's page tree, and each slot additionally owns a page
@@ -614,7 +677,35 @@ class SlotCache:
         self.last_token = np.zeros(batch_size, np.int32)
         self.temperature = np.zeros(batch_size, np.float32)
         self.top_k = np.zeros(batch_size, np.int32)
-        self.rng = np.zeros((batch_size, 2), np.uint32)
+        self._rng = np.zeros((batch_size, 2), np.uint32)
+        # rows whose key the device has moved past the host's copy
+        self._rng_stale = np.zeros(batch_size, bool)
+        # rows the host changed since the last chunk round
+        self.dirty = np.zeros(batch_size, bool)
+        # where the small arrays go, so that the first state, a patch
+        # and a successor all meet ONE executable: what the chunk
+        # program hands back is replicated under a mesh (no round
+        # re-shards it), and otherwise committed to a device exactly
+        # when the parameters are — jit keys its programs on that too
+        leaf = jax.tree_util.tree_leaves(params)[0]
+        if mesh is not None:
+            from tony_tpu.parallel.sharding import replicated
+
+            self._small = replicated(mesh)
+        elif getattr(leaf, "committed", False):
+            self._small = leaf.sharding
+        else:
+            self._small = None
+        self._no_patch = jax.device_put(
+            np.zeros((batch_size, 1 + STATE_COLS), np.int32), self._small)
+        self._table_sent = None  # host copy of the table on the device
+        self._table_dev = None
+        self.state = self._empty_state()
+        self.rounds = 0        # chunk rounds enqueued
+        self.rounds_clean = 0  # ... that sent no state
+        self.rows_patched = 0  # dirty rows sent, summed over rounds
+        self.table_sends = 0   # page tables sent
+        self.rng_pulls = 0     # device-to-host copies of the keys
 
     @property
     def cache(self) -> Any:
@@ -689,7 +780,8 @@ class SlotCache:
         passes None). ``length`` = real prompt length (bucket padding
         beyond it is invisible: masked now, overwritten as the slot
         advances). ``last_token`` is the first sampled continuation —
-        the next step feeds it at position ``length``."""
+        the next step feeds it at position ``length``. The row is
+        marked dirty: the next chunk round sends it to the device."""
         if self.active[slot]:
             raise ValueError(f"slot {slot} is occupied")
         if not 0 < length <= self.max_seq_len:
@@ -705,24 +797,102 @@ class SlotCache:
         self.last_token[slot] = last_token
         self.temperature[slot] = temperature
         self.top_k[slot] = top_k
-        self.rng[slot] = np.asarray(rng_key, np.uint32).reshape(2)
+        self._rng[slot] = np.asarray(rng_key, np.uint32).reshape(2)
+        self._rng_stale[slot] = False
         self.active[slot] = True
+        self.dirty[slot] = True
 
     def evict(self, slot: int) -> None:
-        """Free a slot (EOS / budget exhausted). Device state is left in
+        """Free a slot (EOS / budget exhausted). The K/V is left in
         place — an inactive slot's position is -1, so nothing reads it,
-        and the next admit overwrites the whole row. Paged: the slot's
-        page references are dropped (pages a prefix-store entry also
-        holds stay resident under their remaining refcount) and its
-        unspent reservation is returned."""
+        and the next admit overwrites the whole row; the row of the
+        resident decode state is marked dirty, so that the next chunk
+        round tells the device the slot is empty (a row left live there
+        would go on writing into pages that are no longer its own).
+        Paged: the slot's page references are dropped (pages a
+        prefix-store entry also holds stay resident under their
+        remaining refcount) and its unspent reservation is returned."""
         self.active[slot] = False
         self.lengths[slot] = 0
         self.last_token[slot] = 0
         self.temperature[slot] = 0.0
         self.top_k[slot] = 0
-        self.rng[slot] = 0
+        self._rng[slot] = 0
+        self._rng_stale[slot] = False
+        self.dirty[slot] = True
         if self.pool is not None:
             self.release_pages(slot)
+
+    # ------------------------------------------ resident decode state
+
+    def _empty_state(self):
+        empty = np.zeros((self.batch_size, STATE_COLS), np.int32)
+        empty[:, _POS] = -1
+        return jax.device_put(empty, self._small)
+
+    @property
+    def rng(self) -> np.ndarray:
+        """The per-slot rng keys ``[slots, 2]`` uint32 on the host,
+        pulled back from the device first where a chunk round has
+        advanced them since (one small copy for all rows; none while
+        every live row is greedy)."""
+        if self._rng_stale.any():
+            keys = np.array(self.state)[:, _RNG:].view(np.uint32)
+            self._rng[self._rng_stale] = keys[self._rng_stale]
+            self._rng_stale[:] = False
+            self.rng_pulls += 1
+        return self._rng
+
+    def decode_patch(self, budgets):
+        """What the next chunk round must tell the device: the rows the
+        host changed since the last one, packed from the mirrors and
+        ``budgets`` (remaining tokens, one per slot) behind a mask column —
+        or, with no such row, the all-clear patch that already lives
+        there (nothing is sent). ``advance`` closes the round."""
+        if not self.dirty.any():
+            return self._no_patch
+        patch = np.empty((self.batch_size, 1 + STATE_COLS), np.int32)
+        patch[:, 0] = self.dirty
+        row = patch[:, 1:]
+        row[:, _TOK] = self.last_token
+        row[:, _POS] = self.positions()
+        row[:, _REM] = budgets
+        row[:, _TOPK] = self.top_k
+        row[:, _TEMP] = self.temperature.view(np.int32)
+        row[:, _RNG:] = self._rng.view(np.int32)
+        return jax.device_put(patch, self._small)
+
+    def advance(self, state) -> None:
+        """A chunk round was enqueued on ``decode_patch``'s patch and
+        handed back ``state``: it is the resident state now, every row
+        is clean, and a live sampled row's key has moved on the
+        device."""
+        n = int(self.dirty.sum())
+        self.rounds += 1
+        self.rounds_clean += n == 0
+        self.rows_patched += n
+        self.dirty[:] = False
+        self._rng_stale |= self.active & (self.temperature > 0.0)
+        self.state = state
+
+    def host_fed(self, rng) -> None:
+        """A round ran on inputs made from the mirrors and handed back
+        ``rng`` (the verify round): the host is the truth for every
+        live row again, and the next chunk round sends them."""
+        self._rng = np.array(rng, np.uint32)  # a copy: admit writes it
+        self._rng_stale[:] = False
+        self.dirty |= self.active
+
+    def device_table(self, cols: int):
+        """``page_table[:, :cols]`` on the device: sent only when those
+        columns, or ``cols``, changed since the last send."""
+        want = self.page_table[:, :cols]
+        if self._table_sent is None \
+                or not np.array_equal(self._table_sent, want):
+            self._table_sent = want.copy()
+            self._table_dev = jax.device_put(self._table_sent, self._small)
+            self.table_sends += 1
+        return self._table_dev
 
     # --------------------------------------------------- paged helpers
 
@@ -803,6 +973,10 @@ class SlotCache:
 
     def reset(self) -> None:
         """Evict everything (a fresh serving session on the same cache
-        allocation — no reallocation, no recompile)."""
+        allocation — no reallocation, no recompile). The resident state
+        is made anew, all slots empty: a failed dispatch may have left
+        a successor that can never be read."""
         for i in range(self.batch_size):
             self.evict(i)
+        self.state = self._empty_state()
+        self.dirty[:] = False
