@@ -12,7 +12,6 @@
 #                 proxy, profiler
 #   make check  - lint + smoke (the pre-commit gate)
 #   make test   - the full suite (~15-20 min on a 1-core box)
-#   make bench  - the driver-contract benchmark (one JSON line)
 #   make serve-smoke - boot a tiny-model gateway, concurrent curl
 #                 clients (unary + streaming), a /metrics exposition +
 #                 /debug/trace + on-demand profile observability round,
@@ -29,7 +28,7 @@
 
 PY ?= python
 
-LINT_PATHS = tony_tpu tests examples tools bench.py __graft_entry__.py
+LINT_PATHS = tony_tpu tests examples tools __graft_entry__.py
 
 SMOKE_TESTS = tests/test_config.py tests/test_session.py \
 	tests/test_scheduler.py tests/test_rpc.py tests/test_events.py \
@@ -107,7 +106,7 @@ SMOKE_TESTS = tests/test_config.py tests/test_session.py \
 #     GET /v1/stream/<id>?offset=0 vs a never-crashed control — zero
 #     5xx after restart, clean drain compacts the journal to empty
 
-.PHONY: lint smoke check test bench serve-smoke chaos-smoke \
+.PHONY: lint smoke check test serve-smoke chaos-smoke \
 	autoscale-smoke goodput-smoke remote-smoke disagg-smoke \
 	autotune-smoke shard-smoke bundle-smoke storm-smoke \
 	migrate-smoke rebalance-smoke recovery-smoke
@@ -128,9 +127,6 @@ check: lint smoke
 
 test:
 	$(PY) -m pytest tests/ -q
-
-bench:
-	$(PY) bench.py
 
 serve-smoke:
 	PY=$(PY) sh tools/serve_smoke.sh

@@ -64,8 +64,8 @@ PHASES = {  # chips -> [(phase, its time limit in seconds)]
     4: [("mesh_serve", 780), ("dp_train", 360)],
 }
 
-# The two models the repo's records are about (bench.py: bench_decode_1b,
-# flagship_lm_setup; ROADMAP S1's first cells), and their toy twins for
+# The two models the repo's first records were about (ROADMAP S1's first
+# cells), and their toy twins for
 # the rehearsal. Prompt lengths span three prefill buckets.
 SERVE_MODEL = dict(vocab_size=32768, d_model=2048, n_layers=20, n_heads=16,
                    n_kv_heads=8, d_ff=8192, max_seq_len=2048)
@@ -301,7 +301,7 @@ class _CompileMeter:
 
 def _seeded_lm(cfg_kw: dict, seed: int, dtype=None):
     """``Transformer`` + parameters initialised ON the device by one
-    jitted program (and cast there), as bench.py's 1B section does —
+    jitted program (and cast there) —
     the gateway CLI itself only loads a checkpoint directory."""
     import jax
     import jax.numpy as jnp

@@ -12,8 +12,7 @@ latest checkpoint automatically (fit() reads TONY_CHECKPOINT_DIR).
     python -m tony_tpu.cli.local --conf_file examples/lm-pretrain/job.toml
 
 The model's sizes are arguments; the defaults are a CPU-sized toy. The
-386M flagship (bench.py ``flagship_lm_setup``; what ``chip_smoke.py``
-submits to a TPU) is::
+386M flagship (what ``chip_smoke.py`` submits to a TPU) is::
 
     --vocab 32768 --d-model 1024 --n-layers 28 --n-heads 8 --n-kv-heads 8
     --d-ff 4096 --seq-len 2048 --attention pallas --block-q 512

@@ -49,24 +49,24 @@ def _device_rows(srv) -> dict:
 # ------------------------------------------- (1) the state follows the host
 
 
-@pytest.mark.parametrize("chunk_steps", [1, 4])
-@pytest.mark.parametrize("freeze", [True, False])
+@pytest.mark.parametrize("chunk_steps", [1, 2, 4, 8])
 @pytest.mark.parametrize("paged", [True, False])
 def test_clean_rows_equal_the_mirrors_after_every_round(
-        tiny, paged, freeze, chunk_steps):
+        tiny, paged, chunk_steps):
     """After every round, for every live row the host has not touched
     since, the device's last token, position and budget are the
     mirrors' — staggered budgets and a late arrival, so rows are
-    admitted and evicted around the ones compared."""
+    admitted and evicted around the ones compared; at every depth the
+    scheduler compiles up to ``Server``'s default of 8."""
     model, params = tiny
     srv = Server(model, params, batch_size=3, paged=paged,
-                 in_dispatch_eos=freeze, chunk_steps=chunk_steps,
+                 chunk_steps=chunk_steps,
                  kv_page_size=8 if paged else 0)
-    for i, budget in enumerate([9, 30, 17]):
+    for i, budget in enumerate([9, 48, 30]):
         srv.submit(Request(_prompt(i), budget, id=i,
                            temperature=0.7 * (i == 1), top_k=5, seed=3))
     compared = 0
-    for it in range(60):
+    for it in range(80):
         if it == 3:
             srv.submit(Request(_prompt(9), 12, id="late"))
         srv.step()
@@ -78,9 +78,8 @@ def test_clean_rows_equal_the_mirrors_after_every_round(
             assert dev["pos"][slot] == s.positions()[slot]
             assert dev["top_k"][slot] == s.top_k[slot]
             assert dev["temp"][slot] == s.temperature[slot]
-            if freeze:
-                assert dev["rem"][slot] == \
-                    live.request.max_new_tokens - len(live.generated)
+            assert dev["rem"][slot] == \
+                live.request.max_new_tokens - len(live.generated)
             compared += 1
         if srv.done:
             break
@@ -201,14 +200,15 @@ def test_snapshot_mid_stream_carries_the_device_key(tiny, after):
 # ------------------------------- (5) chunk and verify rounds hand over whole
 
 
-@pytest.mark.parametrize("freeze", [True, False])
+@pytest.mark.parametrize("chunk_steps", [1, 2])
 @pytest.mark.parametrize("paged", [True, False])
 def test_chunk_and_verify_rounds_interleaved_give_the_plain_stream(
-        tiny, paged, freeze):
+        tiny, paged, chunk_steps):
     """A repetitive greedy prompt drafts (verify rounds), a random
     greedy one and a sampled one ride along: rounds of both kinds
     alternate, each handing the per-slot values to the other, and every
-    stream is the one speculation-off gives."""
+    stream is the one speculation-off gives. Depth 1 is what
+    ``--speculate-k`` meets under the gateway's ``--chunk-steps 1``."""
     model, params = tiny
 
     def drive(srv) -> tuple:
@@ -232,11 +232,7 @@ def test_chunk_and_verify_rounds_interleaved_give_the_plain_stream(
                 break
         return got, kinds
 
-    # without the fused round a verify round advances a non-drafting
-    # co-tenant by one token, and the batch-drag gate refuses it where
-    # a chunk would yield more: at depth 1 it never does
-    kw = dict(batch_size=3, paged=paged, in_dispatch_eos=freeze,
-              chunk_steps=2 if freeze else 1)
+    kw = dict(batch_size=3, paged=paged, chunk_steps=chunk_steps)
     plain, _ = drive(Server(model, params, **kw))
     srv = Server(model, params, speculate_k=3, **kw)
     got, kinds = drive(srv)

@@ -437,3 +437,18 @@ def test_gateway_cli_e2e_concurrent_and_sigterm(tmp_path):
     finally:
         if proc.poll() is None:
             proc.kill()
+
+
+@pytest.mark.parametrize("cli", ["gateway", "replica"])
+def test_retired_flags_are_refused(cli, capsys):
+    """``--no-in-dispatch-eos`` went with the arm it chose: a launcher
+    that still passes it is told so (exit 2), not obeyed in silence by
+    an engine that has no such arm."""
+    import importlib
+
+    parser = importlib.import_module(f"tony_tpu.cli.{cli}").build_parser()
+    parser.parse_args(["--demo-model"])
+    with pytest.raises(SystemExit) as exc:
+        parser.parse_args(["--demo-model", "--no-in-dispatch-eos"])
+    assert exc.value.code == 2
+    assert "--no-in-dispatch-eos" in capsys.readouterr().err

@@ -195,36 +195,11 @@ def test_ledger_reconciles_with_timeline_and_counters(tiny, paged):
     assert led["utilization"][step_kind]["est_bytes"] > 0
 
 
-def test_overshoot_bucket_charges_trimmed_chunk_time(tiny):
-    """A slot finishing mid-chunk decodes trimmed garbage to the chunk
-    end — the `wasted_steps` counter as TIME, pinned on the
-    in_dispatch_eos=False control: a steady k=4 chunk round with a
-    budget-3 co-tenant must charge the overshoot bucket, and the
-    position accounting must equal the counter exactly."""
-    model, params = tiny
-    server = Server(model, params, batch_size=2, eos_id=-1,
-                    chunk_steps=4, in_dispatch_eos=False)
-
-    def run_pair(base):
-        list(server.run([Request([1, 2, 3], 3, id=base),
-                         Request([4, 5, 6], 9, id=base + 1)]))
-
-    run_pair(0)   # first pass pays the compiles
-    run_pair(10)  # steady: the budget-3 slot overshoots the k=4 chunk
-    assert server.wasted_steps > 0
-    summ = server.timeline.summary()
-    assert summ["decode"]["fed"] - summ["decode"]["tokens"] \
-        == server.wasted_steps
-    led = server.goodput()
-    assert led["ms"]["overshoot"] > 0
-
-
 def test_in_dispatch_eos_zeroes_the_overshoot_bucket(tiny):
-    """ISSUE-13: the same mixed-budget workload under the default
-    in-dispatch EOS freeze lands ZERO overshoot — fed == landed on
-    every decode dispatch, the trailing positions are frozen re-emits
-    charged to padding, and the reconciliation pins hold without
-    loosening (wasted_steps stays exactly sum(fed - tokens) == 0)."""
+    """A mixed-budget workload (a budget-3 slot beside a k=4 chunk
+    round) lands ZERO overshoot — fed == landed on every decode
+    dispatch, the trailing positions are frozen re-emits charged to
+    padding, and wasted_steps stays exactly sum(fed - tokens) == 0."""
     model, params = tiny
     server = Server(model, params, batch_size=2, eos_id=-1,
                     chunk_steps=4)

@@ -81,7 +81,7 @@ _REPEAT = [[5, 6, 7, 8] * 5, [9, 10, 11] * 6]
     ({"paged": True, "prefill_chunk_tokens": 8, "min_bucket": 8},
      _prompts(2), {"prefill_chunk", "prefill", "decode"}),
     ({"paged": True, "speculate_k": 3}, _REPEAT, {"verify"}),
-    ({"paged": True, "speculate_k": 3, "in_dispatch_eos": False},
+    ({"paged": True, "speculate_k": 3, "chunk_steps": 1},
      _REPEAT, {"verify"}),
     # a store squeezed to ~2 entries spills to the host tier; the
     # repeats page back in through _scatter_pages
@@ -96,7 +96,7 @@ _REPEAT = [[5, 6, 7, 8] * 5, [9, 10, 11] * 6]
     ({"paged": False, "prefix_cache_mb": 1.0},
      (lambda d: d + d)(_prompts(2, seed=3)), {"hit_admit", "decode"}),
     ({"paged": False, "speculate_k": 3}, _REPEAT, {"verify"}),
-], ids=["paged", "paged-chunked", "paged-verify-fused", "paged-verify",
+], ids=["paged", "paged-chunked", "paged-verify", "paged-verify-depth1",
         "paged-page-in", "rows", "rows-chunked", "rows-prefix-hit",
         "rows-verify"])
 def test_every_writer_consumes_the_tree(tiny, kwargs, prompts, kinds):
@@ -132,8 +132,7 @@ def test_every_cache_leaf_aliases(program, scan_layers, kv_int8):
     if program == "decode":
         lowered = E._decode_chunk.lower(
             model, params, cache, i32(b, STATE_COLS),
-            i32(b, 1 + STATE_COLS), table, n_steps=4, eos_ids=(2,),
-            freeze=True)
+            i32(b, 1 + STATE_COLS), table, n_steps=4, eos_ids=(2,))
     elif program == "verify":
         lowered = E._verify_chunk.lower(
             model, params, cache, i32(b, 3), i32(b, 3), i32(b),

@@ -237,16 +237,3 @@ def test_tree_shard_bytes_counts_per_chip(mesh4):
     # sharded leaf contributes 1/4, replicated leaf its whole size
     assert tree_shard_bytes(params, sh) == (8 * 16 // 4 + 6 * 2) * 4
     assert tree_shard_count(params, sh) == 8 * 16 // 4 + 6 * 2
-
-
-def test_int8_kv_flash_bytes_ratio_still_below_one(tiny):
-    """The r13 regression sensor must keep pinning bytes < 1 after the
-    BlockSpec relayout (the kernel-shape suspect is what changed; the
-    read set did not grow)."""
-    from bench import _int8_kv_flash_bytes
-
-    model, params = tiny
-    out = _int8_kv_flash_bytes(model.cfg, params, batch=8,
-                               cache_tokens=512)
-    assert out["int8_kv_flash_bytes_ratio"] < 1.0, out
-    assert out["int8_kv_flash_verdict"] == "dispatch", out

@@ -202,7 +202,7 @@ def test_greedy_parity_spec_on_off_mixed_batch(tiny):
         assert rn[rid][0] == _solo(model, params, p, n), rid
 
 
-@pytest.mark.slow  # the slow bench datum below asserts the same bound
+@pytest.mark.slow
 def test_spec_reduces_dispatches_and_is_exact(tiny):
     """On a repetitive workload at chunk_steps=1 (the streaming
     default) speculation must strictly reduce decode dispatches while
@@ -461,35 +461,24 @@ def test_counters_and_result_fields(tiny):
 
 
 def test_wasted_steps_counts_chunk_overshoot(tiny):
-    """The decode-step utilization satellite, both modes: with
-    in-dispatch EOS OFF (the pre-ISSUE-13 control) a slot finishing
-    mid-chunk decodes garbage until the chunk ends and the trimmed
-    slot-steps surface in counters(); with it ON (the default) the
-    same workload freezes the slot in-dispatch — zero wasted_steps,
-    the trailing positions counted as frozen re-emits instead, and
-    identical outputs. (A SOLO short request never overshoots —
-    _chunk_size bounds the chunk by the max remaining budget — so the
-    waste needs a mixed-budget batch.)"""
+    """A slot finishing mid-chunk freezes in-dispatch: zero
+    wasted_steps, the trailing positions counted as frozen re-emits,
+    and each stream the one a solo run gives. (A SOLO short request
+    never meets a tail — _chunk_size bounds the chunk by the max
+    remaining budget — so this needs a mixed-budget batch.)"""
     model, params = tiny
-
-    def reqs():
-        # budgets 3 and 10, chunk 8: the long slot forces k=8; the
-        # short one consumes 2 decode tokens (1 came at admit) and
-        # trims/freezes 6
-        return [Request([1, 2, 3], max_new_tokens=3, id="w"),
-                Request([5, 9], max_new_tokens=10, id="l")]
-
-    legacy, res_legacy = _run(model, params, reqs(), batch_size=2,
-                              chunk_steps=8, in_dispatch_eos=False)
-    assert len(res_legacy) == 2
-    assert legacy.wasted_steps == 6
-    assert legacy.counters()["wasted_steps"] == 6
-    assert legacy.frozen_steps == 0
-
-    frozen, res_frozen = _run(model, params, reqs(), batch_size=2,
+    # budgets 3 and 10, chunk 8: the long slot forces k=8; the short
+    # one consumes 2 decode tokens (1 came at admit) and freezes 6
+    reqs = [Request([1, 2, 3], max_new_tokens=3, id="w"),
+            Request([5, 9], max_new_tokens=10, id="l")]
+    frozen, res_frozen = _run(model, params, reqs, batch_size=2,
                               chunk_steps=8)
-    assert res_frozen == res_legacy
+    assert len(res_frozen) == 2
+    for r in reqs:
+        assert res_frozen[r.id][0] == _solo(model, params, r.prompt,
+                                            r.max_new_tokens), r.id
     assert frozen.wasted_steps == 0
+    assert frozen.counters()["wasted_steps"] == 0
     assert frozen.frozen_steps == 6
     assert frozen.freeze_faults == 0
     assert frozen.counters()["frozen_steps"] == 6
@@ -497,8 +486,8 @@ def test_wasted_steps_counts_chunk_overshoot(tiny):
 
 def test_wasted_steps_counts_rejected_drafts(tiny, monkeypatch):
     """The utilization counter's speculation side: draft positions the
-    verify pass scored and rejected are decoded-and-thrown-away work,
-    reported next to chunk overshoot (bench_spec's wasted_steps_on)."""
+    verify pass scored and rejected are decoded-and-thrown-away work
+    (``wasted_steps``)."""
     import tony_tpu.serve.engine as eng
 
     model, params = tiny
@@ -515,41 +504,13 @@ def test_wasted_steps_counts_rejected_drafts(tiny, monkeypatch):
     assert server.wasted_steps == server.spec_drafted
 
 
-def test_batch_drag_gate_prefers_chunks(tiny):
-    """A lone drafter must not drag a mixed batch to one token per
-    dispatch in the UNFUSED (in_dispatch_eos=False) path: at
-    chunk_steps=8 the expected verify yield (2 slots + a 4-token
-    draft) never beats the 16-token chunk dispatch, so the gate keeps
-    every round on the chunk path — speculation-on costs exactly
-    speculation-off plus the host-side lookups. The co-tenant is
-    SAMPLED (greedy cycles of the tiny model would start hitting the
-    lookup and make it a second drafter). The fused default needs no
-    gate — every slot decodes the full chunk inside the verify
-    dispatch — which test_fused_round_never_drags pins."""
-    model, params = tiny
-
-    def reqs():
-        # budget 17 = 1 admit token + chunks of 8 + 8: no shrunken
-        # tail chunk where the gate would (correctly) flip to verify
-        return [Request(list(REP), max_new_tokens=17, id="rep"),
-                Request([7, 9, 11], max_new_tokens=17, temperature=0.8,
-                        top_k=8, seed=3, id="samp")]
-
-    off, ro = _run(model, params, reqs(), batch_size=2, chunk_steps=8,
-                   in_dispatch_eos=False)
-    on, rn = _run(model, params, reqs(), batch_size=2, chunk_steps=8,
-                  speculate_k=4, in_dispatch_eos=False)
-    assert rn == ro
-    assert on.spec_rounds == 0
-    assert on.dispatches == off.dispatches
-
-
 def test_fused_round_never_drags(tiny):
-    """The ISSUE-13 fused speculation round replaces the drag gate:
-    the same lone-drafter mixed batch now SPECULATES — the sampled
-    co-tenant decodes its full chunk inside the fused dispatch, so
-    speculation-on needs no more dispatches than speculation-off (and
-    strictly fewer whenever drafts land), with outputs identical."""
+    """A lone drafter beside a SAMPLED co-tenant (greedy cycles of the
+    tiny model would start hitting the lookup and make it a second
+    drafter) SPECULATES — the co-tenant decodes its full chunk inside
+    the verify dispatch, so speculation-on needs no more dispatches
+    than speculation-off (and strictly fewer whenever drafts land),
+    with outputs identical."""
     model, params = tiny
 
     def reqs():
@@ -561,7 +522,7 @@ def test_fused_round_never_drags(tiny):
     on, rn = _run(model, params, reqs(), batch_size=2, chunk_steps=8,
                   speculate_k=4)
     assert rn == ro
-    assert on.spec_rounds > 0  # the gate is gone: drafts verify
+    assert on.spec_rounds > 0
     # every fused round lands >= 1 + chunk tokens per live slot where
     # a chunk round lands exactly chunk — so dispatches never grow by
     # more than the one tail round the accepted drafts can desync off
@@ -602,16 +563,3 @@ def test_gateway_threads_spec_stats(tiny):
         assert "wasted_steps" in snap["engine"]
     finally:
         gw.drain(timeout=60)
-
-
-@pytest.mark.slow  # bench-shaped; tier-1 runs -m 'not slow'
-def test_bench_spec_datum(tiny):
-    """The bench.py extras.spec claim at test scale: on the repetitive
-    workload speculation reduces decode dispatches (>= 1x asserted; the
-    bench records the measured ratio) with outputs identical."""
-    from bench import bench_spec
-
-    datum = bench_spec(on_tpu=False)
-    assert datum["outputs_identical"]
-    assert datum["dispatch_ratio"] >= 1.0, datum
-    assert datum["acceptance_rate"] > 0

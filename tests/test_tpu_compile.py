@@ -69,7 +69,7 @@ def _assert_kernel(hlo: str, n: int = 1):
 
 
 # flagship training shapes: micro-batch 4, seq 2048, 8 heads x 128,
-# block_q 512 / block_k 1024 (bench.py flagship_lm_setup)
+# block_q 512 / block_k 1024 (what chip_smoke.py's TRAIN_MODEL trains)
 _FLAG = S((4, 2048, 8, 128), BF16)
 # the 0.99B serving model's attention: 16 q / 8 kv heads x 128
 _GQA_Q, _GQA_KV = S((2, 2048, 16, 128), BF16), S((2, 2048, 8, 128), BF16)
@@ -251,7 +251,7 @@ def test_serving_step_writes_the_page_pool_in_place(topo, tpu_compile,
         lowered = engine._decode_chunk.lower(
             model, params, pool, A((b, STATE_COLS)),
             A((b, 1 + STATE_COLS)), A((b, cols)), n_steps=2,
-            eos_ids=(2,), freeze=True)
+            eos_ids=(2,))
     else:
         lowered = engine._paged_prefill_admit.lower(
             model, params, pool, A((1, 128)), A((1, 128)), A(()),

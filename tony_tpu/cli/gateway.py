@@ -370,11 +370,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--rebalance-cooldown", type=float, default=5.0,
                    help="lockout after a successful move (s); a move "
                         "that found no victim waits twice as long")
-    p.add_argument("--no-in-dispatch-eos", action="store_true",
-                   help="disable the in-dispatch EOS/refill freeze "
-                   "(ISSUE-13) and fused speculation rounds — the "
-                   "pre-freeze engine behavior, kept as an A/B "
-                   "control; costs chunk overshoot at depth")
     p.add_argument("--autotune", action="store_true",
                    help="arm the ledger-driven adaptive shape "
                    "controller (serve/autotune.py): steers "
@@ -586,8 +581,6 @@ def server_factory(args, model, params, eos):
                       prefill_chunk_tokens=getattr(
                           args, "prefill_chunk_tokens", 0),
                       kv_host_mb=kv_host_mb,
-                      in_dispatch_eos=not getattr(
-                          args, "no_in_dispatch_eos", False),
                       mesh=mesh,
                       shard_rules=getattr(args, "shard_rules", "serve"),
                       page_pool=pool,
@@ -660,8 +653,6 @@ def agent_argv(args, index: int) -> list:
                  os.path.join(args.profile_dir, f"agent-{index}")]
     if args.no_paged_kv:
         argv.append("--no-paged-kv")
-    if getattr(args, "no_in_dispatch_eos", False):
-        argv.append("--no-in-dispatch-eos")
     if args.demo_model:
         argv.append("--demo-model")
     else:

@@ -57,7 +57,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "engine (count or 'tensor=N,expert=M'; see "
                         "cli.gateway --mesh)")
     p.add_argument("--shard-rules", default="serve")
-    p.add_argument("--no-in-dispatch-eos", action="store_true")
     p.add_argument("--max-pending", type=int, default=1024)
     p.add_argument("--eos-id", type=int, default=-1)
     p.add_argument("--dtype", choices=("fp32", "bf16"), default="fp32")

@@ -51,9 +51,9 @@ def _floor6(v: float) -> float:
     return math.floor(max(0.0, v) * 1e6) / 1e6
 
 
-# chip tables shared with bench.py (single source): peak bf16 FLOP/s
-# and HBM bandwidth per chip, keyed by substring of the accelerator
-# name (TPU_ACCELERATOR_TYPE or jax device_kind, lowercased)
+# chip tables: peak bf16 FLOP/s and HBM bandwidth per chip, keyed by
+# substring of the accelerator name (TPU_ACCELERATOR_TYPE or jax
+# device_kind, lowercased)
 PEAK_BF16_TABLE = (
     ("v6e", 918e12), ("trillium", 918e12), ("v5p", 459e12),
     ("v5litepod", 197e12), ("v5 lite", 197e12), ("v5e", 197e12),

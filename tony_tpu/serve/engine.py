@@ -347,18 +347,17 @@ def _sample_rows(logits, rngs, temps, top_ks):
 
 
 def _frozen_body(model, params, temps, top_ks, eos_ids: tuple):
-    """The in-dispatch-EOS decode micro-step (ISSUE-13): the scan body
-    shared by ``_decode_chunk`` (freeze mode) and ``_verify_chunk``'s
-    fused continuation. Carry is ``(cache, tok, positions, rngs, done,
-    rem)``; a row whose emitted token hit EOS — or whose remaining
-    budget ``rem`` ran out — FREEZES: its later micro-steps write to
-    the dropped sentinel position (no KV bytes land), take the greedy
-    sampling path (no rng advance — a frozen sampled row must not
-    move any draw chain), and re-emit the frozen token, so the host's
-    trim walk degenerates to a consistency check and the trailing
-    positions land as padding, not overshoot. A row that never
-    freezes runs EXACTLY the pre-freeze body (every ``where`` is
-    identity), which is what keeps chunk-invariance bitwise."""
+    """The decode micro-step: the scan body of ``_decode_chunk`` and of
+    ``_verify_chunk``'s continuation. Carry is ``(cache, tok,
+    positions, rngs, done, rem)``; a row whose emitted token hit EOS —
+    or whose remaining budget ``rem`` ran out — FREEZES: its later
+    micro-steps write to the dropped sentinel position (no KV bytes
+    land), take the greedy sampling path (no rng advance — a frozen
+    sampled row must not move any draw chain), and re-emit the frozen
+    token, so the host's walk past a finish is a consistency check and
+    the trailing positions land as padding. For a row that never
+    freezes every ``where`` is the identity, which is what keeps
+    chunk-invariance bitwise."""
     def body(carry, _):
         cache, tok, positions, rngs, done, rem = carry
         eff_pos = jnp.where(done, -1, positions)
@@ -377,10 +376,10 @@ def _frozen_body(model, params, temps, top_ks, eos_ids: tuple):
 
 
 @functools.partial(jax.jit, static_argnames=("model", "n_steps",
-                                             "eos_ids", "freeze"),
+                                             "eos_ids"),
                    donate_argnames=("cache",))
 def _decode_chunk(model, params, cache, state, patch, table=None, *,
-                  n_steps: int, eos_ids: tuple = (), freeze: bool = False):
+                  n_steps: int, eos_ids: tuple = ()):
     """The resident serving step: ``n_steps`` decode micro-steps for
     EVERY slot as one lax.scan dispatch (empty slots compute garbage
     that nothing reads — the price of a never-recompiled static shape).
@@ -410,15 +409,12 @@ def _decode_chunk(model, params, cache, state, patch, table=None, *,
     all-clear patch and the table are used again, and a dispatch that
     fails must leave the last state alive.
 
-    ``freeze`` (the ISSUE-13 in-dispatch EOS mode, the engine default)
-    threads a per-slot ``done`` flag + the remaining budget through the
-    scan (``_frozen_body``): a slot that samples EOS or exhausts its
-    budget mid-chunk stops writing K/V (sentinel position), stops
-    advancing rng, and re-emits its final token — so ``chunk_steps``
-    can grow without the trailing positions becoming the ``overshoot``
-    waste bucket, and the host trim becomes a consistency check.
-    ``eos_ids`` is static per engine (one compile). Without it the
-    budget column is carried through unread.
+    The scan threads a per-slot ``done`` flag and the remaining budget
+    (``_frozen_body``): a slot that samples EOS or exhausts its budget
+    mid-chunk stops writing K/V (sentinel position), stops advancing
+    rng, and re-emits its final token — so a deep chunk decodes
+    nothing past a finish. ``eos_ids`` is static per engine (one
+    compile).
 
     ``table`` [b, cols] switches to the paged cache layout — but
     NOT by gathering inside every micro-step: the slot view is
@@ -438,30 +434,15 @@ def _decode_chunk(model, params, cache, state, patch, table=None, *,
     if table is not None:
         cache = paged_view(cache, table, max_len)
 
-    if freeze:
-        body = _frozen_body(model, params, temps, top_ks, eos_ids)
-        carry = (cache, tok, positions, rngs, positions < 0, rem)
-    else:
-        def body(carry, _):
-            cache, tok, positions, rngs = carry
-            cache, last = single_decode_step(model, params, cache, tok,
-                                             positions=positions)
-            nxt, rngs = _sample_rows(last, rngs, temps, top_ks)
-            nxt = nxt.astype(jnp.int32)
-            positions = jnp.where(positions >= 0, positions + 1,
-                                  positions)
-            return (cache, nxt, positions, rngs), nxt
-
-        carry = (cache, tok, positions, rngs)
+    body = _frozen_body(model, params, temps, top_ks, eos_ids)
+    carry = (cache, tok, positions, rngs, positions < 0, rem)
     if n_steps > 1:
         carry, toks = jax.lax.scan(body, carry, None, length=n_steps)
         toks = jnp.moveaxis(toks, 0, 1)  # [steps, b] -> [b, steps]
     else:
         carry, tok1 = body(carry, None)
         toks = tok1[:, None]
-    cache, tok, positions, rngs = carry[:4]
-    if freeze:
-        rem = carry[5]
+    cache, tok, positions, rngs, _, rem = carry
     if table is not None:
         cache = paged_write_back(pool_cache, cache, table, start,
                                  n_steps, max_len)
@@ -476,8 +457,8 @@ def _decode_chunk(model, params, cache, state, patch, table=None, *,
                                              "n_steps", "eos_ids"),
                    donate_argnames=("cache",))
 def _verify_chunk(model, params, cache, toks, positions, draft_len,
-                  temps, top_ks, rngs, rem=None, table=None, *,
-                  window: int, n_steps: int = 0, eos_ids: tuple = ()):
+                  temps, top_ks, rngs, rem, table=None, *,
+                  window: int, n_steps: int, eos_ids: tuple = ()):
     """The speculative verify dispatch: score ``window`` positions for
     EVERY slot in one batched multi-token pass (multi_decode_step) and
     judge each row's draft against its own greedy verdicts — the
@@ -486,7 +467,8 @@ def _verify_chunk(model, params, cache, toks, positions, draft_len,
     Row layout: ``toks[i] = [last_token, draft_1..draft_d, pad...]``
     at ``positions[i] = [p, p+1, .., p+d, -1...]`` (``d`` =
     ``draft_len[i]``; padding writes drop, padding logits are junk).
-    Returns ``(cache, emit [b, window], accepted [b], rngs)``:
+    Returns ``(cache, emit [b, window], accepted [b], cont [b,
+    n_steps], rngs)``:
 
     - ``emit[i, 0]`` is the token following ``last_token`` under the
       row's OWN sampling knobs (_sample_rows: argmax for greedy rows,
@@ -505,32 +487,27 @@ def _verify_chunk(model, params, cache, toks, positions, draft_len,
       decodes on.
 
     ``window`` is static and power-of-two-plus-one bucketed, so at most
-    log2(speculate_k)+1 verify programs ever compile. ``table``
-    [b, max_pages] switches to the paged cache layout (pre-extended by
-    the host to cover the window's writes).
+    log2(speculate_k)+1 verify programs ever compile per depth.
 
-    ``n_steps`` > 0 is the FUSED speculation round (ISSUE-13): the
-    same dispatch (a) caps ``accepted`` at the first emitted stop
+    The same dispatch (a) caps ``accepted`` at the first emitted stop
     token, so a mid-window EOS costs zero bonus-past-finish waste,
-    and (b) runs ``n_steps`` ``_frozen_body`` decode micro-steps
-    CONTINUING from each row's own bonus verdict — the chunk dispatch
-    that used to follow every verify round rides inside it, so a
-    speculating round costs ONE dispatch for accepted+1+n_steps
-    tokens instead of two dispatches. Paged mode then works like the
-    chunk path: ONE ``paged_view`` gather feeds both the window pass
-    and the continuation scan, and ``paged_write_back`` returns the
-    whole written span (positions the row never wrote copy their own
-    gathered content back — identity). Returns ``(cache, emit,
-    accepted, cont [b, n_steps], rngs)``."""
+    and (b) runs ``n_steps`` >= 1 ``_frozen_body`` decode micro-steps
+    CONTINUING from each row's own bonus verdict (``rem`` [b] is each
+    row's remaining budget before the window): a speculating round
+    costs ONE dispatch for accepted+1+n_steps tokens, and a
+    non-drafting co-tenant still advances a chunk's worth. ``table``
+    [b, cols] switches to the paged cache layout (pre-extended by the
+    host to cover window + continuation) and works like the chunk
+    path: ONE ``paged_view`` gather feeds both the window pass and the
+    continuation scan, and ``paged_write_back`` returns the whole
+    written span (positions the row never wrote copy their own
+    gathered content back — identity)."""
     max_len = model.cfg.max_seq_len
     pool_cache, start = cache, positions[:, 0]
-    if n_steps > 0 and table is not None:
+    if table is not None:
         cache = paged_view(cache, table, max_len)
-        step_table = None
-    else:
-        step_table = table
     cache, logits = multi_decode_step(model, params, cache, toks,
-                                      positions, page_table=step_table)
+                                      positions)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [b, w]
     tok0, rngs = _sample_rows(logits[:, 0], rngs, temps, top_ks)
     emit = jnp.concatenate([tok0[:, None].astype(jnp.int32),
@@ -539,8 +516,6 @@ def _verify_chunk(model, params, cache, toks, positions, draft_len,
     match = (toks[:, 1:] == greedy[:, :-1]) & (j < draft_len[:, None])
     accepted = jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=1),
                        axis=1)
-    if n_steps == 0:
-        return cache, emit, accepted, rngs
     # EOS-capped acceptance: the host appends emit[:accepted + 1] and
     # stops at the first stop token — capping accepted AT that index
     # makes the device and host agree that nothing past it was ever
@@ -551,11 +526,11 @@ def _verify_chunk(model, params, cache, toks, positions, draft_len,
         first_stop = jnp.min(jnp.where(_is_eos(emit, eos_ids), idx,
                                        window), axis=1)
         accepted = jnp.minimum(accepted, first_stop)
-    # fused continuation: each row resumes from its own bonus verdict
-    # at its own position, with the frozen-body discipline bounding
+    # the continuation: each row resumes from its own bonus verdict at
+    # its own position, with the frozen-body discipline bounding
     # EOS/budget — live rows decode n_steps more real tokens in THIS
     # dispatch, so non-drafting co-tenants are never dragged to one
-    # token per round (the old batch-drag-gate failure mode)
+    # token per round
     rows = jnp.arange(toks.shape[0])
     bonus = emit[rows, accepted]
     live = start >= 0
@@ -774,10 +749,9 @@ class Server:
                  timeline: bool = True, paged: bool | None = None,
                  kv_page_size: int = 0, kv_pages: int = 0,
                  hbm_gbps: float = 0.0, prefill_chunk_tokens: int = 0,
-                 kv_host_mb: float = 0.0, in_dispatch_eos: bool = True,
-                 mesh=None, shard_rules: str = "serve",
-                 page_pool: PagePool | None = None,
-                 serialize_dispatch: bool = False):
+                 kv_host_mb: float = 0.0, mesh=None,
+                 shard_rules: str = "serve",
+                 page_pool: PagePool | None = None):
         if model.cfg.quantized:
             # nothing structural in the way — the q8 apply is the same
             # model.apply — but untested here; fail loud, not wrong
@@ -842,22 +816,16 @@ class Server:
         self.fault_plan = fault_plan
         self.eos_ids = normalize_eos_ids(eos_id)
         self.min_bucket = min_bucket
-        # in-dispatch EOS/refill (ISSUE-13, default ON): the decode
-        # chunk and the verify round carry a per-slot ``done`` flag so
-        # a slot finishing mid-dispatch freezes instead of decoding
-        # trimmed overshoot — chunk_steps can grow without feeding the
-        # ``overshoot`` waste bucket, the speculation path fuses its
-        # follow-up chunk into the verify dispatch, and the host trim
-        # walk becomes a consistency check. False = the pre-ISSUE-13
-        # behavior, kept as the bench/regression A/B control.
-        self.in_dispatch_eos = bool(in_dispatch_eos)
+        # the decode chunk and the verify round carry a per-slot
+        # ``done`` flag: a slot finishing mid-dispatch freezes instead
+        # of decoding past its finish, and the host's walk over the
+        # tail is a consistency check
         self.frozen_steps = 0  # decode/verify positions spent frozen
         #                        (re-emitting a finished slot's token);
         #                        they cost no KV writes and land in the
-        #                        ledger's padding bucket, not overshoot
+        #                        ledger's padding bucket
         self.freeze_faults = 0  # frozen-tail consistency violations
-        #                         (must stay 0; the old host trim, as
-        #                         a check)
+        #                         (must stay 0)
         # upper bound on decode micro-steps fused into one dispatch;
         # 1 = token-at-a-time (lowest latency to each token, highest
         # per-token dispatch cost — the right setting for streaming)
@@ -905,23 +873,17 @@ class Server:
         else:
             self.slots = SlotCache(model, params, batch_size, mesh=mesh)
         # dispatch concurrency (ISSUE-19): every engine owns ITS OWN
-        # scheduler lock — co-located engines on a shared pool no
-        # longer serialize whole step() iterations through one
-        # pool-wide writer. The shared device TREE is protected at a
-        # finer grain instead: ``_tree_lock`` (the pool's lock when
-        # shared, else this same per-engine lock — a free re-entrant
-        # acquire) brackets each read-dispatch-reassign window, held
-        # only while ENQUEUEING a dispatch, never across the host sync
-        # — so two engines' device work overlaps while the tree-version
-        # chain stays linear. ``serialize_dispatch=True`` restores the
-        # old pool-wide single-writer discipline (whole steps under
-        # pool.lock) as the measured A/B control for bench
-        # extras.migrate's concurrent-pool arm.
-        shared = self.paged and self.slots.pool.shared
-        self.serialize_dispatch = bool(serialize_dispatch) and shared
-        self._dispatch_lock = self.slots.pool.lock \
-            if self.serialize_dispatch else threading.RLock()
-        self._tree_lock = self.slots.pool.lock if shared \
+        # scheduler lock, so co-located engines on a shared pool do not
+        # serialize whole step() iterations. The shared device TREE is
+        # protected at a finer grain: ``_tree_lock`` (the pool's lock
+        # when shared, else this same per-engine lock — a free
+        # re-entrant acquire) brackets each read-dispatch-reassign
+        # window, held only while ENQUEUEING a dispatch, never across
+        # the host sync — so two engines' device work overlaps while
+        # the tree-version chain stays linear.
+        self._dispatch_lock = threading.RLock()
+        self._tree_lock = self.slots.pool.lock \
+            if self.paged and self.slots.pool.shared \
             else self._dispatch_lock
         # the pool tree this engine's pages and prefix entries live in
         # (PagePool.tree_epoch; see _check_tree)
@@ -960,22 +922,15 @@ class Server:
         self.dispatches = 0  # decode dispatches (chunk + verify)
         self.prefills = 0    # prefill dispatches (exact hits skip one)
         self.wasted_steps = 0  # PER-SLOT token positions decoded and
-        #                       thrown away. With in-dispatch EOS on
-        #                       (the default) only REJECTED DRAFT
-        #                       positions remain — chunk overshoot and
-        #                       verify bonus past a finish are frozen
-        #                       in-dispatch (frozen_steps) instead of
-        #                       decoded and trimmed. The legacy
-        #                       in_dispatch_eos=False engine still
-        #                       counts all three. Different unit from
-        #                       `steps` — compare against emitted
-        #                       tokens for utilization, the pairing
-        #                       bench.py reports
+        #                       thrown away: REJECTED DRAFT positions
+        #                       only — positions past a finish are
+        #                       frozen in-dispatch (frozen_steps), not
+        #                       decoded. Different unit from `steps`:
+        #                       compare against emitted tokens
         # per-dispatch timeline (obs/timeline.py): one record per
         # prefill / hit-admit / decode / verify dispatch with host-wall
-        # duration and a first-call compile flag; False = off, for the
-        # obs overhead A/B (bench extras.obs) — the layer itself is
-        # cheap enough to stay on in production
+        # duration and a first-call compile flag; False = off — the
+        # layer itself is cheap enough to stay on in production
         self.timeline = DispatchTimeline() if timeline else None
         self._compiled: set = set()  # (kind, shape-bucket) pairs seen
         # host phase ledger (obs/phases.py): the stepping thread's wall
@@ -2691,8 +2646,8 @@ class Server:
     def _chunk_size(self) -> int:
         """Decode micro-steps for this iteration: enough for the
         longest-remaining live slot but never past ``chunk_steps``,
-        quantized DOWN to a power of two (bounded compile count). Slots
-        finishing mid-chunk overshoot and are trimmed — overshoot
+        quantized DOWN to a power of two (bounded compile count). A
+        slot finishing mid-chunk freezes for the rest of it — frozen
         slot-steps are free (the batched step runs every row
         regardless); a too-long chunk would only waste WHOLE-batch
         steps at the very tail, which the max-remaining bound prevents."""
@@ -2710,9 +2665,7 @@ class Server:
         a shared pool step CONCURRENTLY (ISSUE-19): the shared device
         tree is guarded per dispatch by ``_tree_lock`` around each
         read-dispatch-reassign window, and allocator state by the
-        pool's fine ``_mu`` — unless ``serialize_dispatch=True`` pins
-        the old pool-wide single-writer discipline as the A/B
-        control."""
+        pool's fine ``_mu``."""
         with self.phases.rest("step.other"), self._dispatch_lock:
             return self._step_locked()
 
@@ -2784,7 +2737,7 @@ class Server:
         if self.paged:
             # the table is frozen across the chunk: pre-extend every
             # live slot to cover the positions this chunk will write
-            # (capped at the slot's own budget — overshoot past a
+            # (capped at the slot's own budget — a frozen tail past a
             # finish writes through the sentinel and drops). The table
             # is read COLUMN-SLICED to a power-of-two bucket of the live
             # extent: the gathered view — and every micro-step's
@@ -2805,7 +2758,6 @@ class Server:
                        s.max_pages)
             table = s.device_table(cols)
         view_tokens = cols * s.pool.page_size if self.paged else 0
-        freeze = self.in_dispatch_eos
         # per-slot remaining budgets, for the rows the patch carries:
         # the device freezes a slot the moment it samples EOS or
         # exhausts this, so every emitted (non-frozen) position is a
@@ -2825,8 +2777,7 @@ class Server:
         with self._tree_lock:
             s.cache, toks, state = _decode_chunk(
                 self.model, self.params, s.cache, s.state, patch, table,
-                n_steps=k, eos_ids=self.eos_ids if freeze else (),
-                freeze=freeze)
+                n_steps=k, eos_ids=self.eos_ids)
         s.advance(state)
         self.steps += k
         self.dispatches += 1
@@ -2835,7 +2786,7 @@ class Server:
         if self.timeline is not None:
             # duration closes at the host sync (np.asarray above), the
             # latency a request actually experienced; tokens landed are
-            # counted below once the EOS/budget walk trims overshoot
+            # counted below once the EOS/budget walk has found finishes
             dur_ms = (time.monotonic() - t0) * 1e3
         self.phases.switch("decode.emit")
         landed = 0
@@ -2855,9 +2806,7 @@ class Server:
                     reason = "length"
                 if reason:
                     # tokens past this point were frozen in-dispatch
-                    # (re-emitted finals, no KV writes) — or, with
-                    # freeze off, chunk overshoot: decoded garbage the
-                    # host trims. Either way never reported.
+                    # (re-emitted finals, no KV writes): never reported
                     break
             if reason is None:
                 # the chunk wrote k tokens at advancing positions; the
@@ -2866,23 +2815,17 @@ class Server:
                 s.last_token[slot] = int(toks[slot, k - 1])
                 landed += k
                 continue
-            if freeze:
-                # in-dispatch EOS: the trailing positions were frozen
-                # re-emits, not overshoot — the trim is a consistency
-                # check now, and the waste counter stays put
-                self.frozen_steps += k - (j + 1)
-                if j + 1 < k and not (toks[slot, j + 1:]
-                                      == toks[slot, j]).all():
-                    self.freeze_faults += 1
-                    log.warning(
-                        "frozen slot %d re-emitted a different token "
-                        "(%s after %d) — in-dispatch EOS consistency "
-                        "violation", slot, toks[slot, j + 1:].tolist(),
-                        int(toks[slot, j]))
-            else:
-                # tokens past the finish are chunk overshoot the host
-                # trimmed: decoded, paid for, never reported
-                self.wasted_steps += k - (j + 1)
+            # the trailing positions were frozen re-emits: walking them
+            # is a consistency check, and the waste counter stays put
+            self.frozen_steps += k - (j + 1)
+            if j + 1 < k and not (toks[slot, j + 1:]
+                                  == toks[slot, j]).all():
+                self.freeze_faults += 1
+                log.warning(
+                    "frozen slot %d re-emitted a different token "
+                    "(%s after %d) — in-dispatch EOS consistency "
+                    "violation", slot, toks[slot, j + 1:].tolist(),
+                    int(toks[slot, j]))
             landed += j + 1
             finished.append(Result(req.id, list(req.prompt),
                                    live.generated, reason,
@@ -2900,19 +2843,15 @@ class Server:
             if view_tokens:
                 tags["view_tokens"] = view_tokens
             view = view_tokens or self.model.cfg.max_seq_len
-            # position accounting: with freeze on, every fed position
-            # landed a kept token (fed == landed -> the ledger's
-            # overshoot bucket is structurally 0; frozen tails join
-            # the empty-slot positions in padding). Freeze off keeps
-            # the old fed = depth x occupancy, whose excess over
-            # landed IS the overshoot bucket.
-            fed = landed if freeze else k * occ
-            if freeze:
-                tags["frozen"] = k * occ - landed
+            # position accounting: every fed position landed a kept
+            # token (fed == landed: a chunk round charges the ledger's
+            # overshoot bucket nothing; frozen tails join the
+            # empty-slot positions in padding)
+            tags["frozen"] = k * occ - landed
             self._record_dispatch(
                 "decode", t0, dur_ms, occ, k, landed,
                 ("decode", k, view_tokens), tags=tags,
-                work=k * s.batch_size, fed=fed,
+                work=k * s.batch_size, fed=landed,
                 est=self.cost.decode(k, s.batch_size, view))
         return finished
 
@@ -2929,75 +2868,31 @@ class Server:
         d+1 tokens, so d is clamped to remaining-1 — which also keeps
         every window write inside max_seq_len).
 
-        A verify round advances every NON-drafting live slot by exactly
-        one token, where a chunk round would advance it ``chunk_steps``
-        — so a lone hot drafter in a mixed batch could drag the rest of
-        the batch to 1 token/dispatch indefinitely. The batch-drag gate
-        refuses the verify round when both hold: (a) some live slot is
-        not drafting, and (b) the round's expected token yield (one per
-        live slot + EMA-weighted draft lengths) is below what the chunk
-        dispatch would land — keeping the worst case at today's cost +
-        the host-side lookups, the speculation contract. A solo drafter
-        (no one to drag) always speculates: its verify is 1 step deep
-        where the chunk is chunk_steps deep. The gate is prechecked on
-        an UPPER bound (full draft caps, before any lookup) so rounds
-        it is provably going to refuse skip the n-gram scans
-        altogether — an ineligible slot can't start drafting and the
-        EMA only moves in verify rounds, so a permanently gated batch
-        pays nothing per round, not one scan per greedy slot.
-
-        With in-dispatch EOS on, the verify round FUSES its follow-up
-        chunk (``_verify_chunk(n_steps=...)``): every live slot —
-        drafting or not — decodes the full chunk depth inside the same
-        dispatch, so there is no batch to drag and the gate is
-        structurally unnecessary; any proposed draft is pure upside
-        (accepted tokens on top of the chunk's) minus one window pass.
-        The EMA still silences hopeless drafters."""
+        The verify round carries a chunk's worth of continuation
+        (``_verify_chunk(n_steps=...)``): every live slot — drafting
+        or not — decodes the full chunk depth inside the same dispatch,
+        so a lone drafter drags nobody and any proposed draft is pure
+        upside (accepted tokens on top of the chunk's) minus one window
+        pass. The EMA silences hopeless drafters."""
         out: list = [None] * self.slots.batch_size
-        n_live = 0
-        all_eligible = True
-        bound = 0.0  # upper bound on the verify round's token yield
-        eligible: list = []  # (slot, live, d_cap)
-        fused = self.in_dispatch_eos
+        any_draft = False
         for slot, live in enumerate(self._live):
             if live is None:
                 continue
-            n_live += 1
-            bound += 1.0
             req = live.request
             if req.temperature != 0.0 \
                     or self._spec_ema[slot] < self.SPEC_EMA_DISABLE:
-                all_eligible = False
                 continue
             d_cap = min(self.speculate_k,
                         req.max_new_tokens - len(live.generated) - 1)
             if d_cap <= 0:
-                all_eligible = False
                 continue
-            eligible.append((slot, live, d_cap))
-            bound += self._spec_ema[slot] * d_cap
-        if not eligible:
-            return None
-        if not fused and not all_eligible \
-                and bound < self._chunk_size() * n_live:
-            return None  # gate precheck: refuses before any lookup
-        any_draft = False
-        expected = float(n_live)  # actual-proposal yield estimate
-        for slot, live, d_cap in eligible:
-            req = live.request
             ctx = np.asarray(list(req.prompt) + live.generated, np.int32)
             draft = _propose_draft(ctx, d_cap)
             if draft.size:
                 out[slot] = draft
                 any_draft = True
-                expected += self._spec_ema[slot] * draft.size
-        if not any_draft:
-            return None
-        drafting = sum(d is not None for d in out)
-        if not fused and drafting < n_live and \
-                expected < self._chunk_size() * n_live:
-            return None  # batch-drag gate: the chunk dispatch yields more
-        return out
+        return out if any_draft else None
 
     def _verify_round(self, drafts: list) -> list[Result]:
         """One speculative verify dispatch + acceptance/evict. Every
@@ -3008,22 +2903,17 @@ class Server:
         POINTER ARITHMETIC ONLY: their K/V stays in the cache beyond
         the slot's length, invisible to every later query and
         overwritten as the slot decodes on (the prefix-store masked-
-        visibility exactness argument). Mid-window EOS/budget trims
-        exactly like chunk overshoot; donation reads the row whose
+        visibility exactness argument). Donation reads the row whose
         [0, len) span covers only fed, accepted tokens.
 
-        With in-dispatch EOS on this is the FUSED speculation round:
-        the dispatch continues every row ``chunk_size`` frozen-body
-        micro-steps past its own bonus verdict, so the chunk dispatch
-        that used to follow each verify round is gone — a speculating
-        round lands accepted + 1 + chunk tokens per slot in ONE
-        dispatch, and non-drafting co-tenants keep their full chunk
-        cadence (no batch drag, no gate)."""
+        The dispatch continues every row ``chunk_size`` frozen-body
+        micro-steps past its own bonus verdict: a speculating round
+        lands accepted + 1 + chunk tokens per slot in ONE dispatch,
+        and non-drafting co-tenants keep their full chunk cadence."""
         finished: list[Result] = []
         s = self.slots
         b = s.batch_size
-        fused = self.in_dispatch_eos
-        k_cont = self._chunk_size() if fused else 0
+        k_cont = self._chunk_size()
         window = _bucket_pow2(max(d.size for d in drafts
                                   if d is not None)) + 1
         toks = np.zeros((b, window), np.int32)
@@ -3047,10 +2937,10 @@ class Server:
         if self.paged:
             # window row i writes positions [lengths, lengths + d_i]
             # (last_token + its drafts) — always within the slot's
-            # budget (drafts are clamped to remaining - 1) — plus, in
-            # the fused round, up to k_cont continuation positions
-            # (budget overshoot there writes through the sentinel and
-            # drops; ensure_pages never grows past the reservation).
+            # budget (drafts are clamped to remaining - 1) — plus up
+            # to k_cont continuation positions (a frozen tail there
+            # writes through the sentinel and drops; ensure_pages
+            # never grows past the reservation).
             # Column-sliced like the chunk path: the verify gather
             # reads O(live extent)
             hi = 0
@@ -3074,16 +2964,14 @@ class Server:
         # back if a chunk round moved them), and hands the next chunk
         # round every live row to send (``host_fed`` below)
         with self._tree_lock:
-            s.cache, emit, accepted, *cont, rng = _verify_chunk(
+            s.cache, emit, accepted, cont, rng = _verify_chunk(
                 self.model, self.params, s.cache, jnp.asarray(toks),
                 jnp.asarray(positions), jnp.asarray(draft_len),
                 jnp.asarray(s.temperature), jnp.asarray(s.top_k),
-                jnp.asarray(s.rng),
-                jnp.asarray(rem) if fused else None,
-                table, window=window, n_steps=k_cont,
-                eos_ids=self.eos_ids if fused else ())
+                jnp.asarray(s.rng), jnp.asarray(rem), table,
+                window=window, n_steps=k_cont, eos_ids=self.eos_ids)
         self.phases.switch("verify.wait")
-        cont = np.asarray(cont[0]) if fused else None
+        cont = np.asarray(cont)
         self.steps += window + k_cont
         self.dispatches += 1
         self.spec_rounds += 1
@@ -3109,72 +2997,65 @@ class Server:
                 self.spec_drafted += d
                 self.spec_accepted += a
                 # rejected drafts were scored and thrown away — the
-                # speculation-side waste the utilization counter reports
-                # next to chunk overshoot (in the fused round the EOS
-                # cap folds accepted-but-discarded drafts past a stop
-                # token in here too)
+                # waste the utilization counter reports (the EOS cap
+                # folds accepted-but-discarded drafts past a stop token
+                # in here too)
                 self.wasted_steps += d - a
                 self._spec_ema[slot] = (
                     self.SPEC_EMA_DECAY * self._spec_ema[slot]
                     + (1.0 - self.SPEC_EMA_DECAY) * a / d)
             reason = None
-            consumed = 0
             # emit[:a] are the accepted drafts, emit[a] the bonus
             # verdict after them — appended in order with the same
-            # EOS/budget walk as the chunk path
+            # EOS/budget walk as the chunk path. The walk always
+            # reaches the bonus: the device capped ``a`` at the first
+            # stop token, and a draft is at most remaining - 1 long
             for jj in range(a + 1):
                 tok = int(emit[slot, jj])
                 live.generated.append(tok)
-                consumed += 1
                 if tok in self.eos_ids:
                     reason = "eos"
                 elif len(live.generated) >= req.max_new_tokens:
                     reason = "length"
                 if reason:
                     break
-            landed += consumed
+            landed += a + 1
             cont_consumed = 0
-            if fused:
-                if reason is None:
-                    # the fused continuation: this slot's chunk
-                    # tokens, same EOS/budget walk; frozen tails
-                    # re-emit
-                    for jj in range(k_cont):
-                        tok = int(cont[slot, jj])
-                        live.generated.append(tok)
-                        cont_consumed += 1
-                        if tok in self.eos_ids:
-                            reason = "eos"
-                        elif len(live.generated) >= req.max_new_tokens:
-                            reason = "length"
-                        if reason:
-                            break
-                    if reason is not None \
-                            and cont_consumed < k_cont \
-                            and not (cont[slot, cont_consumed:]
-                                     == cont[slot,
-                                             cont_consumed - 1]).all():
-                        self.freeze_faults += 1
-                        log.warning(
-                            "frozen slot %d re-emitted a different "
-                            "token in a fused verify round — "
-                            "in-dispatch EOS consistency violation",
-                            slot)
-                # a slot that finished inside the window froze for the
-                # whole continuation; mid-continuation finishes freeze
-                # the tail — either way those positions are padding
-                self.frozen_steps += k_cont - cont_consumed
-                landed += cont_consumed
-                cont_fed += cont_consumed
             if reason is None:
-                # fed last_token + a accepted drafts (+ the fused
-                # continuation): the slot's position-exact span grew
-                # by accepted + 1 + cont_consumed
-                s.lengths[slot] += a + 1 + cont_consumed
-                s.last_token[slot] = int(cont[slot, k_cont - 1]) \
-                    if fused else int(emit[slot, a])
+                # the continuation: this slot's chunk tokens, same
+                # EOS/budget walk; frozen tails re-emit
+                for jj in range(k_cont):
+                    tok = int(cont[slot, jj])
+                    live.generated.append(tok)
+                    cont_consumed += 1
+                    if tok in self.eos_ids:
+                        reason = "eos"
+                    elif len(live.generated) >= req.max_new_tokens:
+                        reason = "length"
+                    if reason:
+                        break
+                if reason is not None \
+                        and cont_consumed < k_cont \
+                        and not (cont[slot, cont_consumed:]
+                                 == cont[slot, cont_consumed - 1]).all():
+                    self.freeze_faults += 1
+                    log.warning(
+                        "frozen slot %d re-emitted a different token "
+                        "in a verify round's continuation — "
+                        "in-dispatch EOS consistency violation", slot)
+            # a slot that finished inside the window froze for the
+            # whole continuation; mid-continuation finishes freeze the
+            # tail — either way those positions are padding
+            self.frozen_steps += k_cont - cont_consumed
+            landed += cont_consumed
+            cont_fed += cont_consumed
+            if reason is None:
+                # fed last_token + a accepted drafts + the
+                # continuation: the slot's position-exact span grew by
+                # accepted + 1 + k_cont
+                s.lengths[slot] += a + 1 + k_cont
+                s.last_token[slot] = int(cont[slot, k_cont - 1])
                 continue
-            self.wasted_steps += (a + 1) - consumed
             finished.append(Result(req.id, list(req.prompt),
                                    live.generated, reason,
                                    live.prefix_hit_tokens,
@@ -3198,18 +3079,16 @@ class Server:
                     "accepted": accepted_n}
             if view_tokens:
                 tags["view_tokens"] = view_tokens
-            if fused:
-                tags["cont_steps"] = k_cont
+            tags["cont_steps"] = k_cont
             view = view_tokens or self.model.cfg.max_seq_len
-            # fused round: fed = one seed token per live slot + every
-            # draft + the live continuation positions; landed is the
-            # same minus the rejected drafts, so fed - landed ==
-            # rejected and the ledger's overshoot bucket stays 0
+            # fed = one seed token per live slot + every draft + the
+            # live continuation positions; landed is the same minus
+            # the rejected drafts, so fed - landed == rejected and the
+            # ledger's overshoot bucket stays 0
             fed = occ + drafted_n + cont_fed
             est = self.cost.verify(window, b, view)
-            if fused:
-                dec = self.cost.decode(k_cont, b, view)
-                est = (est[0] + dec[0], est[1] + dec[1])
+            dec = self.cost.decode(k_cont, b, view)
+            est = (est[0] + dec[0], est[1] + dec[1])
             self._record_dispatch(
                 "verify", t0, dur_ms, occ, window, landed,
                 ("verify", window, k_cont, view_tokens), tags=tags,
@@ -3281,17 +3160,16 @@ class Server:
 
     def counters(self) -> dict:
         """Engine-level counters for observability surfaces (gateway
-        /stats, MetricsStore, bench): flat numeric dict. Prefix-store
+        /stats, MetricsStore): flat numeric dict. Prefix-store
         state rides along when the store is on."""
         out = {
             "prefills": self.prefills,
             "decode_steps": self.steps,
             "dispatches": self.dispatches,
             "wasted_steps": self.wasted_steps,
-            # in-dispatch EOS (ISSUE-13): positions a finished slot
-            # spent frozen (re-emits, no KV writes — padding, not
-            # overshoot) and the trim-walk consistency violations
-            # (must stay 0)
+            # positions a finished slot spent frozen (re-emits, no KV
+            # writes — padding) and the tail-walk consistency
+            # violations (must stay 0)
             "frozen_steps": self.frozen_steps,
             "freeze_faults": self.freeze_faults,
             # writer dispatches that consumed the KV tree they were
