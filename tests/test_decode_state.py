@@ -53,9 +53,9 @@ def _device_rows(srv) -> dict:
 @pytest.mark.parametrize("paged", [True, False])
 def test_clean_rows_equal_the_mirrors_after_every_round(
         tiny, paged, chunk_steps):
-    """After every round, for every live row the host has not touched
-    since, the device's last token, position and budget are the
-    mirrors' — staggered budgets and a late arrival, so rows are
+    """After every step, for every live row the host has not touched
+    since, the device's last token, position and budget are where the
+    host planned them: the mirrors' plus the rounds in flight — staggered budgets and a late arrival, so rows are
     admitted and evicted around the ones compared; at every depth the
     scheduler compiles up to ``Server``'s default of 8."""
     model, params = tiny
@@ -70,16 +70,25 @@ def test_clean_rows_equal_the_mirrors_after_every_round(
         if it == 3:
             srv.submit(Request(_prompt(9), 12, id="late"))
         srv.step()
+        # the mirrors lag the device by the rounds in flight: the
+        # device stands where the host PLANNED it (``_budgets``), the
+        # mirror plus the depth of every round the row still rides,
+        # and its last token is the newest of those rounds' last
         s = srv.slots
         dev = _device_rows(srv)
+        left = srv._budgets()
         for slot in np.flatnonzero(s.active & ~s.dirty):
-            live = srv._live[slot]
-            assert dev["tok"][slot] == s.last_token[slot]
-            assert dev["pos"][slot] == s.positions()[slot]
+            if left[slot] <= 0:
+                continue    # ends under the rounds in flight: frozen
+            riding = [r for r in srv._inflight if slot in r.riders]
+            ahead = sum(r.k for r in riding)
+            tok = int(np.asarray(riding[-1].toks)[slot, -1]) if riding \
+                else s.last_token[slot]
+            assert dev["tok"][slot] == tok
+            assert dev["pos"][slot] == s.positions()[slot] + ahead
             assert dev["top_k"][slot] == s.top_k[slot]
             assert dev["temp"][slot] == s.temperature[slot]
-            assert dev["rem"][slot] == \
-                live.request.max_new_tokens - len(live.generated)
+            assert dev["rem"][slot] == left[slot]
             compared += 1
         if srv.done:
             break

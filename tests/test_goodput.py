@@ -254,7 +254,7 @@ def test_merged_local_plus_remote_ledger_pins(tiny):
     agent = AgentHTTP(ReplicaAgent(Server(
         model, params, batch_size=2, eos_id=-1))).start()
     stub = RemoteServer(agent.address, heartbeat_interval_s=0.1,
-                        lease_misses=3, boot_timeout_s=20.0)
+                        lease_misses=10, boot_timeout_s=20.0)
     local = Server(model, params, batch_size=2, eos_id=-1)
     gw = Gateway([local, stub], max_queue=32, max_attempts=3,
                  stall_timeout_s=10.0, breaker_base_s=0.05,

@@ -365,6 +365,13 @@ def test_cpu_capture_holds_the_engines_spans(tiny, tmp_path):
     seqs = {int(st["seq"]) for st in found["tony.decode.wait"]}
     recs = {r.seq: r for r in srv.timeline.since(before)}
     assert seqs and all(recs[s].kind == "decode" for s in seqs)
+    # a round's enqueue half comes a round before its record's number
+    # is known: both halves carry the round's ordinal, and so does the
+    # record
+    for st in found["tony.decode.wait"]:
+        assert recs[int(st["seq"])].tags["round"] == int(st["round"])
+    assert {int(st["round"]) for st in found["tony.decode.enqueue"]} \
+        >= {int(st["round"]) for st in found["tony.decode.wait"]}
     assert {str(st["rid"]) for st in found["tony.admit.wait"]} \
         == {"0", "1"}
     assert "tony.step.other" in found

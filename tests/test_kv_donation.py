@@ -246,8 +246,12 @@ def test_reset_after_a_consumed_tree_serves_the_next_request(tiny, paged):
     p0, p1 = _prompts(2, seed=11)
     assert [r.tokens for r in srv.run([Request(p0, 6)])] \
         == [_solo(model, params, p0, 6)]
-    srv.submit(Request(p1, 6))
-    srv.step()  # one in flight
+    # a stream long enough to outlast the two rounds its first step
+    # leaves in the device's queue: the next step() has a round to
+    # enqueue, and meets the tree that is gone
+    srv.submit(Request(p1, 24))
+    srv.step()
+    assert len(srv._inflight) == 1 and srv._plan_round() is not None
     stored = len(srv.prefix)
     assert stored > 0
     _consume(srv.slots.cache)
@@ -305,7 +309,7 @@ def test_a_lost_shared_tree_stops_the_neighbour_until_its_reset(tiny):
     b = Server(model, params, batch_size=2, page_pool=pool,
                prefix_cache_mb=1.0)
     pa, pb = _prompts(2, seed=13)
-    a.submit(Request(pa, 6))
+    a.submit(Request(pa, 24))   # outlasts the rounds in flight
     b.submit(Request(pb, 6))
     a.step()
     b.step()

@@ -213,7 +213,7 @@ def _stub(address, **kw):
     from tony_tpu.gateway.remote import RemoteServer
 
     kw.setdefault("heartbeat_interval_s", 0.1)
-    kw.setdefault("lease_misses", 3)
+    kw.setdefault("lease_misses", 10)
     kw.setdefault("boot_timeout_s", 20.0)
     return RemoteServer(address, **kw)
 
@@ -674,7 +674,7 @@ def test_remote_delta_stale_summary_falls_back_full(tiny):
     _warm(helper, list(prompt) + list(expect))
     http = _start_agent(tiny, prefix_cache_mb=2.0, fault_plan=_slow())
     stub = _ForcedSummary(http.address, heartbeat_interval_s=0.1,
-                          lease_misses=3, boot_timeout_s=20.0)
+                          lease_misses=10, boot_timeout_s=20.0)
     gw = Gateway([_mk(tiny, fault_plan=_slow()), stub],
                  prefix_affinity=False).start()
     try:

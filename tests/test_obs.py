@@ -699,7 +699,7 @@ def test_metrics_exposition_consistency_with_remote_stub(tiny):
     agent = AgentHTTP(ReplicaAgent(Server(
         model, params, batch_size=2, min_bucket=8))).start()
     stub = RemoteServer(agent.address, heartbeat_interval_s=0.1,
-                        lease_misses=3, boot_timeout_s=20.0)
+                        lease_misses=10, boot_timeout_s=20.0)
     gw = Gateway([stub], max_queue=32, max_attempts=3,
                  stall_timeout_s=10.0, breaker_base_s=0.05,
                  breaker_max_s=0.2).start()

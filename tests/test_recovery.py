@@ -105,7 +105,7 @@ def _stub(address, **kw):
     from tony_tpu.gateway.remote import RemoteServer
 
     kw.setdefault("heartbeat_interval_s", 0.1)
-    kw.setdefault("lease_misses", 3)
+    kw.setdefault("lease_misses", 10)
     kw.setdefault("read_timeout_s", 2.0)
     kw.setdefault("boot_timeout_s", 20.0)
     return RemoteServer(address, **kw)

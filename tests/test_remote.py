@@ -53,8 +53,18 @@ def start_agent(demo, port=0, **server_kw):
 def make_stub(address, **kw):
     from tony_tpu.gateway.remote import RemoteServer
 
+    # a 1 s lease (10 misses of 0.1 s; tests of the expiry set their
+    # own). A heartbeat here is /healthz then /v1/obs, each answered
+    # in two writes under Nagle's algorithm: 40 ms a call, 85-120 ms a
+    # beat under a streaming engine, beats 176 ms apart at the median
+    # (PERF.md section 7 row 13). A 0.3 s lease was one late beat from
+    # expiring, and under six workers the engine's two rounds in
+    # flight supplied it (a tiny model's scheduler thread no longer
+    # blocks in its sync): a healthy agent expired in every whole run
+    # of PR 34's first draft. Curing the 40 ms in the agent uncovers a
+    # race in the mux channel (same row), so the lease has the room
     kw.setdefault("heartbeat_interval_s", 0.1)
-    kw.setdefault("lease_misses", 3)
+    kw.setdefault("lease_misses", 10)
     kw.setdefault("read_timeout_s", 2.0)
     kw.setdefault("boot_timeout_s", 20.0)
     return RemoteServer(address, **kw)

@@ -214,40 +214,65 @@ def test_mid_chunk_eos_refill_parity(tiny, eos_probe):
     assert server.freeze_faults == 0
 
 
+def _settled_trees_equal(a, b, slot):
+    """Two settled engines hold the same device: every K/V leaf
+    bit-equal, and ``slot``'s row of ``SlotCache.state`` (token,
+    position, budget, sampling knobs, rng words) too."""
+    assert not a._inflight and not b._inflight
+    for (path, x), y in zip(
+            jax.tree_util.tree_flatten_with_path(a.slots.cache)[0],
+            jax.tree_util.tree_leaves(b.slots.cache)):
+        assert np.array_equal(np.array(x), np.array(y)), \
+            jax.tree_util.keystr(path)
+    assert np.array_equal(np.array(a.slots.state)[slot],
+                          np.array(b.slots.state)[slot])
+
+
+def _fresh_third(model, params, n_prompt, seed):
+    """A seeded ``n_prompt``-token prompt whose third greedy token is
+    one it has not emitted before (a stop token that strikes there),
+    with its three tokens."""
+    rng = np.random.default_rng(seed)
+    for _ in range(64):
+        prompt = rng.integers(1, 64, size=n_prompt).tolist()
+        solo = _solo(model, params, prompt, 3)
+        if solo[2] not in solo[:2]:
+            return prompt, solo, rng
+    pytest.fail("no seeded prompt emits a new token third")
+
+
 @pytest.mark.parametrize("finish", ["eos", "budget"])
 @pytest.mark.parametrize("paged", [True, False])
 def test_frozen_tail_is_an_identity_write(tiny, paged, finish):
-    """One round of depth 4 in which a greedy slot finishes at step 2
-    beside a sampled co-tenant leaves the device what two rounds of
-    depth 2 leave it, the second with that slot empty: every K/V leaf
-    bit-equal (outside the two positions the slot wrote nothing
-    landed), and the co-tenant's row of ``SlotCache.state`` — token,
-    position, budget, rng words — bit-equal too. A frozen step is an
-    empty slot's step: what a round enqueued behind a finish may lean
-    on."""
+    """Rounds of depth 4 in which a greedy slot finishes at step 2 of
+    the first, beside a sampled co-tenant, leave the device what
+    rounds of depth 2 leave it, all but the first with that slot
+    frozen or empty: every K/V leaf bit-equal (outside the two
+    positions the slot wrote nothing landed), and the co-tenant's row
+    of ``SlotCache.state`` — token, position, budget, rng words —
+    bit-equal too. A frozen step is an empty slot's step, WITHIN a
+    dispatch (the deep round's tail) and ACROSS dispatches (the round
+    that was already queued behind the finish: the engine keeps two in
+    flight), which is what the overlap leans on."""
     model, params = tiny
-    # 9-token prompts under pages of 8: two pages a slot from admission
-    # on, none taken or freed mid-way, so the pools compare whole
-    rng = np.random.default_rng(1)
-    for _ in range(64):
-        prompt = rng.integers(1, 64, size=9).tolist()
-        solo = _solo(model, params, prompt, 3)
-        if solo[2] not in solo[:2]:
-            break
-    else:
-        pytest.fail("no seeded prompt emits a new token third")
+    # 8-token prompts under pages of 8: a slot's second page is taken
+    # at the first enqueue and none is taken or freed after the
+    # finish, so the pools compare whole
+    prompt, solo, rng = _fresh_third(model, params, 8, 1)
     eos, budget = (solo[2], 10) if finish == "eos" else (-1, 3)
-    co_prompt = rng.integers(1, 64, size=9).tolist()
+    co_prompt = rng.integers(1, 64, size=8).tolist()
 
-    def drive(chunk_steps, rounds, seed):
+    def drive(chunk_steps, steps, seed):
         srv = Server(model, params, batch_size=2, eos_id=eos,
                      min_bucket=8, chunk_steps=chunk_steps, paged=paged,
                      kv_page_size=8 if paged else 0)
         srv.submit(Request(prompt, budget, id="fin"))
         srv.submit(Request(co_prompt, 20, id="co", temperature=0.9,
                            top_k=8, seed=seed))
-        done = [r for _ in range(rounds) for r in srv.step()]
-        assert srv.dispatches == rounds
+        done = [r for _ in range(steps) for r in srv.step()]
+        # a step from idle enqueues two rounds, each later one one
+        assert srv.dispatches == steps + 1 == srv.slots.rounds
+        srv._settle(done)
         assert [(r.id, r.tokens) for r in done] == [("fin", solo)]
         slot = next(i for i, lv in enumerate(srv._live) if lv is not None)
         return srv, slot
@@ -258,22 +283,104 @@ def test_frozen_tail_is_an_identity_write(tiny, paged, finish):
             break
     else:
         pytest.fail("every seeded co-tenant drew the stop token")
-    flat, co_flat = drive(2, 2, seed)
-    assert deep.frozen_steps == 2 and flat.frozen_steps == 0
+    flat, co_flat = drive(2, 3, seed)
+    # the tail of the round it finished in, and (a finish by EOS only:
+    # one by length the host sees coming) the whole round queued behind
+    queued = finish == "eos"
+    assert deep.frozen_steps == 2 + 4 * queued
+    assert flat.frozen_steps == 2 * queued
     assert deep.freeze_faults == 0 and deep.wasted_steps == 0
     assert co == co_flat
     assert deep._live[co].generated == flat._live[co].generated
-    assert len(deep._live[co].generated) == 5
+    assert len(deep._live[co].generated) == 9
     if paged:
         assert (deep.slots.page_table[co]
                     == flat.slots.page_table[co]).all()
-    for (path, a), b in zip(
-            jax.tree_util.tree_flatten_with_path(deep.slots.cache)[0],
-            jax.tree_util.tree_leaves(flat.slots.cache)):
-        assert np.array_equal(np.array(a), np.array(b)), \
-            jax.tree_util.keystr(path)
-    assert np.array_equal(np.array(deep.slots.state)[co],
-                          np.array(flat.slots.state)[co])
+    _settled_trees_equal(deep, flat, co)
+
+
+def _halves(srv, order):
+    """Drive the chunk round's two halves by hand: ``"e"`` enqueues a
+    round, ``"a"`` arrives on the oldest in flight."""
+    done = []
+    for half in order:
+        if half == "e":
+            with srv.phases.phase("decode.prepare"):
+                srv._enqueue_round(*srv._plan_round())
+        else:
+            srv._arrive(done)
+    return done
+
+
+@pytest.mark.parametrize("depth", [1, 4])
+@pytest.mark.parametrize("finish", ["eos", "budget"])
+@pytest.mark.parametrize("paged", [True, False])
+def test_round_over_an_unseen_finisher_is_the_serial_round(
+        tiny, paged, finish, depth):
+    """The device freezes a finisher the host has not seen: a round
+    enqueued over a slot that finished at the LAST step of the round
+    before, neither evicted nor patched (its state row still reads
+    live), leaves the K/V tree and the sampled co-tenant's state row
+    bit-equal to the serial order — read the tokens, evict, send the
+    patch that empties the row, then the round. By budget (the device
+    sees ``rem <= 0``) and by a stop token (it sees its own last
+    token), paged and not, one step deep and four."""
+    model, params = tiny
+    # 8-token prompts, pages of 8: a slot's second page comes with the
+    # first enqueue, in either order before the finish is seen, and
+    # covers both rounds: the pools compare whole
+    rng = np.random.default_rng(2)
+    prompt, co_prompt = (rng.integers(1, 64, size=8).tolist()
+                         for _ in range(2))
+
+    def drive(order, eos, budget, fin_seed, co_seed):
+        srv = Server(model, params, batch_size=2, eos_id=eos,
+                     min_bucket=8, chunk_steps=depth, paged=paged,
+                     kv_page_size=8 if paged else 0)
+        for req in (Request(prompt, budget, id="fin", temperature=0.9,
+                            top_k=8, seed=fin_seed),
+                    Request(co_prompt, 20, id="co", temperature=0.9,
+                            top_k=8, seed=co_seed)):
+            with srv.phases.phase("admit.host"):
+                assert srv._admit_one(req, [])
+        return srv, _halves(srv, order)
+
+    # the finisher samples (the tiny model's greedy stream repeats one
+    # token): a seed under which its last token of the first round is
+    # one it has not drawn before, so that as a stop token it strikes
+    # exactly there
+    for fin_seed in range(32):
+        probe, _ = drive("ea", -1, 20, fin_seed, 0)
+        toks = list(probe._live[0].generated)
+        if toks[depth] not in toks[:depth]:
+            break
+    else:
+        pytest.fail("no seeded finisher draws a new token there")
+    eos, budget = (toks[depth], 20) if finish == "eos" \
+        else (-1, depth + 1)
+    for co_seed in range(8):  # a co-tenant that draws no stop token
+        over, done = drive("eeaa", eos, budget, fin_seed, co_seed)
+        if over._live[1] is not None:
+            break
+    else:
+        pytest.fail("every seeded co-tenant drew the stop token")
+    serial, done_serial = drive("eaea", eos, budget, fin_seed, co_seed)
+    reason = "eos" if finish == "eos" else "length"
+    for d in (done, done_serial):
+        assert [(r.id, r.tokens, r.finish_reason) for r in d] \
+            == [("fin", toks, reason)]
+    assert over.rounds_overlapped == 1 and serial.rounds_overlapped == 0
+    # overlapped, the second round met the finisher's row as the first
+    # left it; serial, it met the patch that empties it
+    assert over.slots.rows_patched == 2 and serial.slots.rows_patched == 3
+    assert over.frozen_steps == (depth if finish == "eos" else 0)
+    assert serial.frozen_steps == 0 == over.freeze_faults
+    assert over._live[1].generated == serial._live[1].generated
+    assert len(over._live[1].generated) == 1 + 2 * depth
+    if paged:
+        assert (over.slots.page_table[1]
+                    == serial.slots.page_table[1]).all()
+    _settled_trees_equal(over, serial, 1)
 
 
 def test_admit_evict_scheduler_invariants(tiny):
@@ -395,19 +502,24 @@ def test_serve_flash_decode_backend(tiny):
     assert got == ref
 
 
-def test_continuous_beats_fixed_on_decode_steps(tiny):
+@pytest.mark.parametrize("chunk_steps", [1, 2])
+def test_continuous_beats_fixed_on_decode_steps(tiny, chunk_steps):
     """The scheduling claim in its launch-overhead-free form: on a
     mixed-budget workload the continuous scheduler executes strictly
     fewer batched decode steps than fixed batching's
     sum-of-batch-maxima (tokens per second is read on the chip, by
-    ``benchmarks/run.py``; step counts are deterministic)."""
+    ``benchmarks/run.py``; step counts are deterministic). With two
+    rounds in the device's queue a freed slot stands empty for the
+    round already queued, a whole chunk: 23 and 24 steps here against
+    fixed batching's 29, and at depth 4, where the serial order took
+    22, it is 30 (ROADMAP S2 (c))."""
     model, params = tiny
     budgets = [3, 14, 5, 9, 4, 12, 6, 15]
     batch = 4
     fixed_steps = sum(max(budgets[i:i + batch])
                       for i in range(0, len(budgets), batch))
     server = Server(model, params, batch_size=batch, eos_id=-1,
-                    min_bucket=8, chunk_steps=4)
+                    min_bucket=8, chunk_steps=chunk_steps)
     n_done = sum(1 for _ in server.run(
         Request([1 + i, 2, 3], max_new_tokens=b, id=i)
         for i, b in enumerate(budgets)))

@@ -3915,4 +3915,13 @@ class Gateway:
             "decode_rows_patched": total("decode_rows_patched"),
             "decode_table_sends": total("decode_table_sends"),
             "decode_rng_pulls": total("decode_rng_pulls"),
+            # the overlap (serve/engine.Server._decode_round): rounds
+            # enqueued while an older one's tokens had not been read,
+            # and times an engine drained its queue early because a
+            # caller needed the host mirrors (a verify round, a
+            # session's extraction or adoption), and rounds dropped
+            # unread (the device ran them; no timeline record has them)
+            "decode_rounds_overlapped": total("decode_rounds_overlapped"),
+            "decode_settles": total("decode_settles"),
+            "decode_rounds_dropped": total("decode_rounds_dropped"),
         }

@@ -15,19 +15,21 @@ path):
   the slot's length masks the tail until decode overwrites it.
 - Each ``step()``: admit pending prompts into free slots (prefill,
   slot copy and first-token sample FUSED into one dispatch per
-  request), run a CHUNK of K batched decode micro-steps as one
+  request), enqueue a CHUNK of K batched decode micro-steps as one
   lax.scan dispatch (K adapts to the live slots' remaining budgets,
   rounded to a power of two so at most log2(chunk_steps)+1 programs
-  ever compile), sample per-slot inside the chunk, then detect EOS /
+  ever compile; sampling is per-slot inside the chunk), then read the
+  tokens of the OLDEST chunk in the device's queue, detect EOS /
   budget per slot host-side, evict finished slots and return their
-  results. A finished slot is refilled the SAME iteration — mixed-
-  length traffic never waits on the longest sequence in the batch (the
-  fixed-batch ``generate()`` failure mode). Chunking amortizes the
-  per-dispatch host cost over K tokens; a slot that finishes mid-chunk
-  decodes garbage until the chunk ends (its row is independent — no
-  other slot sees it) which the host trims before reporting, so
-  results are unaffected and the waste is bounded by K-1 slot-steps
-  per finish.
+  results. Two chunks are kept in flight (``Server._decode_round``):
+  the device runs the younger while the host walks and streams the
+  older one's tokens. A finished slot is refilled the next iteration
+  — mixed-length traffic never waits on the longest sequence in the
+  batch (the fixed-batch ``generate()`` failure mode). Chunking
+  amortizes the per-dispatch host cost over K tokens; a slot that
+  finishes mid-chunk FREEZES for the rest of it, and for the chunk
+  already queued behind it (no K/V, no rng, its last token re-emitted,
+  which the host trims before reporting), so results are unaffected.
 
 Greedy outputs are token-for-token identical to a solo ``generate()``
 of the same prompt (the exactness contract tests/test_serve.py pins):
@@ -413,7 +415,12 @@ def _decode_chunk(model, params, cache, state, patch, table=None, *,
     (``_frozen_body``): a slot that samples EOS or exhausts its budget
     mid-chunk stops writing K/V (sentinel position), stops advancing
     rng, and re-emits its final token — so a deep chunk decodes
-    nothing past a finish. ``eos_ids`` is static per engine (one
+    nothing past a finish. The flag is SEEDED from the state itself
+    (empty, budget spent, or last token a stop token), so the freeze
+    holds ACROSS dispatches too: the engine enqueues a round before it
+    has read its predecessor's tokens, and a row that finished there
+    rides this one frozen from its first step (no K/V, no rng, its
+    state row unchanged). ``eos_ids`` is static per engine (one
     compile).
 
     ``table`` [b, cols] switches to the paged cache layout — but
@@ -426,7 +433,7 @@ def _decode_chunk(model, params, cache, state, patch, table=None, *,
     unwritten tail positions copy their own gathered content back —
     an identity write). The table is fixed across the chunk, so the
     host pre-extends it to cover every position the chunk will write
-    (engine ``_chunk_round``)."""
+    (engine ``_enqueue_round``)."""
     max_len = model.cfg.max_seq_len
     state = apply_patch(state, patch)
     tok, positions, rem, top_ks, temps, rngs = unpack_state(state)
@@ -435,7 +442,14 @@ def _decode_chunk(model, params, cache, state, patch, table=None, *,
         cache = paged_view(cache, table, max_len)
 
     body = _frozen_body(model, params, temps, top_ks, eos_ids)
-    carry = (cache, tok, positions, rngs, positions < 0, rem)
+    # a row that finished in the round BEFORE this one, and that the
+    # host has not seen yet (this round was enqueued before that one's
+    # tokens were read), starts frozen: its budget is spent or its last
+    # token is a stop token. No row the host has seen is either
+    # (admission finishes such a request on the spot), so for those the
+    # seed is ``positions < 0`` as it always was
+    done = (positions < 0) | (rem <= 0) | _is_eos(tok, eos_ids)
+    carry = (cache, tok, positions, rngs, done, rem)
     if n_steps > 1:
         carry, toks = jax.lax.scan(body, carry, None, length=n_steps)
         toks = jnp.moveaxis(toks, 0, 1)  # [steps, b] -> [b, steps]
@@ -652,6 +666,27 @@ class _Live:
 
 
 @dataclass
+class _Round:
+    """A chunk round in the device's queue (``Server._inflight``): what
+    its arrival needs, kept from its enqueue. ``riders`` maps a slot to
+    the ``_Live`` that held it when the round was enqueued and could
+    still move in it; a rider that leaves (it finished in an earlier
+    round, or was extracted) is taken out, so every rider of a round
+    in flight is its slot's present occupant, and a slot's later
+    occupant rides no round enqueued before its admission."""
+
+    rid: int         # the engine's ordinal of this round: on both
+    #                  halves' spans and on the record's ``round`` tag
+    toks: Any        # [b, k] on the device, not yet read
+    k: int           # depth: decode micro-steps
+    t0: float        # host clock at the enqueue
+    riders: dict     # slot -> _Live
+    view_tokens: int  # the gathered view's span (0 = unpaged)
+    done_at: float = 0.0  # host clock when a later program's wait saw
+    #                  this round done (``Server._wait_behind``)
+
+
+@dataclass
 class _PrefillState:
     """A slot mid-CHUNKED-prefill: admitted (reservation + any prefix
     seed already in place), prompt written up to ``done``, not yet
@@ -670,8 +705,9 @@ class Server:
     """Slot-based continuous-batching server.
 
     ``submit()`` enqueues; ``step()`` runs one scheduler iteration
-    (admit -> batched decode -> per-slot EOS/evict) and returns whatever
-    finished; ``run()`` drives to completion as a generator. ``params``
+    (admit -> enqueue a batched decode round -> read the oldest round
+    in flight -> per-slot EOS/evict) and returns whatever finished;
+    ``run()`` drives to completion as a generator. ``params``
     is the bare param tree (the ``generate()`` convention).
 
     eos_id follows generate(): an int (-1 = none) or a list/tuple
@@ -915,6 +951,20 @@ class Server:
         self.pending: deque[Request] = deque()
         self._pending_lock = threading.Lock()
         self._live: list[_Live | None] = [None] * batch_size
+        # chunk rounds enqueued and not yet read, oldest first: the
+        # engine keeps up to two in the device's queue and waits for
+        # the older, so the host's work between two decode programs
+        # runs under the younger (``_decode_round``). The host mirrors
+        # (``slots.lengths``/``last_token``, ``_Live.generated``) lag
+        # the device by these rounds; ``_settle`` catches them up.
+        self._inflight: deque[_Round] = deque()
+        # results of a settle outside ``step()`` (``extract_session``),
+        # handed out by the next ``step()``/``drain()``
+        self._held: list[Result] = []
+        self.rounds_overlapped = 0  # rounds enqueued behind an unread one
+        self.settles = 0            # times the queue was drained early
+        self.rounds_dropped = 0     # rounds enqueued and never read
+        self._decode_end = 0.0      # host clock at the last round's end
         self._ids = itertools.count()
         self.steps = 0       # decode dispatch DEPTH, summed (chunk k /
         #                      verify window — once per dispatch, not
@@ -1127,6 +1177,14 @@ class Server:
         the cost model's bytes/FLOPs estimate, with per-dispatch
         HBM-BW% / MFU tags when a roofline reference is known."""
         tags = tags or {}
+        if kind != "decode" and self._inflight:
+            # enqueued behind the rounds in flight, this program's sync
+            # waited them out first (``_wait_behind`` stamped when they
+            # were done): its record starts where theirs end
+            late = self._inflight[-1].done_at - t0
+            if late > 0:
+                t0 += late
+                dur_ms = max(0.0, dur_ms - late * 1e3)
         est_bytes, est_flops = est
         if self.cost is not None and est_bytes:
             bw, mfu = self.cost.utilization(est_bytes, est_flops,
@@ -1287,7 +1345,10 @@ class Server:
         # mid-chunked-prefill slots count: they hold a request the
         # engine is working on (a busy/done signal that ignored them
         # would let a front door idle out a half-prefilled prompt)
-        return self.slots.n_active + len(self._prefilling)
+        # ... and so do results a settle is holding for the next
+        # step(): the front door must step once more to be given them
+        return self.slots.n_active + len(self._prefilling) \
+            + len(self._held)
 
     @property
     def n_prefilling(self) -> int:
@@ -1296,7 +1357,7 @@ class Server:
     @property
     def done(self) -> bool:
         return not self.pending and self.slots.n_active == 0 \
-            and not self._prefilling
+            and not self._prefilling and not self._held
 
     def _free_slots(self) -> list[int]:
         """Slots admittable RIGHT NOW: free on the device AND not
@@ -1423,7 +1484,7 @@ class Server:
                         self.prefix_hit_tokens += hit_tokens
                         self.prefill_tokens_saved += saved
                     if self.timeline is not None:
-                        self.phases.switch("prefill_chunk.wait")
+                        self._wait_behind("prefill_chunk.wait")
                         jax.block_until_ready(row)  # close the record
                         self.phases.switch("prefill_chunk.record")
                         tags = {"prompt_len": len(p), "chunk": 1}
@@ -1465,7 +1526,7 @@ class Server:
             self.prefix_hits += 1
             self.prefix_hit_tokens += hit_tokens
             self.prefill_tokens_saved += saved
-        self.phases.switch("admit.wait")
+        self._wait_behind("admit.wait")
         tok = int(tok)  # host sync: the admit dispatch is done here
         self.phases.switch("admit.emit")
         if self.timeline is not None:
@@ -1693,7 +1754,7 @@ class Server:
             self.prefix_hits += 1
             self.prefix_hit_tokens += hit_tokens
             self.prefill_tokens_saved += saved
-        self.phases.switch("admit.wait")
+        self._wait_behind("admit.wait")
         tok = int(tok)  # host sync: the admit dispatch is done here
         self.phases.switch("admit.emit")
         if self.timeline is not None:
@@ -1810,7 +1871,7 @@ class Server:
         if self.timeline is not None:
             # close the record at a real sync: without it the chunk
             # would bill its device time to whatever syncs next
-            self.phases.switch("prefill_chunk.wait")
+            self._wait_behind("prefill_chunk.wait")
             jax.block_until_ready(mark)
             self.phases.switch("prefill_chunk.record")
             tags = {"prompt_len": len(p), "chunk": st.chunks,
@@ -1843,7 +1904,7 @@ class Server:
         st.done += take
         st.chunks += 1
         if self.timeline is not None:
-            self.phases.switch("prefill_chunk.wait")
+            self._wait_behind("prefill_chunk.wait")
             jax.block_until_ready(row)
             self.phases.switch("prefill_chunk.record")
             self._record_dispatch(
@@ -1892,7 +1953,7 @@ class Server:
         if self.prefix is not None:
             self.prefix.insert(p, pages=s.slot_pages(slot, len(p)),
                                logits=last)
-        self.phases.switch("admit.wait")
+        self._wait_behind("admit.wait")
         tok = int(tok)
         self.phases.switch("admit.emit")
         if self.timeline is not None:
@@ -1952,7 +2013,7 @@ class Server:
         self.prefill_chunked += 1
         if self.prefix is not None:
             self.prefix.insert(p, *row_last)
-        self.phases.switch("admit.wait")
+        self._wait_behind("admit.wait")
         tok = int(tok)
         self.phases.switch("admit.emit")
         if self.timeline is not None:
@@ -2145,7 +2206,7 @@ class Server:
             self.prefix.insert(p, pages=s.slot_pages(slot, n_tok),
                                logits=jnp.asarray(logits))
         self.handoffs_in += 1
-        self.phases.switch("admit.wait")
+        self._wait_behind("admit.wait")
         tok = int(tok)
         self.phases.switch("admit.emit")
         if self.timeline is not None:
@@ -2210,7 +2271,7 @@ class Server:
         self.handoffs_in += 1
         self.migrate_bytes_avoided += \
             (n_alias - fork) * pool.page_nbytes
-        self.phases.switch("admit.wait")
+        self._wait_behind("admit.wait")
         tok = int(tok)
         self.phases.switch("admit.emit")
         if self.timeline is not None:
@@ -2342,11 +2403,16 @@ class Server:
     def extract_session(self, request_id, *, wire: bool = False):
         """Freeze a live decode slot into a ``SessionSnapshot`` and
         evict it — the source half of a migration, called between
-        dispatches by the replica's own driver thread.
+        steps (it takes the dispatch lock). Rounds in flight are
+        settled first: the snapshot is cut from host mirrors that have
+        caught up with the device.
 
         Returns None when ``request_id`` is not in a live decode slot
         (still pending or mid-prefill) — those carry no per-slot state
-        worth moving, so the caller re-runs them as ordinary requests.
+        worth moving, so the caller re-runs them as ordinary requests —
+        and when the session finished under the settle (its remaining
+        budget was all in flight): the next ``step()`` returns its
+        result.
 
         ``wire=False`` (local owner swap): the snapshot holds page IDS
         pinned by one ``share()`` ref that transfers with it — zero KV
@@ -2368,6 +2434,14 @@ class Server:
             if slot is None:
                 return None
             live = self._live[slot]
+            # the snapshot is cut from the mirrors: catch them up with
+            # the device first. What finishes meanwhile is handed out
+            # by the next step() — this session too, and then there is
+            # nothing left to move. The caller may be another thread
+            # than the phase ledger's owner, so no span is booked
+            self._settle(self._held, spans=False)
+            if self._live[slot] is not live:
+                return None
             req = live.request
             t0 = time.monotonic()
             occ = s.n_active
@@ -2643,16 +2717,33 @@ class Server:
             self.prefix.release(entry)
         return self.prefix.acquire(p)
 
-    def _chunk_size(self) -> int:
-        """Decode micro-steps for this iteration: enough for the
-        longest-remaining live slot but never past ``chunk_steps``,
-        quantized DOWN to a power of two (bounded compile count). A
-        slot finishing mid-chunk freezes for the rest of it — frozen
-        slot-steps are free (the batched step runs every row
-        regardless); a too-long chunk would only waste WHOLE-batch
-        steps at the very tail, which the max-remaining bound prevents."""
-        rem = max(live.request.max_new_tokens - len(live.generated)
-                  for live in self._live if live is not None)
+    def _budgets(self) -> list[int]:
+        """Per slot, the tokens a round enqueued NOW could still give
+        its occupant: the request's budget less what it has generated
+        AS THE MIRRORS KNOW IT, less the depth of every round in flight
+        that it rides (the host plans a round ahead of its mirrors).
+        0 for an empty slot; <= 0 for an occupant whose budget the
+        rounds in flight spend, which the device will have frozen."""
+        left = [0] * self.slots.batch_size
+        for slot, live in enumerate(self._live):
+            if live is not None:
+                left[slot] = live.request.max_new_tokens \
+                    - len(live.generated)
+        for rnd in self._inflight:
+            for slot in rnd.riders:
+                left[slot] -= rnd.k
+        return left
+
+    def _chunk_size(self, left: list[int]) -> int:
+        """Decode micro-steps for the next dispatch, from ``_budgets``:
+        enough for the longest-remaining live slot but never past
+        ``chunk_steps``, quantized DOWN to a power of two (bounded
+        compile count). A slot finishing mid-chunk freezes for the
+        rest of it — frozen slot-steps are free (the batched step runs
+        every row regardless); a too-long chunk would only waste
+        WHOLE-batch steps at the very tail, which the max-remaining
+        bound prevents."""
+        rem = max(left)
         k = 1
         while k * 2 <= min(self.chunk_steps, rem):
             k *= 2
@@ -2679,12 +2770,16 @@ class Server:
         if self.fault_plan is not None:
             self.fault_plan.on_dispatch()
         self._check_tree()
-        finished: list[Result] = []
+        finished, self._held = self._held, []
         while self._free_slots():
             with self._pending_lock:
                 if not self.pending:
                     break
                 req = self.pending.popleft()
+            if req.migrate is not None:
+                # a session resumes from what ANOTHER engine's host
+                # knew: adopt it with this one's mirrors caught up too
+                self._settle(finished)
             with self.phases.phase("admit.host", rid=req.id):
                 admitted = self._admit_one(req, finished)
             if not admitted:
@@ -2696,47 +2791,90 @@ class Server:
                 break
         # mid-prefill slots advance ONE chunk, then every live slot
         # gets its decode round — the interleave that keeps a long
-        # prompt from starving co-tenants' TPOT
+        # prompt from starving co-tenants' TPOT. A prefill program
+        # enqueued here runs BEHIND a round in flight: the device takes
+        # them in order, which is what lets a finisher's pages go to
+        # the next occupant while that round is still queued
         self._advance_prefills(finished)
         if self.slots.n_active:
             finished.extend(self._decode_round())
         return finished
 
     def _decode_round(self) -> list[Result]:
-        """One batched decode round over the live slots + EOS/evict —
-        ``step()`` minus admission (``drain()`` runs it alone). With
-        speculation on, a round where any slot drafts runs ONE verify
-        dispatch (``_verify_round``); otherwise the plain chunk path."""
+        """One batched decode round's tokens over the live slots +
+        EOS/evict — ``step()`` minus admission (``drain()`` runs it
+        alone). With speculation on, a round where any slot drafts
+        runs ONE verify dispatch (``_verify_round``); otherwise the
+        plain chunk path, in two halves: ENQUEUE rounds until two are
+        in the device's queue (or no live row could move in another),
+        then ARRIVE on the oldest. From idle that is two enqueues, in
+        the steady state one; the device runs round n+1 while the host
+        copies back round n, walks and streams its tokens, and
+        enqueues round n+2.
+
+        That order needs no word from the host about round n: round
+        n+1's inputs are round n's own outputs, resident on the device
+        (``SlotCache.state``), and a row that finished in round n
+        starts round n+1 frozen (``_decode_chunk``'s seed). Where the
+        next dispatch's inputs are the HOST's — a verify round, and so
+        any engine that speculates — one round is in flight at most
+        and the order is serial, as it always was."""
+        finished: list[Result] = []
+        depth = 2
         if self.speculate_k > 0:
+            depth = 1
+            # nothing to settle unless speculation was switched on
+            # since the last step (``serve/autotune.py`` may)
+            self._settle(finished)
             with self.phases.phase("verify.draft"):
                 drafts = self._collect_drafts()
             if drafts is not None:
                 with self.phases.phase("verify.prepare",
                                        seq=self._next_seq()):
-                    return self._verify_round(drafts)
-        with self.phases.phase("decode.prepare", seq=self._next_seq()):
-            return self._chunk_round()
+                    finished.extend(self._verify_round(drafts))
+                return finished
+        while len(self._inflight) < depth:
+            plan = self._plan_round()
+            if plan is None:
+                break
+            with self.phases.phase("decode.prepare",
+                                   round=self.dispatches + 1):
+                self._enqueue_round(*plan)
+        if self._inflight:
+            self._arrive(finished)
+        return finished
 
-    def _chunk_round(self) -> list[Result]:
-        """The plain chunk path of ``_decode_round``, inside its open
-        ``decode.prepare`` leaf: the leaf is switched to ``enqueue``
-        (what the host must send, and the jit call), ``wait`` (the host
-        sync on the tokens), ``emit`` (the token walk) and ``record``
-        (the timeline record with its cost and goodput stamps).
+    def _plan_round(self) -> tuple | None:
+        """Whether another chunk round is worth enqueueing NOW, and for
+        whom: ``(left, riders)``, the budgets (``_budgets``) and the
+        occupants that could still move in it, by slot. None when no
+        live row could move: every occupant's budget is spent by the
+        rounds in flight, so a finish by length costs no all-frozen
+        round."""
+        left = self._budgets()
+        riders = {slot: live for slot, live in enumerate(self._live)
+                  if live is not None and left[slot] > 0}
+        return (left, riders) if riders else None
+
+    def _enqueue_round(self, left: list[int], riders: dict) -> None:
+        """The enqueue half of a chunk round (``_plan_round`` says for
+        whom), inside its open ``decode.prepare`` leaf (switched to
+        ``decode.enqueue`` for what the host must send, and the jit
+        call).
 
         The program's small inputs are on the device already
         (``SlotCache.state``): this round sends the rows the host
         changed since the last one as one packed patch, the page table
-        if its live columns changed, and in most rounds neither; it
-        copies back the tokens only, from which the mirrors follow the
-        device as before."""
-        finished: list[Result] = []
+        if its live columns changed, and in most rounds neither. The
+        mirrors lag the device by the rounds in flight, so the page
+        cover and the view's extent are planned from a slot's mirror
+        PLUS the depth of every round in flight it rides."""
         s = self.slots
-        k = self._chunk_size()
+        k = self._chunk_size(left)
         table = None
         if self.paged:
             # the table is frozen across the chunk: pre-extend every
-            # live slot to cover the positions this chunk will write
+            # rider to cover the positions this chunk will write
             # (capped at the slot's own budget — a frozen tail past a
             # finish writes through the sentinel and drops). The table
             # is read COLUMN-SLICED to a power-of-two bucket of the live
@@ -2747,33 +2885,31 @@ class Server:
             # bit-identical (at most log2(max_pages) programs per
             # chunk depth, the prefill-bucket discipline)
             hi = 0
-            for slot, live in enumerate(self._live):
-                if live is not None:
-                    s.ensure_pages(slot, min(
-                        int(s.lengths[slot]) + k,
-                        len(live.request.prompt)
-                        + live.request.max_new_tokens))
-                    hi = max(hi, int(s.lengths[slot]) + k)
+            for slot, live in riders.items():
+                req = live.request
+                # where the slot will stand once the rounds in flight
+                # it rides have landed, plus this chunk
+                ahead = req.max_new_tokens - len(live.generated) \
+                    - left[slot]
+                upto = int(s.lengths[slot]) + ahead + k
+                s.ensure_pages(slot, min(
+                    upto, len(req.prompt) + req.max_new_tokens))
+                hi = max(hi, upto)
             cols = min(_bucket_pow2(-(-hi // s.pool.page_size)),
                        s.max_pages)
             table = s.device_table(cols)
-        view_tokens = cols * s.pool.page_size if self.paged else 0
-        # per-slot remaining budgets, for the rows the patch carries:
-        # the device freezes a slot the moment it samples EOS or
-        # exhausts this, so every emitted (non-frozen) position is a
-        # token the request keeps
-        rem = [live.request.max_new_tokens - len(live.generated)
-               if live is not None else 0 for live in self._live]
-        if self.timeline is not None:
-            t0 = time.monotonic()
-            occ = s.n_active
-            riders = [lv.request.id for lv in self._live if lv is not None]
+        t0 = time.monotonic()
         # the read-dispatch-reassign window on the (possibly shared)
         # tree: enqueue ONE dispatch against the current version and
-        # reassign — the host sync (np.asarray below) runs OUTSIDE the
-        # lock, so co-located engines' device work overlaps
+        # reassign — the host sync (``_arrive``) runs OUTSIDE the lock,
+        # so co-located engines' device work overlaps
         self.phases.switch("decode.enqueue")
-        patch = s.decode_patch(rem)
+        # ``left`` is the budget of the rows the patch carries (a row
+        # the host changed rides no round in flight, so it is that
+        # row's whole remainder): the device freezes a slot the moment
+        # it samples EOS or exhausts it, so every emitted (non-frozen)
+        # position is a token the request keeps
+        patch = s.decode_patch(left)
         with self._tree_lock:
             s.cache, toks, state = _decode_chunk(
                 self.model, self.params, s.cache, s.state, patch, table,
@@ -2781,20 +2917,86 @@ class Server:
         s.advance(state)
         self.steps += k
         self.dispatches += 1
-        self.phases.switch("decode.wait")
-        toks = np.asarray(toks)  # [b, k]
-        if self.timeline is not None:
-            # duration closes at the host sync (np.asarray above), the
-            # latency a request actually experienced; tokens landed are
-            # counted below once the EOS/budget walk has found finishes
-            dur_ms = (time.monotonic() - t0) * 1e3
-        self.phases.switch("decode.emit")
-        landed = 0
+        self.rounds_overlapped += bool(self._inflight)
+        self._inflight.append(_Round(
+            self.dispatches, toks, k, t0, riders,
+            cols * s.pool.page_size if self.paged else 0))
 
-        for slot in range(s.batch_size):
-            live = self._live[slot]
-            if live is None:
-                continue
+    def _arrive(self, finished: list, *, spans: bool = True) -> None:
+        """The arrive half of the OLDEST round in flight: ``decode.wait``
+        (the host sync on its tokens, the one copy back), ``decode.emit``
+        (the token walk, from which the mirrors follow the device) and
+        ``decode.record`` (the timeline record with its cost and goodput
+        stamps). ``spans=False`` books no phase: for a caller on
+        another thread than the ledger's owner (``extract_session``).
+
+        The walk is over the round's RIDERS, not ``_live``: a slot whose
+        occupant left in an earlier round, or that was admitted to
+        another request since, gets nothing from this one (its row was
+        frozen or empty on the device; what it emitted is a re-emit or
+        garbage). Finishers are evicted here; the device learns of it
+        with the next enqueue's patch, and until then holds them frozen
+        by itself."""
+        rnd = self._inflight.popleft()
+        if spans:
+            with self.phases.phase("decode.wait", seq=self._next_seq(),
+                                   round=rnd.rid):
+                self._land(rnd, finished, self.phases.switch)
+        else:
+            self._land(rnd, finished, lambda name: None)
+        # a round whose riders have all left (each on a stop token the
+        # host could not plan for) is dropped unread: the engine goes
+        # idle with nothing in its queue. The device still runs it,
+        # every row frozen; it leaves no record, only this count
+        while self._inflight and not self._inflight[-1].riders:
+            self._inflight.pop()
+            self.rounds_dropped += 1
+
+    def _wait_behind(self, name: str) -> None:
+        """Open the wait leaf ``name`` of a program enqueued BEHIND the
+        rounds in flight (an admission's prefill, a prefill chunk). The
+        device takes its programs in order, so the host sync that
+        follows waits those rounds out too: wait for them first and
+        stamp when each was done, so that the wall is billed where it
+        was spent. A stamped round's record closes at its stamp
+        (``_land``) and this program's record starts there
+        (``_record_dispatch``). The rounds stay in flight and unread."""
+        self.phases.switch(name)
+        for rnd in self._inflight:
+            if not rnd.done_at:
+                jax.block_until_ready(rnd.toks)
+                rnd.done_at = time.monotonic()
+
+    def _settle(self, finished: list, *, spans: bool = True) -> None:
+        """Arrive on EVERY round in flight: the host mirrors are the
+        truth again. For whoever reads them as such: a verify round
+        (host-fed), a session's extraction and its adoption."""
+        if not self._inflight:
+            return
+        self.settles += 1
+        while self._inflight:
+            self._arrive(finished, spans=spans)
+
+    def _land(self, rnd: _Round, finished: list, switch) -> None:
+        """``_arrive``'s body, from the host sync on: ``switch`` moves
+        the open phase leaf on, or does nothing."""
+        s = self.slots
+        k = rnd.k
+        toks = np.asarray(rnd.toks)  # [b, k]: the host sync
+        # the round's clock starts at the LATER of its enqueue and its
+        # predecessor's end: two rounds in flight share wall, and the
+        # ledger's buckets must count it once. It closes at the host
+        # sync, the latency a request experienced, or where a program
+        # queued behind it saw it done (``_wait_behind``)
+        now = rnd.done_at or time.monotonic()
+        t0 = max(rnd.t0, self._decode_end)
+        dur_ms = max(0.0, now - t0) * 1e3
+        self._decode_end = now
+        switch("decode.emit")
+        occ = len(rnd.riders)
+        names = [live.request.id for live in rnd.riders.values()]
+        landed = 0
+        for slot, live in list(rnd.riders.items()):
             req = live.request
             reason = None
             for j in range(k):
@@ -2833,16 +3035,22 @@ class Server:
                                    live.prefill_tokens_saved,
                                    live.drafted, live.accepted,
                                    live.prefill_chunks))
+            # a finish by EOS is one the host could not plan for: the
+            # rounds already queued hold this row frozen for their
+            # whole depth, and it rides them no longer
+            for later in self._inflight:
+                if later.riders.pop(slot, None) is not None:
+                    self.frozen_steps += later.k
             if self.prefix is not None and self.prefix_donate:
                 self._donate(live, slot)
             self._live[slot] = None
             s.evict(slot)
-        self.phases.switch("decode.record")
+        switch("decode.record")
         if self.timeline is not None:
-            tags = {"requests": riders}
-            if view_tokens:
-                tags["view_tokens"] = view_tokens
-            view = view_tokens or self.model.cfg.max_seq_len
+            tags = {"requests": names, "round": rnd.rid}
+            if rnd.view_tokens:
+                tags["view_tokens"] = rnd.view_tokens
+            view = rnd.view_tokens or self.model.cfg.max_seq_len
             # position accounting: every fed position landed a kept
             # token (fed == landed: a chunk round charges the ledger's
             # overshoot bucket nothing; frozen tails join the
@@ -2850,10 +3058,9 @@ class Server:
             tags["frozen"] = k * occ - landed
             self._record_dispatch(
                 "decode", t0, dur_ms, occ, k, landed,
-                ("decode", k, view_tokens), tags=tags,
+                ("decode", k, rnd.view_tokens), tags=tags,
                 work=k * s.batch_size, fed=landed,
                 est=self.cost.decode(k, s.batch_size, view))
-        return finished
 
     # ------------------------------------------------- speculative decode
 
@@ -2913,7 +3120,7 @@ class Server:
         finished: list[Result] = []
         s = self.slots
         b = s.batch_size
-        k_cont = self._chunk_size()
+        k_cont = self._chunk_size(self._budgets())
         window = _bucket_pow2(max(d.size for d in drafts
                                   if d is not None)) + 1
         toks = np.zeros((b, window), np.int32)
@@ -3132,7 +3339,8 @@ class Server:
         or resume stepping. The graceful-shutdown hook: a front door
         stops feeding, calls drain(), and every request that already
         holds a slot completes instead of being dropped mid-decode."""
-        finished: list[Result] = []
+        with self._dispatch_lock:
+            finished, self._held = self._held, []
         while self.slots.n_active or self._prefilling:
             # lock PER ITERATION: on a shared pool, co-tenant engines
             # keep stepping between this engine's drain rounds
@@ -3141,6 +3349,8 @@ class Server:
                 self._advance_prefills(finished)
                 if self.slots.n_active:
                     finished.extend(self._decode_round())
+        # the last rider's finish leaves nothing in flight (a round
+        # without riders is dropped unread): the engine is settled
         return finished
 
     def live_progress(self, since: dict | None = None) -> dict:
@@ -3185,6 +3395,14 @@ class Server:
             "decode_rows_patched": self.slots.rows_patched,
             "decode_table_sends": self.slots.table_sends,
             "decode_rng_pulls": self.slots.rng_pulls,
+            # the overlap (``_decode_round``): rounds enqueued while an
+            # older one's tokens had not been read, times the queue
+            # was drained early because a caller needed the mirrors,
+            # and rounds dropped unread (their riders all gone, or a
+            # reset): run by the device, in no timeline record
+            "decode_rounds_overlapped": self.rounds_overlapped,
+            "decode_settles": self.settles,
+            "decode_rounds_dropped": self.rounds_dropped,
             "spec_rounds": self.spec_rounds,
             "spec_drafted": self.spec_drafted,
             "spec_accepted": self.spec_accepted,
@@ -3277,6 +3495,12 @@ class Server:
             with self._pending_lock:
                 self.pending.clear()
             self._live = [None] * self.slots.batch_size
+            # rounds in flight are dropped unread, not waited for: the
+            # device runs them against rows nothing reads again, and
+            # whatever is enqueued next runs after them
+            self.rounds_dropped += len(self._inflight)
+            self._inflight.clear()
+            self._held.clear()
             # mid-chunked-prefill slots drop with their requests;
             # their page reservations are returned by slots.reset()'s
             # evicts

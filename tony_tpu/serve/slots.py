@@ -609,7 +609,10 @@ class SlotCache:
     The host arrays are numpy and stay the scheduler's truth for
     everything off the round's critical path (admission, the token
     walk, the verify round, the migration snapshot, ``/stats``); the
-    engine still updates them from each round's tokens. They feed the
+    engine still updates them from each round's tokens, as it reads
+    them: they lag the device by the rounds in flight
+    (``Server._settle`` catches them up for a caller that needs the
+    truth). They feed the
     program only where the HOST changed a row: ``admit`` and ``evict``
     (and ``host_fed``, after a round that ran on host-fed inputs) mark
     the row ``dirty``, and the next chunk round sends ONE packed patch
@@ -807,8 +810,17 @@ class SlotCache:
         place — an inactive slot's position is -1, so nothing reads it,
         and the next admit overwrites the whole row; the row of the
         resident decode state is marked dirty, so that the next chunk
-        round tells the device the slot is empty (a row left live there
-        would go on writing into pages that are no longer its own).
+        round ENQUEUED tells the device the slot is empty. A round may
+        already be in the device's queue when the host evicts (the
+        engine keeps two in flight), and there the row still reads
+        live: what keeps it from writing into pages that are no longer
+        its own is not the patch's timing but the chunk program's own
+        seed — a row whose budget is spent or whose last token is a
+        stop token starts the round frozen (no K/V, no rng:
+        ``engine._decode_chunk``). So the pages may go to the prefix
+        store or to the next occupant at once: that round writes
+        nothing there, and the occupant's prefill runs after it (the
+        device takes its programs in order, along one donated tree).
         Paged: the slot's page references are dropped (pages a
         prefix-store entry also holds stay resident under their
         remaining refcount) and its unspent reservation is returned."""
