@@ -169,6 +169,11 @@ def build_parser() -> argparse.ArgumentParser:
                    help="serve fixed-shape per-slot cache rows instead "
                         "of the paged pool (A/B escape hatch; "
                         "sliding-window models downgrade automatically)")
+    p.add_argument("--warm-views", action="store_true",
+                   help="compile the decode program for every page-view "
+                        "bucket at start-up instead of when a live row "
+                        "first grows into one (no compile stall under "
+                        "traffic, at the cost of a longer boot)")
     p.add_argument("--no-shared-pool", action="store_true",
                    help="give each in-process replica its own private "
                         "KV page pool instead of one gateway-owned "
@@ -584,6 +589,7 @@ def server_factory(args, model, params, eos):
                       mesh=mesh,
                       shard_rules=getattr(args, "shard_rules", "serve"),
                       page_pool=pool,
+                      warm_views=getattr(args, "warm_views", False),
                       **paged_kw)
 
     return make

@@ -3778,7 +3778,7 @@ class Gateway:
                                if s.timeline is not None]
             host_blocks = [h for h in (s.host_phases() for s in servers)
                            if h is not None]
-        return {
+        out = {
             # fleet dispatch timeline: per-kind count / host-wall ms /
             # compile split / tokens, merged across replicas — the
             # /stats block ROADMAP 4's dispatch-overhead work reads
@@ -3925,3 +3925,19 @@ class Gateway:
             "decode_settles": total("decode_settles"),
             "decode_rounds_dropped": total("decode_rounds_dropped"),
         }
+        if any("moe_tokens_routed" in c for c in counts):
+            # a model of routed experts of which a replica holds a share
+            # (serve/engine.Server.moe_counts, over the decode rounds
+            # read): token-expert pairs chosen, those whose expert is
+            # held there, the load of the fullest held expert summed
+            # over every routed layer's evaluation, and held experts
+            # that took at least one pair, summed likewise
+            for key in ("moe_tokens_routed", "moe_tokens_held",
+                        "moe_expert_load_max", "moe_experts_hit"):
+                out[key] = total(key)
+            out["moe_experts_held"] = max(
+                c.get("moe_experts_held", 0) for c in counts)
+        if any("latent_bytes_per_token" in c for c in counts):
+            out["latent_bytes_per_token"] = max(
+                c.get("latent_bytes_per_token", 0) for c in counts)
+        return out

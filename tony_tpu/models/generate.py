@@ -30,11 +30,17 @@ import jax.numpy as jnp
 
 
 def init_cache(model, params, batch_size: int, dtype=None) -> Any:
-    """Allocate the per-layer KV cache sized by cfg.max_seq_len."""
+    """Allocate the per-layer KV cache sized by cfg.max_seq_len. Only
+    the SHAPES come from ``model.init`` (under ``jax.eval_shape``: no
+    float32 model is ever built beside the served one, which at 2 B a
+    served parameter was twice the served weights again); every cache
+    variable starts at zero, the buffers and the counters alike."""
     cfg = model.cfg
-    tokens = jnp.zeros((batch_size, cfg.max_seq_len), jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), tokens, decode=True)
-    return variables["cache"]
+    tokens = jax.ShapeDtypeStruct((batch_size, cfg.max_seq_len), jnp.int32)
+    shapes = jax.eval_shape(
+        lambda t: model.init(jax.random.PRNGKey(0), t, decode=True)["cache"],
+        tokens)
+    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
 
 
 def sample_logits(logits, rng, temperature, top_k: int, top_p: float = 1.0):
@@ -104,9 +110,11 @@ def _is_eos(tok, eos_ids):
 
 
 def single_decode_step(model, params, cache, tok, positions=None,
-                       page_table=None):
+                       page_table=None, moe_stats: bool = False):
     """ONE token step through the KV cache: feed ``tok`` [b] at the
-    current position(s), return ``(new_cache, last_logits [b, V])``.
+    current position(s), return ``(new_cache, last_logits [b, V])``;
+    with ``moe_stats`` a third value, the step's routed-expert counts
+    summed over its routed layers (``RoutedMLP``: [4] int32).
 
     The shared decode body of ``_generate``'s scan and the serving
     loop's resident step (serve/engine.py): the scalar-index path
@@ -121,9 +129,12 @@ def single_decode_step(model, params, cache, tok, positions=None,
     kwargs = {} if positions is None else {"positions": positions}
     if page_table is not None:
         kwargs["page_table"] = page_table
-    logits, vars_ = model.apply({"params": params, "cache": cache},
-                                tok[:, None], decode=True,
-                                mutable=["cache"], **kwargs)
+    logits, vars_ = model.apply(
+        {"params": params, "cache": cache}, tok[:, None], decode=True,
+        mutable=["cache", "moe_stats"] if moe_stats else ["cache"], **kwargs)
+    if moe_stats:
+        counts = sum(jax.tree_util.tree_leaves(vars_["moe_stats"]))
+        return vars_["cache"], logits[:, -1], counts
     return vars_["cache"], logits[:, -1]
 
 

@@ -14,19 +14,44 @@ No reference analog (TonY has no model code); built TPU-first:
 from __future__ import annotations
 
 import functools
-from dataclasses import dataclass
+import math
+from dataclasses import dataclass, replace
 from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from tony_tpu.parallel.moe import moe_logical_axes
+from tony_tpu.parallel.moe import N_COUNTS, moe_logical_axes, routed_share
 from tony_tpu.parallel.ring_attention import (
     blockwise_attention,
     reference_attention,
     ring_attention,
 )
+
+
+@dataclass(frozen=True)
+class LatentConfig:
+    """Sizes of multi-head latent attention (DeepSeek-V2's MLA): queries
+    through a ``q_rank`` bottleneck, keys and values through a shared
+    ``kv_rank`` latent; each head's query and key are ``nope_dim``
+    unrotated dims from the latent and ``rope_dim`` rotated ones (ONE
+    rotary key for all heads), its value ``v_dim``. ``scale_mult``
+    multiplies the ``(nope_dim + rope_dim) ** -0.5`` softmax scale
+    (YaRN's squared ``mscale_all_dim`` term)."""
+
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    scale_mult: float = 1.0
+
+    @property
+    def cache_width(self) -> int:
+        """Values cached a token a layer: the normed latent and the
+        rotated shared key."""
+        return self.kv_rank + self.rope_dim
 
 
 @dataclass(frozen=True)
@@ -161,9 +186,38 @@ class TransformerConfig:
     # (dp/fsdp/tp) must leave this False:
     # a replicate pin would all-gather batch-sharded activations.
     shard_activations: bool = False
+    # multi-head LATENT attention (``LatentAttention``): the cache holds
+    # one compressed key/value vector and one shared rotary key a token,
+    # not per-head keys and values. None = ``Attention``.
+    latent: LatentConfig | None = None
+    # ROUTED experts of which this chip holds a share (``RoutedMLP``,
+    # parallel/moe.py ``RoutedConfig``): the MLP of every layer from
+    # ``routed.first_dense`` on. None = dense everywhere (or moe_every).
+    routed: Any = None
 
     def __post_init__(self):
         # invalid knob combinations fail at construction, not first apply
+        if self.latent is not None:
+            for knob in ("kv_cache_quant", "quantized", "sliding_window",
+                         "scan_layers"):
+                if getattr(self, knob):
+                    raise ValueError(
+                        f"{knob} is not implemented for latent attention")
+            if self.decode_attention != "einsum":
+                raise ValueError(
+                    "decode_attention='flash' is not implemented for latent "
+                    "attention (the flash-decode kernel reads per-head K/V)")
+            if self.positional != "rope" or self.rotary_dims:
+                raise ValueError("latent attention rotates its own rope_dim "
+                                 "slice: positional='rope', rotary_dims=0")
+        if self.routed is not None:
+            if self.moe_every or self.scan_layers or self.quantized:
+                raise ValueError(
+                    "routed experts are exclusive of moe_every, scan_layers "
+                    "(layers differ) and quantized")
+            if not self.gated_mlp or self.parallel_residual:
+                raise ValueError("routed experts are SwiGLU in a serial "
+                                 "block: gated_mlp=True, no parallel_residual")
         if self.gated_mlp and self.moe_every:
             raise ValueError("gated_mlp is not implemented for MoE expert "
                              "FFNs; use moe_every with gated_mlp=False")
@@ -178,6 +232,15 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.explicit_head_dim or self.d_model // self.n_heads
+
+    @property
+    def cache_values_per_token(self) -> int:
+        """What ONE layer caches for one position, in values of the cache
+        dtype: the cache spec ``serve/slots.kv_page_nbytes`` sizes a page
+        from (int8 K/V adds its float32 scales there)."""
+        if self.latent is not None:
+            return self.latent.cache_width
+        return 2 * self.kv_heads * self.head_dim
 
     @property
     def kv_heads(self) -> int:
@@ -345,18 +408,39 @@ class RopeScaling:
     (long-wavelength) components are divided by ``factor``, high-frequency
     ones kept, with a smooth ramp between the two wavelength thresholds
     derived from ``low_freq_factor``/``high_freq_factor`` and the
-    pre-extension ``original_max_len``.
+    pre-extension ``original_max_len``. kind="yarn": HF's YaRN rule
+    (``_compute_yarn_parameters``) — the pair whose wave turns
+    ``beta_fast`` times over ``original_max_len`` and every faster one
+    is kept, the one turning ``beta_slow`` times and every slower one
+    is divided by ``factor``, a linear ramp over the pair index between
+    (the cos/sin factor is the caller's: 1 where ``mscale`` equals
+    ``mscale_all_dim``).
     """
 
-    kind: str = "llama3"  # llama3 | linear
+    kind: str = "llama3"  # llama3 | linear | yarn
     factor: float = 8.0
     low_freq_factor: float = 1.0
     high_freq_factor: float = 4.0
     original_max_len: int = 8192
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
 
-    def apply(self, freq):
+    def apply(self, freq, theta: float = 10_000.0):
         if self.kind == "linear":
             return freq / self.factor
+        if self.kind == "yarn":
+            half = freq.shape[0]
+
+            def pair(turns):  # the pair index that turns ``turns`` times
+                return half * math.log(self.original_max_len / (
+                    turns * 2 * math.pi)) / math.log(theta)
+
+            low = max(math.floor(pair(self.beta_fast)), 0)
+            high = min(math.ceil(pair(self.beta_slow)), 2 * half - 1)
+            ramp = jnp.clip(
+                (jnp.arange(half, dtype=jnp.float32) - low)
+                / max(high - low, 0.001), 0.0, 1.0)
+            return freq / self.factor * ramp + freq * (1.0 - ramp)
         if self.kind != "llama3":
             raise ValueError(f"unknown rope scaling kind {self.kind!r}")
         two_pi = 2.0 * jnp.pi
@@ -387,7 +471,7 @@ def rotary_embedding(x, positions, theta: float = 10_000.0,
     half = d // 2
     freq = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
     if scaling is not None:
-        freq = scaling.apply(freq)
+        freq = scaling.apply(freq, theta)
     angles = positions[..., None].astype(jnp.float32) * freq  # [..., half]
     if angles.ndim == 3:  # per-row positions [B, L, half]
         cos = jnp.cos(angles)[:, :, None, :]
@@ -754,6 +838,185 @@ class Attention(nn.Module):
         return out.reshape(b, l, h, dh).astype(q.dtype)
 
 
+def latent_attend(q_n, q_r, c_kv, k_r, w_kvb, visible, scale):
+    """Latent attention with keys and values MATERIALISED from the cache
+    (the prefill form): ``[k_n; v]`` of every head from the latent, then
+    plain masked softmax attention. ``q_n`` [b, l, h, nope], ``q_r``
+    [b, l, h, rope] (rotated), ``c_kv`` [b, m, kv_rank] (the normed
+    latent), ``k_r`` [b, m, rope] (the rotated shared key), ``w_kvb``
+    [kv_rank, h, nope + v], ``visible`` [b | 1, l, m]. Returns [b, l, h,
+    v]. Scores and softmax are float32; operands stay in the activation
+    dtype."""
+    nope = q_n.shape[-1]
+    kv = jnp.einsum("bmr,rhd->bmhd", c_kv, w_kvb)
+    k_n, v = kv[..., :nope], kv[..., nope:]
+    s = jnp.einsum("blhd,bmhd->bhlm", q_n, k_n,
+                   preferred_element_type=jnp.float32) \
+        + jnp.einsum("blhd,bmd->bhlm", q_r, k_r,
+                     preferred_element_type=jnp.float32)
+    p = jax.nn.softmax(jnp.where(visible[:, None], s * scale, -1e30), axis=-1)
+    return jnp.einsum("bhlm,bmhd->blhd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32).astype(q_n.dtype)
+
+
+def latent_attend_absorbed(q_n, q_r, c_kv, k_r, w_kvb, visible, scale):
+    """The same attention with the key and value projections ABSORBED
+    (the decode form): the query is folded into the latent space
+    (``q_n W_kvb[K]``, per head) and scored against the cached latent
+    itself, the rotary part against the shared key; the softmax is
+    summed over the latent, and only that [b, l, h, kv_rank] result goes
+    through ``W_kvb[V]``. Nothing of shape [b, m, h, ...] is ever
+    formed: the step reads the cache's ``kv_rank + rope`` values a
+    position and that is all. Same arguments and result as
+    ``latent_attend``."""
+    nope, f32 = q_n.shape[-1], jnp.float32
+    # float32 operands, as Attention's cached path has them: the chip
+    # multiplies in bfloat16 at default precision either way and the
+    # converts fuse into the products; the CPU has no mixed product
+    w_k, w_v = w_kvb[..., :nope].astype(f32), w_kvb[..., nope:].astype(f32)
+    c_kv = c_kv.astype(f32)
+    q_lat = jnp.einsum("blhd,rhd->blhr", q_n.astype(f32), w_k)
+    s = jnp.einsum("blhr,bmr->bhlm", q_lat, c_kv) \
+        + jnp.einsum("blhd,bmd->bhlm", q_r.astype(f32), k_r.astype(f32))
+    p = jax.nn.softmax(jnp.where(visible[:, None], s * scale, -1e30), axis=-1)
+    o_lat = jnp.einsum("bhlm,bmr->blhr", p, c_kv)
+    return jnp.einsum("blhr,rhd->blhd", o_lat, w_v).astype(q_n.dtype)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (``cfg.latent``). With x the normed
+    input: ``c_q = RMSNorm(x W_qa)``, ``[q_n; q_r] = c_q W_qb`` per head;
+    ``[c_kv; k_r] = x W_kva``, ``c_kv = RMSNorm(c_kv)``, ``k_r`` rotated
+    (one rotary key for all heads); ``[k_n; v] = c_kv W_kvb`` per head;
+    score ``(q_n . k_n + RoPE(q_r) . k_r) * scale``, causal softmax in
+    float32, then ``W_o``.
+
+    The cache holds ``kv_rank + rope`` values a token a layer and no
+    per-head key or value: ``cached_latent`` [b, max_len, kv_rank]
+    (``c_kv`` after its norm) and ``cached_rope_key`` [b, max_len, rope]
+    (``k_r`` after RoPE); page pools [n_pages, page_size, ...] when
+    paged. Two leaves and not one of their joint width: a minor
+    dimension that is no multiple of the TPU's 128 lanes makes the
+    compiler store the pool pages-minor, and every step then transposes
+    the whole pool in and out (compiled for the v5e at kv_rank 512, rope
+    64: one copy of the pool a layer each way). The three write modes
+    are ``Attention._decode_attention``'s (shared ``cache_index``;
+    per-slot ``positions``; per-slot through a ``page_table``, where
+    sentinel pages and padding drop), and so is the visibility mask. A
+    window of several tokens (prefill, a chunk of one) materialises
+    keys and values from the latent (``latent_attend``); a single-token
+    step runs absorbed (``latent_attend_absorbed``)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, decode: bool = False, segment_ids=None,
+                 positions=None, page_table=None):
+        cfg, la = self.cfg, self.cfg.latent
+        if segment_ids is not None:
+            raise ValueError("segment_ids are not implemented for latent "
+                             "attention")
+        b, l, _ = x.shape
+        h = cfg.n_heads
+        init = nn.initializers.normal(0.02)
+        dense = lambda name, feats: nn.DenseGeneral(  # noqa: E731
+            feats, axis=-1, use_bias=False, dtype=cfg.dtype,
+            param_dtype=jnp.float32, name=name, kernel_init=init)
+        norm = lambda name: RMSNorm(cfg.dtype, cfg.norm_eps,  # noqa: E731
+                                    name=name)
+        q = dense("q_b", (h, la.nope_dim + la.rope_dim))(
+            norm("q_norm")(dense("q_a", la.q_rank)(x)))
+        q_n, q_r = q[..., :la.nope_dim], q[..., la.nope_dim:]
+        kv = dense("kv_a", la.cache_width)(x)
+        c_kv, k_r = norm("kv_norm")(kv[..., :la.kv_rank]), kv[..., la.kv_rank:]
+        w_kvb = self.param("kv_b", init, (la.kv_rank, h,
+                                          la.nope_dim + la.v_dim),
+                           jnp.float32).astype(cfg.dtype)
+        scale = la.scale_mult * (la.nope_dim + la.rope_dim) ** -0.5
+        rope = lambda t, pos: rotary_embedding(  # noqa: E731
+            t, pos, cfg.rope_theta, cfg.rope_scaling)
+        if decode:
+            out = self._cached(q_n, q_r, c_kv, k_r, w_kvb, scale, rope,
+                               positions, page_table)
+        else:
+            pos = jnp.arange(l)
+            with jax.named_scope("mla.prefill"):
+                out = latent_attend(
+                    q_n, rope(q_r, pos), c_kv,
+                    rope(k_r[:, :, None], pos)[:, :, 0], w_kvb,
+                    (pos[None, :] <= pos[:, None])[None], scale)
+        return nn.DenseGeneral(
+            cfg.d_model, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
+            param_dtype=jnp.float32, name="o", kernel_init=init)(out)
+
+    def _cached(self, q_n, q_r, c_kv, k_r, w_kvb, scale, rope, positions,
+                page_table):
+        cfg, la = self.cfg, self.cfg.latent
+        b, l, h, _ = q_n.shape
+        max_len = cfg.max_seq_len
+        is_init = self.has_variable("cache", "cached_latent")
+        leaves = [self.variable("cache", name, jnp.zeros,
+                                (b, max_len, width), c_kv.dtype)
+                  for name, width in (("cached_latent", la.kv_rank),
+                                      ("cached_rope_key", la.rope_dim))]
+        cache_index = self.variable("cache", "cache_index",
+                                    lambda: jnp.array(0, jnp.int32))
+        if not is_init:  # shape-only init pass
+            return jnp.zeros((b, l, h, la.v_dim), q_n.dtype)
+        per_slot = positions is not None
+        if page_table is not None and not per_slot:
+            raise ValueError("page_table requires per-slot positions")
+        cur = cache_index.value
+        if per_slot:
+            pos2d = positions[:, None] if positions.ndim == 1 else positions
+            if pos2d.shape != (b, l):
+                raise ValueError(
+                    f"positions shape {positions.shape} does not match "
+                    f"the token window ({b}, {l})")
+        q_pos = pos2d if per_slot else (cur + jnp.arange(l))[None, :]
+        rope_pos = pos2d if per_slot else cur + jnp.arange(l)
+        q_r = rope(q_r, rope_pos)
+        new = (c_kv, rope(k_r[:, :, None], rope_pos)[:, :, 0])
+        if page_table is not None:
+            # the paged scatter and the position-ordered gather of
+            # Attention._decode_attention, over these two leaves
+            n_pages, ps = leaves[0].value.shape[-3:-1]
+            span = page_table.shape[1] * ps
+            valid = (pos2d >= 0) & (pos2d < span)
+            safe = jnp.where(valid, pos2d, 0)
+            page = jnp.take_along_axis(page_table, safe // ps, axis=1)
+            page = jnp.where(valid, page, n_pages)  # drop via OOB
+            tab = jnp.clip(page_table, 0, n_pages - 1)
+            seen = []
+            for leaf, val in zip(leaves, new):
+                leaf.value = leaf.value.at[page, safe % ps].set(
+                    val, mode="drop")
+                seen.append(jnp.take(leaf.value, tab, axis=0).reshape(
+                    b, span, -1))
+        elif per_slot:
+            rows = jnp.arange(b)[:, None]
+            write = jnp.where(pos2d >= 0, pos2d, max_len)  # drop, never clamp
+            for leaf, val in zip(leaves, new):
+                leaf.value = leaf.value.at[rows, write].set(val, mode="drop")
+            seen = [leaf.value for leaf in leaves]
+        else:
+            for leaf, val in zip(leaves, new):
+                leaf.value = jax.lax.dynamic_update_slice(
+                    leaf.value, val, (0, cur, 0))
+            seen = [leaf.value for leaf in leaves]
+            cache_index.value = cur + l
+        # sized by the BUFFER: the engine's bucketed views are shorter
+        # than max_len, and a masked column weighs exactly 0.0
+        kv_pos = jnp.arange(seen[0].shape[1])
+        visible = kv_pos[None, None, :] <= q_pos[:, :, None]
+        if l == 1:
+            with jax.named_scope("mla.absorb"):
+                return latent_attend_absorbed(q_n, q_r, *seen, w_kvb,
+                                              visible, scale)
+        with jax.named_scope("mla.prefill"):
+            return latent_attend(q_n, q_r, *seen, w_kvb, visible, scale)
+
+
 def _q8_shard_axes(cfg: TransformerConfig, name: str) -> tuple:
     """(in_axis, out_axis) mesh axes for a QuantDense, mirroring the
     'tp' preset's logical rules in logical_axis_rules_tree: q/wi/wg
@@ -969,17 +1232,64 @@ class MoEMLP(nn.Module):
         return _serve_replicate(cfg, out.astype(cfg.dtype))
 
 
+class RoutedMLP(nn.Module):
+    """This chip's share of a routed expert layer (``cfg.routed``,
+    parallel/moe.py ``routed_share``) plus the shared expert: the
+    router keeps its published width, the expert leaves ``wg``/``wi``
+    [held, d, f] and ``wo`` [held, f, d] hold only the experts that live
+    here. ``live`` [b, l] keeps padding and empty slots out of the
+    routing (they neither count nor touch an expert). Outside ``init``
+    the layer sows its counts (``routed_share``) into the
+    ``moe_stats`` collection, summed over the routed layers of the call;
+    a caller that does not make the collection mutable pays nothing."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, live=None):
+        cfg, rc = self.cfg, self.cfg.routed
+        b, l, d = x.shape
+        init = nn.initializers.normal(0.02)
+        n_held = rc.held[1]
+        leaf = lambda name, shape: self.param(  # noqa: E731
+            name, init, shape, jnp.float32).astype(cfg.dtype)
+        router = leaf("router", (d, rc.n_routed))
+        wg, wi = (leaf(n, (n_held, d, rc.d_ff)) for n in ("wg", "wi"))
+        wo = leaf("wo", (n_held, rc.d_ff, d))
+        y, counts = routed_share(
+            x.reshape(b * l, d), router, wg, wi, wo, rc,
+            None if live is None else live.reshape(b * l))
+        if not self.is_initializing():
+            self.sow("moe_stats", "counts", counts,
+                     reduce_fn=lambda acc, c: acc + c,
+                     init_fn=lambda: jnp.zeros((N_COUNTS,), jnp.int32))
+        y = y.reshape(b, l, d)
+        if rc.shared_d_ff:
+            with jax.named_scope("moe.shared"):
+                y = y + MLP(replace(cfg, d_ff=rc.shared_d_ff),
+                            name="shared")(x)
+        return y.astype(cfg.dtype)
+
+
 class Block(nn.Module):
     cfg: TransformerConfig
     use_moe: bool = False
+    use_routed: bool = False
 
     @nn.compact
     def __call__(self, x, decode: bool = False, segment_ids=None,
                  positions=None, page_table=None):
-        attn_out = Attention(self.cfg, name="attn")(
+        attn_cls = Attention if self.cfg.latent is None else LatentAttention
+        attn_out = attn_cls(self.cfg, name="attn")(
             make_norm(self.cfg, "ln1")(x), decode=decode,
             segment_ids=segment_ids, positions=positions,
             page_table=page_table)
+        if self.use_routed:
+            live = None if positions is None \
+                else (positions >= 0).reshape(x.shape[0], -1)
+            x = x + attn_out
+            return x + RoutedMLP(self.cfg, name="moe")(
+                make_norm(self.cfg, "ln2")(x), live)
         ffn_cls = MoEMLP if self.use_moe else MLP
         if (self.cfg.remat and not decode
                 and self.cfg.remat_policy == "attn_saved"):
@@ -1143,7 +1453,10 @@ class Transformer(nn.Module):
                                      policy=policy)
             for i in range(cfg.n_layers):
                 use_moe = cfg.moe_every > 0 and (i + 1) % cfg.moe_every == 0
-                x = block(cfg, use_moe=use_moe, name=f"block_{i}")(
+                use_routed = cfg.routed is not None \
+                    and i >= cfg.routed.first_dense
+                x = block(cfg, use_moe=use_moe, use_routed=use_routed,
+                          name=f"block_{i}")(
                     x, decode, segment_ids=segment_ids, positions=positions,
                     page_table=page_table)
         x = make_norm(cfg, "ln_f")(x)

@@ -1,5 +1,15 @@
 """Mixture-of-Experts with expert parallelism.
 
+Two layers live here. ``moe_layer`` (below) is the Switch/Mixtral-style
+one: softmax gates, every expert on the chip or sharded by GSPMD,
+capacity-limited dispatch einsums (training) or a dense evaluation of
+every expert on every token (``dropless``, checkpoint parity).
+``routed_share`` (at the end) is the SERVED one: a chip is told which
+experts of a wider router it holds, routes over all of them, and
+computes its own experts' part of the result with work that follows the
+routing (tokens sorted by expert, a grouped product) — one chip's share
+of wide expert parallelism, run without its exchange.
+
 Absent from the reference (SURVEY.md section 2.4: EP "NO"). Implementation
 is the pjit idiom: expert weights carry a leading expert dim annotated with
 the ``expert`` mesh axis; dispatch/combine are einsums against a capacity-
@@ -14,6 +24,35 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+
+
+@dataclass(frozen=True)
+class RoutedConfig:
+    """A routed expert layer as ONE chip of a wider deployment sees it
+    (``routed_share``): the router scores all ``n_routed`` experts and
+    picks ``top_k`` a token; this chip holds the ``held[1]`` experts
+    from index ``held[0]`` on and adds what they give, times
+    ``scaling``. ``shared_d_ff`` > 0 adds a shared SwiGLU expert of that
+    width, computed whole on every chip; the first ``first_dense``
+    layers of the model keep a dense MLP. Hashable: it rides
+    ``TransformerConfig``, a static argument of jitted code."""
+
+    n_routed: int
+    top_k: int
+    d_ff: int
+    held: tuple = (0, 0)
+    scaling: float = 1.0
+    shared_d_ff: int = 0
+    first_dense: int = 0
+
+    def __post_init__(self):
+        first, count = self.held
+        if not (0 <= first and count > 0
+                and first + count <= self.n_routed):
+            raise ValueError(f"held={self.held} is no range of the "
+                             f"{self.n_routed} routed experts")
+        if not 0 < self.top_k <= self.n_routed:
+            raise ValueError(f"top_k={self.top_k} of {self.n_routed}")
 
 
 @dataclass
@@ -262,3 +301,101 @@ def moe_layer(params: dict, x: jnp.ndarray, cfg: MoEConfig):
                              "ecd,edf->ecf", "ecf,efd->ecd")
     out = jnp.einsum("ecd,tec->td", expert_out, combine)
     return out.reshape(b, l, d), aux
+
+
+# ------------------------------------------------ one chip's routed share
+
+def sigmoid_top_k(logits, k: int, scaling: float = 1.0):
+    """DeepSeek-V3-style routing without groups or a selection bias:
+    ``p = sigmoid(logits)`` (float32), the ``k`` largest, their weights
+    renormalised to sum to 1 and multiplied by ``scaling``. Returns
+    (weights [T, k] float32, expert indices [T, k])."""
+    p = jax.nn.sigmoid(logits.astype(jnp.float32))
+    top, idx = jax.lax.top_k(p, k)
+    return top / (jnp.sum(top, -1, keepdims=True) + 1e-20) * scaling, idx
+
+
+N_COUNTS = 4  # what ``routed_share`` counts beside its result
+
+
+def _held_here(idx, first: int, n_held: int, live):
+    """(the held experts' own index of each chosen one, whether that
+    (token, choice) pair is computed here): its expert is one of the
+    ``n_held`` from ``first`` on and its token is ``live``."""
+    local = idx - first
+    here = (local >= 0) & (local < n_held)
+    if live is not None:
+        here = here & live[:, None]
+    return local, here
+
+
+def grouped_experts(x, idx, w, wg, wi, wo, first: int, live=None):
+    """What the experts held here add: ``sum_j w[t, j] *
+    expert_{idx[t, j]}(x[t])`` over the pairs whose expert is one of the
+    ``wg.shape[0]`` held from index ``first`` on (and whose token is
+    ``live``). The (token, choice) pairs are sorted by expert, held
+    ones first, and each expert's run of rows goes through its own
+    weights in ONE grouped product (``jax.lax.ragged_dot``; a Mosaic
+    kernel on the TPU that visits only the row tiles a group covers):
+    FLOPs and weight bytes follow the routing — an expert no pair chose
+    is not read, a pair sent elsewhere costs a gathered row and no
+    product. ``x`` [T, d]; ``idx``/``w`` [T, k]; ``wg``/``wi`` [H, d,
+    f], ``wo`` [H, f, d]. Returns (y [T, d] float32, group sizes [H])."""
+    t, k = idx.shape
+    n_held = wg.shape[0]
+    local, here = _held_here(idx, first, n_held, live)
+    group = jnp.where(here, local, n_held).reshape(-1)     # absent: last
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.zeros((n_held + 1,), jnp.int32).at[group].add(1)[:n_held]
+    rows = x[order // k]                                   # [T k, d]
+    dot = lambda a, b: jax.lax.ragged_dot(  # noqa: E731
+        a, b, sizes, preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(dot(rows, wg)) * dot(rows, wi)).astype(x.dtype)
+    ys = dot(h, wo)
+    # rows past the held pairs belong to no group: whatever the kernel
+    # left there must not reach the sum (0 * NaN)
+    ys = jnp.where((jnp.arange(t * k) < jnp.sum(sizes))[:, None], ys, 0.0)
+    back = jnp.argsort(order)                              # undo the sort
+    ys = ys[back].reshape(t, k, -1)
+    return jnp.sum(ys * jnp.where(here, w, 0.0)[..., None], axis=1), sizes
+
+
+def dense_experts(x, idx, w, wg, wi, wo, first: int, live=None):
+    """The same sum by evaluating EVERY held expert on every token and
+    weighting: what ``grouped_experts`` is tested against, and nothing a
+    served path calls. Returns (y [T, d] float32, group sizes [H])."""
+    n_held = wg.shape[0]
+    local, here = _held_here(idx, first, n_held, live)
+    hot = jax.nn.one_hot(jnp.where(here, local, n_held), n_held + 1,
+                         dtype=jnp.float32)[..., :n_held]   # [T, k, H]
+    up = lambda w_: jnp.einsum(  # noqa: E731
+        "td,edf->etf", x, w_, preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(up(wg)) * up(wi)).astype(x.dtype)
+    per = jnp.einsum("etf,efd->etd", h, wo,
+                     preferred_element_type=jnp.float32)
+    weight = jnp.einsum("tkh,tk->th", hot, w)
+    return jnp.einsum("etd,te->td", per, weight), \
+        jnp.sum(hot, axis=(0, 1)).astype(jnp.int32)
+
+
+def routed_share(x, router, wg, wi, wo, cfg: RoutedConfig, live=None,
+                 experts=grouped_experts):
+    """One chip's share of a routed expert layer on tokens ``x`` [T, d]:
+    route over ALL ``cfg.n_routed`` experts (``router`` [d, n_routed],
+    scores in float32), add what the experts held here give, leave out
+    what the absent ones would (no code stands in for them or for the
+    exchange). ``live`` [T] masks padding and empty slots out of the
+    routing. Returns ``(y [T, d] float32, counts [N_COUNTS] int32)``: the
+    token-expert pairs chosen by live tokens, those of them whose
+    expert is held here, the most pairs one held expert took, and how
+    many held experts took at least one (whose weights were read)."""
+    with jax.named_scope("moe.route"):
+        logits = jnp.einsum("td,de->te", x, router,
+                            preferred_element_type=jnp.float32)
+        w, idx = sigmoid_top_k(logits, cfg.top_k, cfg.scaling)
+    with jax.named_scope("moe.experts"):
+        y, sizes = experts(x, idx, w, wg, wi, wo, cfg.held[0], live)
+    n_live = x.shape[0] if live is None else jnp.sum(live)
+    counts = jnp.stack([n_live * cfg.top_k, jnp.sum(sizes), jnp.max(sizes),
+                        jnp.sum(sizes > 0)]).astype(jnp.int32)
+    return y, counts

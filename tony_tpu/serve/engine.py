@@ -62,6 +62,7 @@ from tony_tpu.obs.goodput import (CostModel, detect_hbm_gbps,
                                   detect_peak_flops, ledger)
 from tony_tpu.obs.phases import HostPhases
 from tony_tpu.obs.timeline import DispatchRecord, DispatchTimeline
+from tony_tpu.parallel.moe import N_COUNTS
 from tony_tpu.serve.faults import FaultPlan
 from tony_tpu.serve.migrate import SessionSnapshot, StaleDelta, \
     snapshot_from_doc
@@ -70,7 +71,7 @@ from tony_tpu.serve.slots import (PagePool, SlotCache, _copy_page,
                                   _gather_pages, _read_slot,
                                   _scatter_pages, apply_patch,
                                   cache_batch_axis, default_page_size,
-                                  pack_state, paged_view,
+                                  kv_page_nbytes, pack_state, paged_view,
                                   paged_write_back, unpack_state)
 from tony_tpu.serve.tier import (HostPageTier, decode_array,
                                  decode_payload, pad_host_pages,
@@ -348,7 +349,8 @@ def _sample_rows(logits, rngs, temps, top_ks):
                         lambda _: (greedy, rngs), None)
 
 
-def _frozen_body(model, params, temps, top_ks, eos_ids: tuple):
+def _frozen_body(model, params, temps, top_ks, eos_ids: tuple,
+                 moe_stats: bool = False):
     """The decode micro-step: the scan body of ``_decode_chunk`` and of
     ``_verify_chunk``'s continuation. Carry is ``(cache, tok,
     positions, rngs, done, rem)``; a row whose emitted token hit EOS —
@@ -359,12 +361,15 @@ def _frozen_body(model, params, temps, top_ks, eos_ids: tuple):
     token, so the host's walk past a finish is a consistency check and
     the trailing positions land as padding. For a row that never
     freezes every ``where`` is the identity, which is what keeps
-    chunk-invariance bitwise."""
+    chunk-invariance bitwise. With ``moe_stats`` (a model of routed
+    experts, ``_decode_chunk`` only) each step also emits its layers'
+    routed-expert counts beside its tokens: ``(nxt, counts [4])``."""
     def body(carry, _):
         cache, tok, positions, rngs, done, rem = carry
         eff_pos = jnp.where(done, -1, positions)
-        cache, last = single_decode_step(model, params, cache, tok,
-                                         positions=eff_pos)
+        cache, last, *counts = single_decode_step(
+            model, params, cache, tok, positions=eff_pos,
+            moe_stats=moe_stats)
         nxt, rngs = _sample_rows(last, rngs,
                                  jnp.where(done, 0.0, temps), top_ks)
         nxt = jnp.where(done, tok, nxt.astype(jnp.int32))
@@ -372,7 +377,8 @@ def _frozen_body(model, params, temps, top_ks, eos_ids: tuple):
                               positions + 1)
         rem = jnp.where(done, rem, rem - 1)
         done = done | _is_eos(nxt, eos_ids) | (rem <= 0)
-        return (cache, nxt, positions, rngs, done, rem), nxt
+        return (cache, nxt, positions, rngs, done, rem), \
+            (nxt, counts[0]) if moe_stats else nxt
 
     return body
 
@@ -433,15 +439,21 @@ def _decode_chunk(model, params, cache, state, patch, table=None, *,
     unwritten tail positions copy their own gathered content back —
     an identity write). The table is fixed across the chunk, so the
     host pre-extends it to cover every position the chunk will write
-    (engine ``_enqueue_round``)."""
+    (engine ``_enqueue_round``).
+
+    A model of routed experts (``cfg.routed``) hands its counts back IN
+    the token array: ``N_COUNTS`` more columns, the round's sums of
+    ``RoutedMLP``'s four counts, the same in every row — they ride the
+    one copy back a round makes (``Server._land`` splits them off)."""
     max_len = model.cfg.max_seq_len
+    moe_stats = model.cfg.routed is not None
     state = apply_patch(state, patch)
     tok, positions, rem, top_ks, temps, rngs = unpack_state(state)
     pool_cache, start = cache, positions
     if table is not None:
         cache = paged_view(cache, table, max_len)
 
-    body = _frozen_body(model, params, temps, top_ks, eos_ids)
+    body = _frozen_body(model, params, temps, top_ks, eos_ids, moe_stats)
     # a row that finished in the round BEFORE this one, and that the
     # host has not seen yet (this round was enqueued before that one's
     # tokens were read), starts frozen: its budget is spent or its last
@@ -450,12 +462,20 @@ def _decode_chunk(model, params, cache, state, patch, table=None, *,
     # seed is ``positions < 0`` as it always was
     done = (positions < 0) | (rem <= 0) | _is_eos(tok, eos_ids)
     carry = (cache, tok, positions, rngs, done, rem)
+    counts = None
     if n_steps > 1:
         carry, toks = jax.lax.scan(body, carry, None, length=n_steps)
+        if moe_stats:
+            toks, counts = toks[0], jnp.sum(toks[1], axis=0)
         toks = jnp.moveaxis(toks, 0, 1)  # [steps, b] -> [b, steps]
     else:
         carry, tok1 = body(carry, None)
+        if moe_stats:
+            tok1, counts = tok1
         toks = tok1[:, None]
+    if moe_stats:
+        toks = jnp.concatenate([toks, jnp.broadcast_to(
+            counts, (toks.shape[0], N_COUNTS))], axis=1)
     cache, tok, positions, rngs, _, rem = carry
     if table is not None:
         cache = paged_write_back(pool_cache, cache, table, start,
@@ -787,7 +807,8 @@ class Server:
                  hbm_gbps: float = 0.0, prefill_chunk_tokens: int = 0,
                  kv_host_mb: float = 0.0, mesh=None,
                  shard_rules: str = "serve",
-                 page_pool: PagePool | None = None):
+                 page_pool: PagePool | None = None,
+                 warm_views: bool = False):
         if model.cfg.quantized:
             # nothing structural in the way — the q8 apply is the same
             # model.apply — but untested here; fail loud, not wrong
@@ -800,6 +821,24 @@ class Server:
             # parity — the store's contract — is unpinned; fail loud
             raise NotImplementedError(
                 "prefix cache over sliding-window models is untested")
+        if model.cfg.latent is not None:
+            # a latent cache is one leaf a layer with no head axis, and
+            # the decode step runs absorbed. The page pool (private or
+            # shared), the prefix store over it and the fixed-shape rows
+            # carry that leaf as they carry K/V (``cache_batch_axis``;
+            # tests/test_latent_moe.py pins them). What was never run
+            # over the layout is refused by name, not guessed: a mesh
+            # (``kv_cache_shardings`` splits a head axis the leaf does
+            # not have), the verify window, and the host page tier.
+            refused = {"mesh": mesh is not None,
+                       "speculate_k": speculate_k > 0,
+                       "kv_host_mb": kv_host_mb > 0}
+            for option, asked in refused.items():
+                if asked:
+                    raise NotImplementedError(
+                        f"{option} is untested over a latent-attention "
+                        "model (cfg.latent): serve it on one chip, "
+                        "without speculation or the host page tier")
         if paged and model.cfg.sliding_window:
             # same precedent: the paged gather itself is window-agnostic
             # but bitwise greedy parity against the unpaged windowed
@@ -964,6 +1003,15 @@ class Server:
         self.rounds_overlapped = 0  # rounds enqueued behind an unread one
         self.settles = 0            # times the queue was drained early
         self.rounds_dropped = 0     # rounds enqueued and never read
+        # a model of routed experts (``cfg.routed``): RoutedMLP's counts,
+        # summed over the decode rounds read so far (they ride each
+        # round's token array: ``_decode_chunk``). Pairs a live token
+        # chose, those whose expert is held here, and, summed over
+        # every routed layer's evaluation, the most pairs one held
+        # expert took in it (x held experts / pairs held = max over
+        # mean load) and the held experts that took any
+        self.moe_counts = np.zeros(N_COUNTS, np.int64) \
+            if model.cfg.routed is not None else None
         self._decode_end = 0.0      # host clock at the last round's end
         self._ids = itertools.count()
         self.steps = 0       # decode dispatch DEPTH, summed (chunk k /
@@ -1029,6 +1077,12 @@ class Server:
                     / max(1, cfg.max_seq_len)
             head_dim = cfg.explicit_head_dim \
                 or cfg.d_model // cfg.n_heads
+            if cfg.latent is not None:
+                # the absorbed step scores over the cache's whole width
+                # and sums over the latent: (width + rank) MACs a head a
+                # cached position where K/V attention has 2 head_dim
+                head_dim = (cfg.latent.cache_width
+                            + cfg.latent.kv_rank) // 2
             # sharded replicas price dispatches PER CHIP (the ISSUE-14
             # goodput rule): each chip reads its param shard and its
             # kv-head slice of the pools, so bytes/FLOPs divide by the
@@ -1164,6 +1218,42 @@ class Server:
                 self.slots.cache = _copy_page(
                     self.slots.cache, jnp.int32(0), jnp.int32(0))
 
+        if warm_views and self.paged:
+            self.warm_views()
+
+    def warm_views(self) -> int:
+        """Compile the chunk program for EVERY view bucket and depth the
+        scheduler can ask for, by running each once with every row
+        empty (an empty row writes nothing). A bucket otherwise
+        compiles when the longest live row first crosses into it, under
+        live traffic, and every stream stalls for the compile — the
+        same argument as the copy-on-write fork's above. What a
+        deployment's warm-up traffic reaches needs none of this; the
+        buckets past its longest PROMPT do (a row grows into them by
+        decoding). Returns the programs run. Call it on an idle
+        engine."""
+        s = self.slots
+        buckets, cols = [], 1
+        while cols < s.max_pages:
+            buckets.append(cols)
+            cols *= 2
+        buckets.append(s.max_pages)
+        k, depths = 1, []
+        while k <= self.chunk_steps:
+            depths.append(k)
+            k *= 2
+        for cols in buckets:
+            table = s.device_table(cols)
+            for k in depths:
+                with self._tree_lock:
+                    s.cache, toks, _ = _decode_chunk(
+                        self.model, self.params, s.cache, s.state,
+                        s._no_patch, table, n_steps=k,
+                        eos_ids=self.eos_ids)
+                jax.block_until_ready(toks)
+                self._compiled.add(("decode", k, cols * s.pool.page_size))
+        return len(buckets) * len(depths)
+
     # ----------------------------------------------------- observability
 
     def _record_dispatch(self, kind: str, t0: float, dur_ms: float,
@@ -1266,6 +1356,12 @@ class Server:
             raise ValueError("a migrated session is already past "
                              "prefill — it cannot also be a "
                              "prefill_only/handoff half")
+        if (request.prefill_only or request.handoff is not None
+                or request.migrate is not None) \
+                and self.model.cfg.latent is not None:
+            raise NotImplementedError(
+                "prefill_only/handoff/migrate are not implemented for a "
+                "latent-attention model (cfg.latent)")
         if (request.prefill_only or request.handoff is not None
                 or request.migrate is not None) and not self.paged:
             raise ValueError(
@@ -2419,6 +2515,10 @@ class Server:
         bytes move, and adopt is a page-table install. ``wire=True``:
         the snapshot holds gathered page CONTENT (a device pytree) fit
         for ``snapshot_to_doc`` and the agent wire."""
+        if self.model.cfg.latent is not None:
+            raise NotImplementedError(
+                "extract_session (migration) is not implemented for a "
+                "latent-attention model (cfg.latent)")
         with self._dispatch_lock:
             s = self.slots
             pool = s.pool
@@ -2983,6 +3083,9 @@ class Server:
         s = self.slots
         k = rnd.k
         toks = np.asarray(rnd.toks)  # [b, k]: the host sync
+        if self.moe_counts is not None:  # the counts ride behind them
+            self.moe_counts += toks[0, k:]
+            toks = toks[:, :k]
         # the round's clock starts at the LATER of its enqueue and its
         # predecessor's end: two rounds in flight share wall, and the
         # ledger's buckets must count it once. It closes at the host
@@ -3425,6 +3528,16 @@ class Server:
             "migrate_freeze_resume_ms": round(
                 self.migrate_freeze_resume_ms, 3),
         }
+        if self.moe_counts is not None:
+            routed, held, load_max, hit = (int(c) for c in self.moe_counts)
+            out["moe_tokens_routed"] = routed
+            out["moe_tokens_held"] = held
+            out["moe_expert_load_max"] = load_max
+            out["moe_experts_hit"] = hit
+            out["moe_experts_held"] = self.model.cfg.routed.held[1]
+        if self.model.cfg.latent is not None:
+            out["latent_bytes_per_token"] = kv_page_nbytes(
+                self.model.cfg, 1)
         if self.mesh is not None:
             # flat numeric twins of mesh_info() so MetricsStore and
             # the remote agent's counters wire carry the topology

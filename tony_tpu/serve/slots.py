@@ -32,7 +32,8 @@ def cache_batch_axis(path, leaf) -> int | None:
     """Batch (slot) axis of a cache leaf, or None for non-batched leaves.
 
     KV buffers are [..., b, max_len, kvh, dh] — batch is 4th-from-last;
-    their quant scales are [..., b, max_len, kvh] — 3rd-from-last.
+    their quant scales are [..., b, max_len, kvh] — 3rd-from-last, as
+    are a latent attention layer's two leaves [..., b, max_len, width].
     scan_layers models prepend an n_layers axis, which this arithmetic
     skips (keying on axis 0 would slice the LAYERS axis). Index counters
     (cache_index/pos_index) carry no batch dim: per-slot decode neither
@@ -41,7 +42,8 @@ def cache_batch_axis(path, leaf) -> int | None:
     name = str(path[-1].key if hasattr(path[-1], "key") else path[-1])
     if name in ("cached_key", "cached_value"):
         return leaf.ndim - 4
-    if name in ("cached_key_scale", "cached_value_scale"):
+    if name in ("cached_key_scale", "cached_value_scale", "cached_latent",
+                "cached_rope_key"):
         return leaf.ndim - 3
     return None
 
@@ -131,32 +133,21 @@ def paged_cache(model, params, n_pages: int, page_size: int,
     preserved by the same from-the-right axis arithmetic
     ``cache_batch_axis`` uses.
 
-    ``mesh`` (sharded serving, ISSUE-14): the pool allocates DIRECTLY
-    under its kv-head shardings — shapes come from ``eval_shape`` (no
-    dense batch-1 init pass materializes either), so one chip never
-    holds more than its shard (``_alloc_sharded``)."""
+    Shapes come from ``eval_shape``: no batch-1 row is materialized on
+    the way. ``mesh`` (sharded serving, ISSUE-14): the pool allocates
+    DIRECTLY under its kv-head shardings, so one chip never holds more
+    than its shard (``_alloc_sharded``)."""
     def remap(path, leaf):
         ax = cache_batch_axis(path, leaf)
-        if ax is None:
-            return leaf
-        shape = leaf.shape[:ax] + (n_pages, page_size) + leaf.shape[ax + 2:]
-        return jnp.zeros(shape, leaf.dtype)
+        shape = leaf.shape if ax is None else \
+            leaf.shape[:ax] + (n_pages, page_size) + leaf.shape[ax + 2:]
+        return jax.ShapeDtypeStruct(shape, leaf.dtype)
 
+    structs = jax.tree_util.tree_map_with_path(
+        remap, jax.eval_shape(lambda p: init_cache(model, p, 1), params))
     if mesh is not None:
-        base = jax.eval_shape(lambda p: init_cache(model, p, 1), params)
-
-        def remap_struct(path, leaf):
-            ax = cache_batch_axis(path, leaf)
-            shape = leaf.shape if ax is None else \
-                leaf.shape[:ax] + (n_pages, page_size) \
-                + leaf.shape[ax + 2:]
-            return jax.ShapeDtypeStruct(shape, leaf.dtype)
-
-        return _alloc_sharded(
-            jax.tree_util.tree_map_with_path(remap_struct, base), mesh)
-
-    return jax.tree_util.tree_map_with_path(
-        remap, init_cache(model, params, 1))
+        return _alloc_sharded(structs, mesh)
+    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), structs)
 
 
 def default_page_size(cfg) -> int:
@@ -170,12 +161,13 @@ def default_page_size(cfg) -> int:
 
 def kv_page_nbytes(cfg, page_size: int) -> int:
     """Analytic bytes of ONE KV page for a model config (agrees with
-    ``page_nbytes`` of the built pool): n_layers x (K + V) x page_size
-    x kv_heads x head_dim at the cache dtype, plus the int8 mode's
-    fp32 scales. Lets the CLIs size ``--kv-pages`` from HBM before any
-    device allocation exists."""
+    ``page_nbytes`` of the built pool): n_layers x page_size x what the
+    config says a layer caches a token (``cache_values_per_token``: K +
+    V of every kv head, or a latent layer's one vector) at the cache
+    dtype, plus the int8 mode's fp32 scales. Lets the CLIs size
+    ``--kv-pages`` from HBM before any device allocation exists."""
     item = 1 if cfg.kv_cache_quant else jnp.dtype(cfg.dtype).itemsize
-    per = 2 * page_size * cfg.kv_heads * cfg.head_dim * item
+    per = page_size * cfg.cache_values_per_token * item
     if cfg.kv_cache_quant:
         per += 2 * page_size * cfg.kv_heads * 4
     return cfg.n_layers * per
