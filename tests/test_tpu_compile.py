@@ -220,7 +220,11 @@ def test_serving_step_writes_the_page_pool_in_place(topo, tpu_compile,
     NO op that copies a whole pool leaf — before the donation every
     step carried one ``copy(%cache__block_i__cached_key|value)`` per
     layer and K/V, half its device time (PERF.md, PR 28) — and every
-    byte it returns aliases an argument."""
+    byte it returns aliases an argument. Nor does any op SELECT over
+    the gathered page view: the gather clamps by its own mode
+    (``take_pages``), where ``jnp.take``'s fill mode ran a second pass
+    over every gathered byte (``broadcast_select_fusion``, the largest
+    device op of the serving cells until PR 36)."""
     import re
 
     from tony_tpu.models import Transformer, TransformerConfig
@@ -258,7 +262,8 @@ def test_serving_step_writes_the_page_pool_in_place(topo, tpu_compile,
             A((1, cols)), A((), F32), A(()), A((2,), jnp.uint32))
     compiled = lowered.compile()
     whole_leaf = re.compile(r"= bf16\[1024,64,8,128\]\S* copy\(")
+    over_view = re.compile(r"= bf16\[\d+,%d,64,8,128\]\S* select\(" % cols)
     assert not [ln for ln in compiled.as_text().splitlines()
-                if whole_leaf.search(ln)]
+                if whole_leaf.search(ln) or over_view.search(ln)]
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * 1024 * 64 * 8 * 128 * 2

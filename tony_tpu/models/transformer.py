@@ -484,6 +484,19 @@ def rotary_embedding(x, positions, theta: float = 10_000.0,
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
+def take_pages(leaf, table, axis: int = 0):
+    """Gather pool pages through a page table: ``leaf`` ``[.., n_pages,
+    ps, ..]`` (pages on ``axis``) becomes ``[.., *table.shape, ps, ..]``.
+    THE page gather: the in-model paged branches, ``serve/slots``'
+    ``paged_view`` and ``gather_pages`` all read the pool through here.
+    The clamp is the gather's own mode: XLA's gather clamps an
+    out-of-range start index natively, so a sentinel entry (``>=
+    n_pages``) reads page ``n_pages - 1`` with no mask built and no
+    select run over the gathered values (``jnp.take``'s default
+    ``fill`` mode pays that second pass over every gathered byte)."""
+    return jnp.take(leaf, table, axis=axis, mode="clip")
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
 
@@ -567,9 +580,11 @@ class Attention(nn.Module):
         so the attention reduction (and greedy outputs) are identical
         to the unpaged path. Table entries >= n_pages are UNALLOCATED
         sentinels: writes through them drop (scatter mode="drop"),
-        gathers clamp to an arbitrary page whose junk the per-row
-        position-visibility mask hides — exactly the bucket-padding
-        argument. Positions at or past ``max_pages * page_size`` also
+        gathers clamp to the last page (the gather's own mode="clip",
+        ``take_pages``: no in-bounds mask, no select over the gathered
+        view), whose junk the per-row position-visibility mask hides —
+        exactly the bucket-padding argument. Positions at or past
+        ``max_pages * page_size`` also
         drop (a chunk overshooting a finished slot's budget must not
         wrap into the slot's own live pages). The host allocator
         guarantees every position that must LAND maps to an allocated,
@@ -698,17 +713,17 @@ class Attention(nn.Module):
             # gather each row's pages back into the position-ordered
             # [span] view the unpaged buffer would hold (position p =
             # gather index p — identical values, identical reduction).
-            # Sentinel entries clamp to page n_pages-1: junk the
-            # visibility mask hides, same as bucket padding.
-            tab = jnp.clip(page_table, 0, n_pages - 1)
-            keys = jnp.take(pool_k, tab, axis=0).reshape(
+            # Sentinel entries clamp to page n_pages-1 (the gather's
+            # own mode, take_pages): junk the visibility mask hides,
+            # same as bucket padding.
+            keys = take_pages(pool_k, page_table).reshape(
                 b, span, kvh, dh)
-            values = jnp.take(pool_v, tab, axis=0).reshape(
+            values = take_pages(pool_v, page_table).reshape(
                 b, span, kvh, dh)
             if quant:
-                ksc = jnp.take(k_scales.value, tab, axis=0).reshape(
+                ksc = take_pages(k_scales.value, page_table).reshape(
                     b, span, kvh)
-                vsc = jnp.take(v_scales.value, tab, axis=0).reshape(
+                vsc = take_pages(v_scales.value, page_table).reshape(
                     b, span, kvh)
         elif per_slot:
             # scatter each row's tokens at that row's own cache
@@ -980,18 +995,18 @@ class LatentAttention(nn.Module):
         if page_table is not None:
             # the paged scatter and the position-ordered gather of
             # Attention._decode_attention, over these two leaves
+            # (sentinel entries clamp by the gather's own mode)
             n_pages, ps = leaves[0].value.shape[-3:-1]
             span = page_table.shape[1] * ps
             valid = (pos2d >= 0) & (pos2d < span)
             safe = jnp.where(valid, pos2d, 0)
             page = jnp.take_along_axis(page_table, safe // ps, axis=1)
             page = jnp.where(valid, page, n_pages)  # drop via OOB
-            tab = jnp.clip(page_table, 0, n_pages - 1)
             seen = []
             for leaf, val in zip(leaves, new):
                 leaf.value = leaf.value.at[page, safe % ps].set(
                     val, mode="drop")
-                seen.append(jnp.take(leaf.value, tab, axis=0).reshape(
+                seen.append(take_pages(leaf.value, page_table).reshape(
                     b, span, -1))
         elif per_slot:
             rows = jnp.arange(b)[:, None]

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tony_tpu.models.generate import init_cache
+from tony_tpu.models.transformer import take_pages
 
 
 def cache_batch_axis(path, leaf) -> int | None:
@@ -225,15 +226,13 @@ def gather_pages(cache: Any, idx) -> Any:
     becomes ``[.., n, ps, ..]`` — the portable form of a page list,
     shared by the role-split handoff (device->device between two
     replicas' pools, or over the agent wire) and the host-RAM tier
-    (device->host spill). Out-of-range entries clamp (padding rows
-    carry junk the consumer drops); non-paged leaves (the shared
-    counters) pass through so the tree STRUCTURE round-trips."""
+    (device->host spill). Out-of-range entries clamp, by the gather's
+    own mode (``take_pages``: padding rows carry junk the consumer
+    drops); non-paged leaves (the shared counters) pass through so the
+    tree STRUCTURE round-trips."""
     def g(path, leaf):
         ax = cache_batch_axis(path, leaf)
-        if ax is None:
-            return leaf
-        safe = jnp.clip(idx, 0, leaf.shape[ax] - 1)
-        return jnp.take(leaf, safe, axis=ax)
+        return leaf if ax is None else take_pages(leaf, idx, ax)
 
     return jax.tree_util.tree_map_with_path(g, cache)
 
@@ -279,7 +278,9 @@ def paged_view(cache: Any, table, max_len: int) -> Any:
     """Gather each slot's pages into an UNPAGED-looking cache: every
     pool leaf ``[.., n_pages, ps, ..]`` becomes ``[.., b, span, ..]``
     via one gather through ``table`` [b, cols] (sentinel entries clamp
-    to junk pages the visibility mask hides). The decode chunk runs
+    to a junk page the visibility mask hides; the clamp is the gather's
+    own mode, ``take_pages``, so the view is written in ONE pass: no
+    in-bounds mask, no select over it). The decode chunk runs
     its whole lax.scan against this view — the per-micro-step compute
     is then literally the unpaged program (bitwise parity for free: a
     masked column contributes softmax weight exactly 0.0, so a view
@@ -298,8 +299,7 @@ def paged_view(cache: Any, table, max_len: int) -> Any:
         ax = cache_batch_axis(path, leaf)
         if ax is None:
             return leaf
-        safe = jnp.clip(table, 0, leaf.shape[ax] - 1)
-        v = jnp.take(leaf, safe, axis=ax)  # [.., b, cols, ps, ..]
+        v = take_pages(leaf, table, ax)  # [.., b, cols, ps, ..]
         shape = v.shape[:ax] + (v.shape[ax],
                                 v.shape[ax + 1] * v.shape[ax + 2]) \
             + v.shape[ax + 3:]
