@@ -17,6 +17,7 @@ entry written without a chip cannot be read back and would warn).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -267,3 +268,69 @@ def test_serving_step_writes_the_page_pool_in_place(topo, tpu_compile,
                 if whole_leaf.search(ln) or over_view.search(ln)]
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * 1024 * 64 * 8 * 128 * 2
+
+
+@pytest.mark.parametrize("pack", [True, False], ids=["packed", "heads"])
+def test_heads_of_64_packed_into_lanes_keep_the_pool_row_major(
+        topo, tpu_compile, pack):
+    """A page pool of 8 kv heads of 64 (the mixed conv/attention
+    configuration's widths; one attention and one conv layer, few
+    experts): stored as heads, its minor dimension is under the 128
+    lanes, the compiler lays the pool out pages-minor and every decode
+    step copies each whole leaf to row-major and back; stored as rows of
+    128 (``kv_pack_lanes``, derived from the widths: the control that
+    stores heads is a subclass) it stays row-major and no whole leaf is
+    copied. The conv layer's state, a row a slot, is returned in
+    place too."""
+    import re
+
+    from tony_tpu.models import (Transformer, TransformerConfig,
+                                 lfm2_moe_config)
+    from tony_tpu.serve import engine
+    from tony_tpu.serve.slots import STATE_COLS, paged_cache
+
+    @dataclasses.dataclass(frozen=True)
+    class StoredAsHeads(TransformerConfig):
+        kv_pack_lanes = property(lambda self: False)
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    def A(shape, dtype=I32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cfg = lfm2_moe_config(dict(
+        vocab_size=4096, hidden_size=2048, num_attention_heads=32,
+        num_key_value_heads=8, num_hidden_layers=2, intermediate_size=1024,
+        max_position_embeddings=2048, norm_eps=1e-5, rope_theta=1e6,
+        layer_types=["full_attention", "conv"], conv_L_cache=3,
+        num_experts=4, num_experts_per_tok=2, moe_intermediate_size=256,
+        num_dense_layers=1, use_expert_bias=True,
+        tie_word_embeddings=False), dtype=BF16)
+    assert cfg.kv_pack_lanes
+    if not pack:
+        cfg = StoredAsHeads(**{f.name: getattr(cfg, f.name)
+                               for f in dataclasses.fields(cfg)})
+    model = Transformer(cfg)
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), I32))["params"]))
+    b, cols = 8, 8
+    pool = on_chip(jax.eval_shape(
+        lambda p: paged_cache(model, p, 1024, 64, slots=b), params))
+    leaf = pool["block_0"]["attn"]["cached_key"].shape
+    assert leaf == ((1024, 64, 4, 128) if pack else (1024, 64, 8, 64))
+    assert pool["block_1"]["conv"]["conv_state"].shape == (b, 2, 2048)
+    compiled = engine._decode_chunk.lower(
+        model, params, pool, A((b, STATE_COLS)), A((b, 1 + STATE_COLS)),
+        A((b, cols)), n_steps=1, eos_ids=(2,)).compile()
+    copies = [ln for ln in compiled.as_text().splitlines() if re.search(
+        r"= bf16\[1024,64,\d+,\d+\]\S* copy\(", ln)]
+    assert bool(copies) != pack, copies[:2]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * 1024 * 64 * 512 * 2 \
+        + b * 2 * 2048 * 2

@@ -173,7 +173,9 @@ def build_parser() -> argparse.ArgumentParser:
                    help="compile the decode program for every page-view "
                         "bucket at start-up instead of when a live row "
                         "first grows into one (no compile stall under "
-                        "traffic, at the cost of a longer boot)")
+                        "traffic, at the cost of a longer boot); with "
+                        "--prefill-chunk-tokens also every program of a "
+                        "chunked prefill")
     p.add_argument("--no-shared-pool", action="store_true",
                    help="give each in-process replica its own private "
                         "KV page pool instead of one gateway-owned "

@@ -3940,4 +3940,14 @@ class Gateway:
         if any("latent_bytes_per_token" in c for c in counts):
             out["latent_bytes_per_token"] = max(
                 c.get("latent_bytes_per_token", 0) for c in counts)
+        if any("state_bytes_per_slot" in c for c in counts):
+            # a model with conv layers (serve/engine.Server): what a
+            # position caches in the attention layers' pages and what a
+            # SLOT carries beside them; admissions that started a
+            # sequence's state and prefill chunks that continued one
+            for key in ("conv_layers", "attn_layers", "state_bytes_per_slot",
+                        "kv_bytes_per_token"):
+                out[key] = max(c.get(key, 0) for c in counts)
+            for key in ("state_resets", "state_carried_chunks"):
+                out[key] = total(key)
         return out

@@ -209,6 +209,60 @@ def llama_config(hf_config, **overrides) -> TransformerConfig:
     return TransformerConfig(**kw)
 
 
+def lfm2_moe_config(hf_config, **overrides) -> TransformerConfig:
+    """TransformerConfig for a ``lfm2_moe`` ``config.json`` (LiquidAI
+    LFM2-8B-A1B; ``hf_config`` an object or a mapping of its keys): a
+    gated short convolution (``conv_L_cache`` taps, no bias) or GQA
+    attention with per-head q/k RMSNorm by ``layer_types``; the first
+    ``num_dense_layers`` feed-forwards a SwiGLU of ``intermediate_size``,
+    the others ``num_experts`` SwiGLU experts of
+    ``moe_intermediate_size`` routed by sigmoid scores, the choice moved
+    by ``use_expert_bias``'s leaf, ``num_experts_per_tok`` a token, no
+    shared expert; all the experts are held here. The config only: no
+    checkpoint importer reads these weights yet."""
+    from tony_tpu.parallel.moe import RoutedConfig
+
+    get = hf_config.get if isinstance(hf_config, dict) \
+        else lambda k, d=None: getattr(hf_config, k, d)
+    if get("conv_bias", False) or not get("norm_topk_prob", True):
+        raise ValueError("lfm2_moe with conv_bias, or without "
+                         "norm_topk_prob, is not supported")
+    n_experts = get("num_experts")
+    kw = dict(
+        vocab_size=get("vocab_size"),
+        d_model=get("hidden_size"),
+        n_heads=get("num_attention_heads"),
+        n_kv_heads=get("num_key_value_heads"),
+        explicit_head_dim=get("head_dim") or 0,
+        n_layers=get("num_hidden_layers"),
+        d_ff=get("intermediate_size"),
+        max_seq_len=get("max_position_embeddings"),
+        dtype=jnp.float32,
+        attention_backend="reference",
+        norm="rms",
+        positional="rope",
+        use_bias=False,
+        activation="silu",
+        norm_eps=get("norm_eps"),
+        rope_theta=float(get("rope_theta", 1_000_000.0)),
+        gated_mlp=True,
+        tied_embeddings=bool(get("tie_word_embeddings",
+                                 get("tie_embedding", True))),
+        layer_types=tuple(get("layer_types")),
+        conv_kernel=get("conv_L_cache"),
+        qk_norm=True,
+        routed=RoutedConfig(
+            n_routed=n_experts, top_k=get("num_experts_per_tok"),
+            d_ff=get("moe_intermediate_size"), held=(0, n_experts),
+            scaling=float(get("routed_scaling_factor", 1.0)),
+            first_dense=get("num_dense_layers"),
+            selection_bias=bool(get("use_expert_bias", False)),
+            renorm_eps=1e-6),
+    )
+    kw.update(overrides)
+    return TransformerConfig(**kw)
+
+
 def _convert_rms_decoder(state_dict: dict, cfg: TransformerConfig, *,
                          family: str, ffn_consumed, ffn_build) -> Any:
     """Shared RMSNorm+RoPE+GQA decoder conversion (Llama-layout state

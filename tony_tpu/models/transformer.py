@@ -194,9 +194,36 @@ class TransformerConfig:
     # parallel/moe.py ``RoutedConfig``): the MLP of every layer from
     # ``routed.first_dense`` on. None = dense everywhere (or moe_every).
     routed: Any = None
-
+    # the MIXER of each layer, by index: "full_attention" (``Attention``)
+    # or "conv" (``ShortConv``, a gated causal convolution of
+    # ``conv_kernel`` taps whose state is ``conv_kernel - 1`` positions
+    # a sequence, not a cached position). () = every layer attends.
+    layer_types: tuple = ()
+    conv_kernel: int = 0
+    # RMSNorm over each query and key head's dims (one learned scale of
+    # head_dim each) before the rotary embedding
+    qk_norm: bool = False
     def __post_init__(self):
         # invalid knob combinations fail at construction, not first apply
+        if self.layer_types:
+            kinds = set(self.layer_types) - {"conv", "full_attention"}
+            if kinds or len(self.layer_types) != self.n_layers:
+                raise ValueError(
+                    f"layer_types names one of conv | full_attention for "
+                    f"each of the {self.n_layers} layers; got "
+                    f"{len(self.layer_types)} entries, unknown {kinds}")
+            if "conv" in self.layer_types and self.conv_kernel < 2:
+                raise ValueError("a conv layer needs conv_kernel >= 2")
+            for knob in ("scan_layers", "latent", "sliding_window",
+                         "kv_cache_quant", "quantized"):
+                if getattr(self, knob):
+                    raise ValueError(
+                        f"{knob} is not implemented for a model of mixed "
+                        "layer kinds (layer_types)")
+            if self.decode_attention != "einsum":
+                raise ValueError(
+                    "decode_attention='flash' is not implemented for a "
+                    "model of mixed layer kinds (layer_types)")
         if self.latent is not None:
             for knob in ("kv_cache_quant", "quantized", "sliding_window",
                          "scan_layers"):
@@ -235,12 +262,47 @@ class TransformerConfig:
 
     @property
     def cache_values_per_token(self) -> int:
-        """What ONE layer caches for one position, in values of the cache
+        """What ONE attention layer caches for one position (a conv
+        layer caches none: ``attn_layers``), in values of the cache
         dtype: the cache spec ``serve/slots.kv_page_nbytes`` sizes a page
         from (int8 K/V adds its float32 scales there)."""
         if self.latent is not None:
             return self.latent.cache_width
         return 2 * self.kv_heads * self.head_dim
+
+    @property
+    def kv_pack_lanes(self) -> bool:
+        """Whether the K/V cache stores a position's heads as rows of 128
+        values, ``[b, max_len, kv_heads * head_dim / 128, 128]``: the same
+        bytes in the same order. Derived, never set: heads narrower than
+        the TPU's 128 lanes that fill whole rows, in a plain K/V cache
+        read by the einsum path (the int8 cache keeps a scale a head and
+        the flash-decode kernel reads heads, so both keep heads). The
+        v5e's compiler lays a pool whose minor dimension is under 128 out
+        PAGES-minor and then copies the whole pool to row-major and back
+        in every decode step (compiled for a described v5e at 8 heads of
+        64: two copies of each 134 MB leaf a step, a byte count, not a
+        measured time: ``tests/test_tpu_compile.py``)."""
+        return (self.latent is None and self.head_dim < 128
+                and (self.kv_heads * self.head_dim) % 128 == 0
+                and not self.kv_cache_quant
+                and self.decode_attention == "einsum")
+
+    @property
+    def attn_layers(self) -> int:
+        """Layers that keep keys and values (or a latent) a position."""
+        return self.n_layers - self.conv_layers
+
+    @property
+    def conv_layers(self) -> int:
+        """Layers whose mixer is a ``ShortConv``: state a SEQUENCE."""
+        return sum(t == "conv" for t in self.layer_types)
+
+    @property
+    def state_values_per_slot(self) -> int:
+        """What the conv layers keep for one sequence, in values of the
+        model's dtype: ``conv_kernel - 1`` positions of ``d_model``."""
+        return self.conv_layers * (self.conv_kernel - 1) * self.d_model
 
     @property
     def kv_heads(self) -> int:
@@ -520,6 +582,10 @@ class Attention(nn.Module):
         q = dense("q", (cfg.n_heads, cfg.head_dim), qkv_bias)(x)
         k = dense("k", (cfg.kv_heads, cfg.head_dim), qkv_bias)(x)
         v = dense("v", (cfg.kv_heads, cfg.head_dim), qkv_bias)(x)
+        if cfg.qk_norm:
+            with jax.named_scope("attn.qk_norm"):
+                q = RMSNorm(cfg.dtype, cfg.norm_eps, name="q_norm")(q)
+                k = RMSNorm(cfg.dtype, cfg.norm_eps, name="k_norm")(k)
         if decode:
             out = self._decode_attention(q, k, v, positions, page_table)
             # serve-shard pin: attn out is kv-head-sharded (it read the
@@ -635,10 +701,14 @@ class Attention(nn.Module):
         # kv_cache_quant stores int8 + per-(pos, head) scales: half the
         # bytes again (docs/PERF.md decode roofline next lever).
         cache_dtype = jnp.int8 if quant else k.dtype
+        # what one position stores: its heads, or (kv_pack_lanes) the
+        # same values as rows of 128 lanes; ``unpack`` is the view back
+        stored = (kvh * dh // 128, 128) if cfg.kv_pack_lanes else (kvh, dh)
+        unpack = lambda t: t.reshape(t.shape[:2] + (kvh, dh))  # noqa: E731
         cached_k = self.variable("cache", "cached_key", jnp.zeros,
-                                 (b, max_len, kvh, dh), cache_dtype)
+                                 (b, max_len) + stored, cache_dtype)
         cached_v = self.variable("cache", "cached_value", jnp.zeros,
-                                 (b, max_len, kvh, dh), cache_dtype)
+                                 (b, max_len) + stored, cache_dtype)
         if quant:
             k_scales = self.variable("cache", "cached_key_scale", jnp.zeros,
                                      (b, max_len, kvh), jnp.float32)
@@ -684,6 +754,7 @@ class Attention(nn.Module):
 
             k, k_sc = quantize_kv(k)  # quantize-on-write, after RoPE
             v, v_sc = quantize_kv(v)
+        k, v = (t.reshape(t.shape[:2] + stored) for t in (k, v))
         if paged:
             # paged scatter: token (i, j) lands in pool page
             # page_table[i, pos // page_size] at offset pos % page_size.
@@ -744,6 +815,7 @@ class Attention(nn.Module):
             values = cached_v.value.at[rows, write].set(v, mode="drop")
             cached_k.value = keys
             cached_v.value = values
+            keys, values = unpack(keys), unpack(values)
             # cache_index stays untouched: per-slot lengths live with the
             # caller (serve.SlotCache), not in the shared scalar
         else:
@@ -758,6 +830,7 @@ class Attention(nn.Module):
                 cached_v.value, v, (0, cur, 0, 0))
             cached_k.value = keys
             cached_v.value = values
+            keys, values = unpack(keys), unpack(values)
             cache_index.value = cur + l
         # query positions, [rows, l]: one broadcast row in scalar mode,
         # one row per slot in per-slot mode — the visibility mask below
@@ -1032,6 +1105,117 @@ class LatentAttention(nn.Module):
             return latent_attend(q_n, q_r, *seen, w_kvb, visible, scale)
 
 
+class ShortConv(nn.Module):
+    """A gated short causal convolution in the place of attention
+    (``cfg.layer_types[i] == "conv"``). With x the normed input:
+    ``[B, C, u] = split3(x W_in)``, ``z = B * u``, ``y_t = sum_j w[:, j]
+    * z_{t-(K-1)+j}`` a channel (``K = cfg.conv_kernel`` taps, zeros
+    before the sequence's start), ``out = (C * y) W_out``. No bias.
+
+    What a sequence carries from one call to the next is ``z`` at its
+    last ``K - 1`` positions and nothing that grows with its length:
+    the cache variable ``conv_state`` [b, K-1, d] in the model's dtype,
+    a row a SLOT (``serve/slots.slot_resident``), never pages. One
+    arithmetic, three ways in:
+
+    - ``decode=False``: a whole row, shifted adds; rows packed with
+      ``segment_ids`` take zeros across a boundary.
+    - ``decode=True``, ``positions`` None (``generate``): the window
+      continues the state and leaves its own last ``K - 1`` positions.
+    - ``decode=True`` with per-slot ``positions`` [b] or [b, l] (the
+      serving engine; a window's padding sits at its END with position
+      -1): a predecessor is taken by POSITION, so a token at position
+      ``p < k`` reads zero for ``z_{p-k}`` whatever the slot's last
+      tenant left, and the state handed on is ``z`` at the row's last
+      ``K - 1`` REAL positions, never the window's end. A row with no
+      real position (an empty or frozen slot) keeps its state.
+
+    ``page_table`` is accepted and unused: the engine hands a one-row
+    window the row of ITS slot (``slots.slot_rows``)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, decode: bool = False, segment_ids=None,
+                 positions=None, page_table=None):
+        cfg = self.cfg
+        b, l, d = x.shape
+        taps = cfg.conv_kernel
+        dense = lambda name, feats: nn.Dense(  # noqa: E731
+            feats, use_bias=False, dtype=cfg.dtype,
+            param_dtype=jnp.float32, name=name,
+            kernel_init=nn.initializers.normal(0.02))
+        with jax.named_scope("conv.in"):
+            gate_b, gate_c, u = jnp.split(dense("in_proj", 3 * d)(x), 3,
+                                          axis=-1)
+            z = gate_b * u
+        w = self.param("kernel", nn.initializers.normal(0.02), (d, taps),
+                       jnp.float32)
+        with jax.named_scope("conv.mix"):
+            if decode:
+                y = self._continued(z, w, positions)
+            else:
+                y = _causal_taps(z, w, segment_ids=segment_ids)
+        with jax.named_scope("conv.out"):
+            return dense("out_proj", d)(gate_c * y.astype(cfg.dtype))
+
+    def _continued(self, z, w, positions):
+        b, l, d = z.shape
+        keep = self.cfg.conv_kernel - 1
+        is_init = self.has_variable("cache", "conv_state")
+        state = self.variable("cache", "conv_state", jnp.zeros,
+                              (b, keep, d), z.dtype)
+        if not is_init:  # shape-only init pass
+            return jnp.zeros((b, l, d), jnp.float32)
+        if state.value.shape[0] != b:
+            raise ValueError(
+                f"conv_state holds {state.value.shape[0]} rows, the window "
+                f"{b}: a slot-resident leaf goes to a one-row window as its "
+                "slot's row (serve/slots.slot_rows)")
+        ext = jnp.concatenate([state.value, z], axis=1)  # [b, keep + l, d]
+        if positions is None:
+            state.value = ext[:, l:]
+            return _causal_taps(ext, w)[:, keep:]
+        pos2d = positions[:, None] if positions.ndim == 1 else positions
+        if pos2d.shape != (b, l):
+            raise ValueError(
+                f"positions shape {positions.shape} does not match "
+                f"the token window ({b}, {l})")
+        y = _causal_taps(ext, w, positions=pos2d)[:, keep:]
+        n_real = jnp.sum(pos2d >= 0, axis=1)
+        if l == 1:  # the decode step: shift one in, or stand still
+            state.value = jnp.where((n_real > 0)[:, None, None],
+                                    ext[:, 1:], state.value)
+        else:
+            at = n_real[:, None] + jnp.arange(keep)[None, :]
+            state.value = jnp.take_along_axis(ext, at[:, :, None], axis=1)
+        return y
+
+
+def _causal_taps(z, w, *, segment_ids=None, positions=None):
+    """``y_t = sum_j w[:, j] * z_{t-(K-1)+j}`` along axis 1 of ``z`` [b,
+    n, d] (float32 sums), ``w`` [d, K]: K - 1 shifted adds, zeros before
+    index 0. ``segment_ids`` [b, n] zeroes a predecessor of another
+    segment. ``positions`` [b, l] are those of the LAST ``l`` rows of
+    ``z`` (the rows before them are carried state): a predecessor ``k``
+    back counts only where ``positions - k >= 0``."""
+    n, taps = z.shape[1], w.shape[1]
+    z32 = z.astype(jnp.float32)
+    y = z32 * w[:, taps - 1]
+    for k in range(1, taps):
+        back = jnp.pad(z32, ((0, 0), (k, 0), (0, 0)))[:, :n]
+        if segment_ids is not None:
+            same = jnp.pad(segment_ids, ((0, 0), (k, 0)),
+                           constant_values=-1)[:, :n] == segment_ids
+            back = jnp.where(same[..., None], back, 0.0)
+        if positions is not None:
+            real = jnp.pad(positions >= k,
+                           ((0, 0), (n - positions.shape[1], 0)))
+            back = jnp.where(real[..., None], back, 0.0)
+        y = y + back * w[:, taps - 1 - k]
+    return y
+
+
 def _q8_shard_axes(cfg: TransformerConfig, name: str) -> tuple:
     """(in_axis, out_axis) mesh axes for a QuantDense, mirroring the
     'tp' preset's logical rules in logical_axis_rules_tree: q/wi/wg
@@ -1252,7 +1436,8 @@ class RoutedMLP(nn.Module):
     parallel/moe.py ``routed_share``) plus the shared expert: the
     router keeps its published width, the expert leaves ``wg``/``wi``
     [held, d, f] and ``wo`` [held, f, d] hold only the experts that live
-    here. ``live`` [b, l] keeps padding and empty slots out of the
+    here; ``expert_bias`` [n_routed] float32 where the routing has a
+    selection bias. ``live`` [b, l] keeps padding and empty slots out of the
     routing (they neither count nor touch an expert). Outside ``init``
     the layer sows its counts (``routed_share``) into the
     ``moe_stats`` collection, summed over the routed layers of the call;
@@ -1271,9 +1456,12 @@ class RoutedMLP(nn.Module):
         router = leaf("router", (d, rc.n_routed))
         wg, wi = (leaf(n, (n_held, d, rc.d_ff)) for n in ("wg", "wi"))
         wo = leaf("wo", (n_held, rc.d_ff, d))
+        bias = self.param("expert_bias", nn.initializers.zeros,
+                          (rc.n_routed,), jnp.float32) \
+            if rc.selection_bias else None
         y, counts = routed_share(
             x.reshape(b * l, d), router, wg, wi, wo, rc,
-            None if live is None else live.reshape(b * l))
+            None if live is None else live.reshape(b * l), bias=bias)
         if not self.is_initializing():
             self.sow("moe_stats", "counts", counts,
                      reduce_fn=lambda acc, c: acc + c,
@@ -1290,12 +1478,17 @@ class Block(nn.Module):
     cfg: TransformerConfig
     use_moe: bool = False
     use_routed: bool = False
+    layer: int = 0  # picks the mixer where ``cfg.layer_types`` names them
 
     @nn.compact
     def __call__(self, x, decode: bool = False, segment_ids=None,
                  positions=None, page_table=None):
         attn_cls = Attention if self.cfg.latent is None else LatentAttention
-        attn_out = attn_cls(self.cfg, name="attn")(
+        conv = bool(self.cfg.layer_types) \
+            and self.cfg.layer_types[self.layer] == "conv"
+        mixer = ShortConv(self.cfg, name="conv") if conv \
+            else attn_cls(self.cfg, name="attn")
+        attn_out = mixer(
             make_norm(self.cfg, "ln1")(x), decode=decode,
             segment_ids=segment_ids, positions=positions,
             page_table=page_table)
@@ -1471,7 +1664,7 @@ class Transformer(nn.Module):
                 use_routed = cfg.routed is not None \
                     and i >= cfg.routed.first_dense
                 x = block(cfg, use_moe=use_moe, use_routed=use_routed,
-                          name=f"block_{i}")(
+                          layer=i, name=f"block_{i}")(
                     x, decode, segment_ids=segment_ids, positions=positions,
                     page_table=page_table)
         x = make_norm(cfg, "ln_f")(x)

@@ -34,7 +34,10 @@ class RoutedConfig:
     from index ``held[0]`` on and adds what they give, times
     ``scaling``. ``shared_d_ff`` > 0 adds a shared SwiGLU expert of that
     width, computed whole on every chip; the first ``first_dense``
-    layers of the model keep a dense MLP. Hashable: it rides
+    layers of the model keep a dense MLP. ``selection_bias`` adds a
+    float32 leaf ``expert_bias`` [n_routed] that only the CHOICE of
+    experts sees (``sigmoid_top_k``); ``renorm_eps`` is what the chosen
+    weights' sum is guarded with. Hashable: it rides
     ``TransformerConfig``, a static argument of jitted code."""
 
     n_routed: int
@@ -44,6 +47,8 @@ class RoutedConfig:
     scaling: float = 1.0
     shared_d_ff: int = 0
     first_dense: int = 0
+    selection_bias: bool = False
+    renorm_eps: float = 1e-20
 
     def __post_init__(self):
         first, count = self.held
@@ -305,14 +310,21 @@ def moe_layer(params: dict, x: jnp.ndarray, cfg: MoEConfig):
 
 # ------------------------------------------------ one chip's routed share
 
-def sigmoid_top_k(logits, k: int, scaling: float = 1.0):
-    """DeepSeek-V3-style routing without groups or a selection bias:
-    ``p = sigmoid(logits)`` (float32), the ``k`` largest, their weights
-    renormalised to sum to 1 and multiplied by ``scaling``. Returns
-    (weights [T, k] float32, expert indices [T, k])."""
+def sigmoid_top_k(logits, k: int, scaling: float = 1.0, bias=None,
+                  eps: float = 1e-20):
+    """DeepSeek-V3-style routing without groups: ``p =
+    sigmoid(logits)`` (float32), the ``k`` experts with the largest ``p
+    + bias`` (``bias`` [E] float32 or None: it moves the CHOICE only),
+    their weights ``p`` renormalised to sum to 1 (the sum guarded by
+    ``eps``) and multiplied by ``scaling``. Returns (weights [T, k]
+    float32, expert indices [T, k])."""
     p = jax.nn.sigmoid(logits.astype(jnp.float32))
-    top, idx = jax.lax.top_k(p, k)
-    return top / (jnp.sum(top, -1, keepdims=True) + 1e-20) * scaling, idx
+    if bias is None:
+        top, idx = jax.lax.top_k(p, k)
+    else:
+        _, idx = jax.lax.top_k(p + bias.astype(jnp.float32), k)
+        top = jnp.take_along_axis(p, idx, axis=-1)
+    return top / (jnp.sum(top, -1, keepdims=True) + eps) * scaling, idx
 
 
 N_COUNTS = 4  # what ``routed_share`` counts beside its result
@@ -379,20 +391,23 @@ def dense_experts(x, idx, w, wg, wi, wo, first: int, live=None):
 
 
 def routed_share(x, router, wg, wi, wo, cfg: RoutedConfig, live=None,
-                 experts=grouped_experts):
+                 experts=grouped_experts, bias=None):
     """One chip's share of a routed expert layer on tokens ``x`` [T, d]:
     route over ALL ``cfg.n_routed`` experts (``router`` [d, n_routed],
     scores in float32), add what the experts held here give, leave out
     what the absent ones would (no code stands in for them or for the
     exchange). ``live`` [T] masks padding and empty slots out of the
-    routing. Returns ``(y [T, d] float32, counts [N_COUNTS] int32)``: the
+    routing; ``bias`` [n_routed] is the selection bias
+    (``cfg.selection_bias``). Returns ``(y [T, d] float32, counts
+    [N_COUNTS] int32)``: the
     token-expert pairs chosen by live tokens, those of them whose
     expert is held here, the most pairs one held expert took, and how
     many held experts took at least one (whose weights were read)."""
     with jax.named_scope("moe.route"):
         logits = jnp.einsum("td,de->te", x, router,
                             preferred_element_type=jnp.float32)
-        w, idx = sigmoid_top_k(logits, cfg.top_k, cfg.scaling)
+        w, idx = sigmoid_top_k(logits, cfg.top_k, cfg.scaling, bias,
+                               cfg.renorm_eps)
     with jax.named_scope("moe.experts"):
         y, sizes = experts(x, idx, w, wg, wi, wo, cfg.held[0], live)
     n_live = x.shape[0] if live is None else jnp.sum(live)

@@ -71,8 +71,9 @@ from tony_tpu.serve.slots import (PagePool, SlotCache, _copy_page,
                                   _gather_pages, _read_slot,
                                   _scatter_pages, apply_patch,
                                   cache_batch_axis, default_page_size,
-                                  kv_page_nbytes, pack_state, paged_view,
-                                  paged_write_back, unpack_state)
+                                  kv_page_nbytes, pack_state, page_axis,
+                                  paged_view, paged_write_back, slot_rows,
+                                  store_slot_rows, unpack_state)
 from tony_tpu.serve.tier import (HostPageTier, decode_array,
                                  decode_payload, pad_host_pages,
                                  payload_pages)
@@ -211,7 +212,7 @@ def _sample_first(logits, temp, top_k, key):
 @functools.partial(jax.jit, static_argnames=("model",),
                    donate_argnames=("cache",))
 def _paged_prefill_admit(model, params, cache, window, positions, length,
-                         table, temp, top_k, key):
+                         table, temp, top_k, key, slot=None):
     """The paged fused admit: a prefill is ONE multi-token per-slot
     window over the resident page pool — ``window`` [1, Lb] holds the
     (suffix of the) prompt right-padded to its bucket, ``positions``
@@ -223,9 +224,11 @@ def _paged_prefill_admit(model, params, cache, window, positions, length,
     place), the last REAL position's logits feed the first-token
     sample. Returns
     ``(cache, token, rng, last_logits [1, V])`` — the logits go to the
-    prefix store so the next exact hit skips everything."""
-    cache, logits = multi_decode_step(model, params, cache, window,
-                                      positions, page_table=table)
+    prefix store so the next exact hit skips everything. ``slot`` (a
+    model with slot-resident state, ``slots.slot_resident``): the
+    window continues that slot's rows and leaves its own there."""
+    cache, logits = _paged_window(model, params, cache, window, positions,
+                                  table, slot)
     last = jax.lax.dynamic_slice_in_dim(logits, length - 1, 1,
                                         axis=1)[:, 0]
     tok, key = _sample_rows(last, key[None],
@@ -234,9 +237,22 @@ def _paged_prefill_admit(model, params, cache, window, positions, length,
     return cache, tok[0].astype(jnp.int32), key[0], last
 
 
+def _paged_window(model, params, cache, window, positions, table, slot):
+    """A one-row multi-token window into the page pool; with ``slot``
+    the slot-resident leaves go in as that slot's rows and come back
+    into it (``slots.slot_rows``)."""
+    if slot is None:
+        return multi_decode_step(model, params, cache, window, positions,
+                                 page_table=table)
+    out, logits = multi_decode_step(model, params, slot_rows(cache, slot),
+                                    window, positions, page_table=table)
+    return store_slot_rows(cache, out, slot), logits
+
+
 @functools.partial(jax.jit, static_argnames=("model",),
                    donate_argnames=("cache",))
-def _paged_prefill_chunk(model, params, cache, window, positions, table):
+def _paged_prefill_chunk(model, params, cache, window, positions, table,
+                         slot=None):
     """One INTERMEDIATE chunk of a chunked prefill: a multi-token
     window written straight into the slot's pages at absolute
     ``positions`` — ``_paged_prefill_admit`` minus the first-token
@@ -248,11 +264,11 @@ def _paged_prefill_chunk(model, params, cache, window, positions, table):
     off a written pool leaf, there for the host to sync on — the tree
     itself may be donated onward (by a co-located engine on a shared
     pool) before the host gets to wait on it."""
-    cache, _ = multi_decode_step(model, params, cache, window,
-                                 positions, page_table=table)
+    cache, _ = _paged_window(model, params, cache, window, positions,
+                             table, slot)
     leaf = next(x for path, x
                 in jax.tree_util.tree_flatten_with_path(cache)[0]
-                if cache_batch_axis(path, x) is not None)
+                if page_axis(path, x) is not None)
     return cache, leaf[(0,) * leaf.ndim]
 
 
@@ -839,6 +855,29 @@ class Server:
                         f"{option} is untested over a latent-attention "
                         "model (cfg.latent): serve it on one chip, "
                         "without speculation or the host page tier")
+        if model.cfg.conv_layers:
+            # a short convolution's state is a row a SLOT beside the
+            # page pool (``slots.slot_resident``): the paged prefill,
+            # its chunks, the decode round, the resident carry and two
+            # rounds in flight carry it. What would need the state AT A
+            # PAGE BOUNDARY (a prefix hit, a spilled or migrated page
+            # list, a verify window's rollback), or a pool several
+            # engines' slots share, was never run over it and is
+            # refused by name, not guessed.
+            refused = {"prefix_cache_mb": prefix_cache_mb > 0,
+                       "kv_host_mb": kv_host_mb > 0,
+                       "speculate_k": speculate_k > 0,
+                       "mesh": mesh is not None,
+                       "page_pool": page_pool is not None,
+                       "paged=False": paged is not None and not paged}
+            for option, asked in refused.items():
+                if asked:
+                    raise NotImplementedError(
+                        f"{option} is not implemented for a model with "
+                        "conv layers (cfg.layer_types): their state is "
+                        "slot-resident; serve it paged on one chip with "
+                        "prefix_cache_mb=0 and a pool of its own "
+                        "(--prefix-cache-mb 0 --no-shared-pool)")
         if paged and model.cfg.sliding_window:
             # same precedent: the paged gather itself is window-agnostic
             # but bitwise greedy parity against the unpaged windowed
@@ -942,7 +981,8 @@ class Server:
                 # reshard order would transiently hold the whole pool
                 # on one chip and OOM exactly the configurations the
                 # mesh unlocks
-                pool = PagePool(model, params, n_pages, ps, mesh=mesh)
+                pool = PagePool(model, params, n_pages, ps, mesh=mesh,
+                                slots=batch_size)
             self.slots = SlotCache(model, params, batch_size, pool=pool,
                                    mesh=mesh)
         else:
@@ -1012,6 +1052,13 @@ class Server:
         # mean load) and the held experts that took any
         self.moe_counts = np.zeros(N_COUNTS, np.int64) \
             if model.cfg.routed is not None else None
+        # slot-resident state (a model with conv layers): a paged
+        # window is told its slot; admissions whose window began at
+        # position 0 (the state's predecessors read as zero there,
+        # ``ShortConv``) and prefill chunks that began from a state
+        self._state_slots = model.cfg.conv_layers > 0
+        self.state_resets = 0
+        self.state_carried_chunks = 0
         self._decode_end = 0.0      # host clock at the last round's end
         self._ids = itertools.count()
         self.steps = 0       # decode dispatch DEPTH, summed (chunk k /
@@ -1189,7 +1236,7 @@ class Server:
             flat = jax.tree_util.tree_flatten_with_path(
                 self.slots.cache)[0]
             for i, (path, leaf) in enumerate(flat):
-                ax = cache_batch_axis(path, leaf)
+                ax = page_axis(path, leaf)
                 if ax is not None:
                     self._payload_leaf_spec = (i, ax)
                     break
@@ -1230,8 +1277,9 @@ class Server:
         same argument as the copy-on-write fork's above. What a
         deployment's warm-up traffic reaches needs none of this; the
         buckets past its longest PROMPT do (a row grows into them by
-        decoding). Returns the programs run. Call it on an idle
-        engine."""
+        decoding). With ``prefill_chunk_tokens`` also the chunked
+        prefill's programs (``_warm_chunked_prefill``). Returns the
+        programs run. Call it on an idle engine."""
         s = self.slots
         buckets, cols = [], 1
         while cols < s.max_pages:
@@ -1252,7 +1300,59 @@ class Server:
                         eos_ids=self.eos_ids)
                 jax.block_until_ready(toks)
                 self._compiled.add(("decode", k, cols * s.pool.page_size))
-        return len(buckets) * len(depths)
+        return len(buckets) * len(depths) + self._warm_chunked_prefill()
+
+    def _warm_chunked_prefill(self) -> int:
+        """With ``prefill_chunk_tokens``, the programs a chunked
+        prefill asks for: a chunk's is keyed by the VIEW SPAN its end
+        reaches, the final chunk's by its suffix bucket and the span
+        of the whole prompt, and a warm-up of one prompt a bucket
+        reaches a handful of that product (on the v5e the rest
+        compiled under traffic, 15 s each: PERF.md, Findings PR 37).
+        Each runs once over a window of padding (position -1 writes
+        nothing and leaves a slot's state as it was). Offsets a prefix
+        hit leaves are not walked. Returns the programs run."""
+        take = self.prefill_chunk
+        if not take:
+            return 0
+        s = self.slots
+        ps, max_len = s.pool.page_size, self.model.cfg.max_seq_len
+        slot = jnp.int32(0) if self._state_slots else None
+
+        def span(n):
+            return min(_bucket_pow2(-(-n // ps)), s.max_pages)
+
+        chunks, finals = set(), set()
+        for off in range(take, max_len, take):  # where a final may start
+            chunks.add(span(off))  # the chunk that ended there
+            lo, lb = 1, self.min_bucket
+            while lo <= min(take, max_len - off):
+                hi = min(lb, take, max_len - off)
+                finals |= {(lb, span(off + lo)), (lb, span(off + hi))}
+                lo, lb = lb + 1, lb * 2
+
+        def window(n, cols):
+            return (jnp.zeros((1, n), jnp.int32),
+                    jnp.full((1, n), -1, jnp.int32),
+                    jnp.asarray(s.page_table[:1, :cols]))
+
+        for cols in sorted(chunks):
+            with self._tree_lock:
+                s.cache, mark = _paged_prefill_chunk(
+                    self.model, self.params, s.cache, *window(take, cols),
+                    slot)
+            jax.block_until_ready(mark)
+            self._compiled.add(("prefill_chunk", take, cols * ps))
+        for lb, cols in sorted(finals):
+            toks, positions, table = window(lb, cols)
+            with self._tree_lock:
+                s.cache, tok, _, _ = _paged_prefill_admit(
+                    self.model, self.params, s.cache, toks, positions,
+                    jnp.int32(1), table, jnp.float32(0.0), jnp.int32(0),
+                    jax.random.PRNGKey(0), slot)
+            jax.block_until_ready(tok)
+            self._compiled.add(("prefill", lb, cols * ps))
+        return len(chunks) + len(finals)
 
     # ----------------------------------------------------- observability
 
@@ -1362,6 +1462,12 @@ class Server:
             raise NotImplementedError(
                 "prefill_only/handoff/migrate are not implemented for a "
                 "latent-attention model (cfg.latent)")
+        if (request.prefill_only or request.handoff is not None
+                or request.migrate is not None) and self._state_slots:
+            raise NotImplementedError(
+                "prefill_only/handoff/migrate are not implemented for a "
+                "model with conv layers (cfg.layer_types): a page list "
+                "does not carry the slot-resident state")
         if (request.prefill_only or request.handoff is not None
                 or request.migrate is not None) and not self.paged:
             raise ValueError(
@@ -1831,7 +1937,8 @@ class Server:
                         jnp.asarray(s.page_table[slot:slot + 1, :cols]),
                         jnp.float32(req.temperature),
                         jnp.int32(req.top_k),
-                        jax.random.PRNGKey(req.seed))
+                        jax.random.PRNGKey(req.seed),
+                        self._state_slot(slot, off))
                 self.prefills += 1
                 d_bucket = lb
                 if self.prefix is not None:
@@ -1905,6 +2012,19 @@ class Server:
                                  prefill_chunks=chunks)
         return True
 
+    def _state_slot(self, slot: int, first_pos: int):
+        """What a paged window is told of its slot: nothing for a model
+        whose cache is all pages, the slot for one with slot-resident
+        state (counted: a window from position 0 starts a sequence, a
+        later one continues the state its predecessor left)."""
+        if not self._state_slots:
+            return None
+        if first_pos:
+            self.state_carried_chunks += 1
+        else:
+            self.state_resets += 1
+        return jnp.int32(slot)
+
     # ------------------------------------------------- chunked prefill
 
     def _advance_prefills(self, finished: list) -> None:
@@ -1959,7 +2079,8 @@ class Server:
             s.cache, mark = _paged_prefill_chunk(
                 self.model, self.params, s.cache, jnp.asarray(window),
                 jnp.asarray(positions),
-                jnp.asarray(s.page_table[slot:slot + 1, :cols]))
+                jnp.asarray(s.page_table[slot:slot + 1, :cols]),
+                self._state_slot(slot, st.done))
         self.prefills += 1
         self.prefill_chunk_dispatches += 1
         st.done += take
@@ -2041,7 +2162,8 @@ class Server:
                 jnp.asarray(positions), jnp.int32(len(suffix)),
                 jnp.asarray(s.page_table[slot:slot + 1, :cols]),
                 jnp.float32(req.temperature), jnp.int32(req.top_k),
-                jax.random.PRNGKey(req.seed))
+                jax.random.PRNGKey(req.seed),
+                self._state_slot(slot, off))
         self.prefills += 1
         self.prefill_chunk_dispatches += 1
         st.chunks += 1
@@ -2515,6 +2637,11 @@ class Server:
         bytes move, and adopt is a page-table install. ``wire=True``:
         the snapshot holds gathered page CONTENT (a device pytree) fit
         for ``snapshot_to_doc`` and the agent wire."""
+        if self._state_slots:
+            raise NotImplementedError(
+                "extract_session (migration) is not implemented for a "
+                "model with conv layers (cfg.layer_types): a snapshot "
+                "carries pages, not the slot-resident state")
         if self.model.cfg.latent is not None:
             raise NotImplementedError(
                 "extract_session (migration) is not implemented for a "
@@ -3538,6 +3665,15 @@ class Server:
         if self.model.cfg.latent is not None:
             out["latent_bytes_per_token"] = kv_page_nbytes(
                 self.model.cfg, 1)
+        if self._state_slots:
+            cfg = self.model.cfg
+            out["conv_layers"] = cfg.conv_layers
+            out["attn_layers"] = cfg.attn_layers
+            out["state_bytes_per_slot"] = cfg.state_values_per_slot \
+                * jnp.dtype(cfg.dtype).itemsize
+            out["kv_bytes_per_token"] = kv_page_nbytes(cfg, 1)
+            out["state_resets"] = self.state_resets
+            out["state_carried_chunks"] = self.state_carried_chunks
         if self.mesh is not None:
             # flat numeric twins of mesh_info() so MetricsStore and
             # the remote agent's counters wire carry the topology

@@ -44,9 +44,58 @@ def cache_batch_axis(path, leaf) -> int | None:
     if name in ("cached_key", "cached_value"):
         return leaf.ndim - 4
     if name in ("cached_key_scale", "cached_value_scale", "cached_latent",
-                "cached_rope_key"):
+                "cached_rope_key") or name in SLOT_RESIDENT:
         return leaf.ndim - 3
     return None
+
+
+# Cache leaves that are a row a SLOT and have no ``max_len`` axis: a
+# short convolution's ``conv_state`` [.., b, K-1, d] (``ShortConv``).
+# They are a function of the sequence, not of a cached position, so the
+# page fabric leaves them where they are: ``paged_cache`` sizes them by
+# the slot count, a decode round takes and returns them whole
+# (``paged_view`` / ``paged_write_back``), a one-row window takes the
+# row of its slot (``slot_rows`` / ``store_slot_rows``), and what moves
+# PAGES (copy, gather, scatter, the byte counts) passes them by.
+SLOT_RESIDENT = ("conv_state",)
+
+
+def slot_resident(path) -> bool:
+    """Whether a cache leaf is slot-resident (above): the ONE place the
+    functions below learn it from."""
+    return str(path[-1].key if hasattr(path[-1], "key")
+               else path[-1]) in SLOT_RESIDENT
+
+
+def page_axis(path, leaf) -> int | None:
+    """The page axis of a POOLED leaf of a paged tree (where the batch
+    axis was); None for a slot-resident leaf and for the counters."""
+    return None if slot_resident(path) else cache_batch_axis(path, leaf)
+
+
+def slot_rows(cache: Any, slot) -> Any:
+    """The tree a ONE-row window (a paged prefill) runs against: every
+    slot-resident leaf cut to ``slot``'s row, the pools as they are
+    (the window reaches those through its page table). Traceable."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: jax.lax.dynamic_slice_in_dim(
+            leaf, jnp.asarray(slot, jnp.int32), 1,
+            axis=cache_batch_axis(path, leaf))
+        if slot_resident(path) else leaf, cache)
+
+
+def store_slot_rows(cache: Any, out: Any, slot) -> Any:
+    """Inverse of ``slot_rows`` after the window ran: ``out``'s pools
+    are the successors of ``cache``'s, its slot-resident rows go back
+    into ``slot`` of ``cache``'s."""
+    def put(path, leaf, oleaf):
+        if not slot_resident(path):
+            return oleaf
+        return jax.lax.dynamic_update_slice_in_dim(
+            leaf, oleaf.astype(leaf.dtype), jnp.asarray(slot, jnp.int32),
+            axis=cache_batch_axis(path, leaf))
+
+    return jax.tree_util.tree_map_with_path(put, cache, out)
 
 
 def write_slot_row(cache: Any, row: Any, slot) -> Any:
@@ -121,13 +170,14 @@ def _alloc_sharded(structs: Any, mesh) -> Any:
 
 
 def paged_cache(model, params, n_pages: int, page_size: int,
-                mesh=None) -> Any:
+                mesh=None, slots: int = 1) -> Any:
     """A PAGED cache pytree: every batched leaf of a batch-1
     ``init_cache`` tree — KV buffers ``[.., 1, max_len, kvh, dh]``,
     int8 scales ``[.., 1, max_len, kvh]`` — becomes a page POOL with
     ``(batch, max_len)`` replaced by ``(n_pages, page_size)``; shared
     counters pass through (per-slot decode neither reads nor advances
-    them). The tree STRUCTURE is unchanged, so ``model.apply`` with a
+    them), and a slot-resident leaf (``slot_resident``) gets ``slots``
+    rows. The tree STRUCTURE is unchanged, so ``model.apply`` with a
     ``page_table`` consumes it directly (flax returns the supplied
     value — the declared init shape only matters on the init pass),
     and scan_layers' stacked ``[n_layers, ...]`` leading axis is
@@ -140,8 +190,13 @@ def paged_cache(model, params, n_pages: int, page_size: int,
     than its shard (``_alloc_sharded``)."""
     def remap(path, leaf):
         ax = cache_batch_axis(path, leaf)
-        shape = leaf.shape if ax is None else \
-            leaf.shape[:ax] + (n_pages, page_size) + leaf.shape[ax + 2:]
+        if ax is None:
+            shape = leaf.shape
+        elif slot_resident(path):
+            shape = leaf.shape[:ax] + (slots,) + leaf.shape[ax + 1:]
+        else:
+            shape = leaf.shape[:ax] + (n_pages, page_size) \
+                + leaf.shape[ax + 2:]
         return jax.ShapeDtypeStruct(shape, leaf.dtype)
 
     structs = jax.tree_util.tree_map_with_path(
@@ -162,7 +217,7 @@ def default_page_size(cfg) -> int:
 
 def kv_page_nbytes(cfg, page_size: int) -> int:
     """Analytic bytes of ONE KV page for a model config (agrees with
-    ``page_nbytes`` of the built pool): n_layers x page_size x what the
+    ``page_nbytes`` of the built pool): attention layers x page_size x what the
     config says a layer caches a token (``cache_values_per_token``: K +
     V of every kv head, or a latent layer's one vector) at the cache
     dtype, plus the int8 mode's fp32 scales. Lets the CLIs size
@@ -171,7 +226,7 @@ def kv_page_nbytes(cfg, page_size: int) -> int:
     per = page_size * cfg.cache_values_per_token * item
     if cfg.kv_cache_quant:
         per += 2 * page_size * cfg.kv_heads * 4
-    return cfg.n_layers * per
+    return cfg.attn_layers * per
 
 
 def tree_consumed(cache: Any) -> bool:
@@ -186,7 +241,7 @@ def page_nbytes(cache: Any) -> int:
     the prefix store's paged byte budget account in."""
     total = 0
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
-        ax = cache_batch_axis(path, leaf)
+        ax = page_axis(path, leaf)
         if ax is not None:
             nbytes = int(np.prod(leaf.shape)) * leaf.dtype.itemsize
             total += nbytes // leaf.shape[ax]
@@ -202,7 +257,7 @@ def copy_page(cache: Any, src, dst) -> Any:
     traceable (``_copy_page`` jits it with traced indices — one
     compile ever)."""
     def cp(path, leaf):
-        ax = cache_batch_axis(path, leaf)
+        ax = page_axis(path, leaf)
         if ax is None:
             return leaf
         row = jax.lax.dynamic_index_in_dim(leaf, jnp.asarray(src, jnp.int32),
@@ -231,7 +286,7 @@ def gather_pages(cache: Any, idx) -> Any:
     drops); non-paged leaves (the shared counters) pass through so the
     tree STRUCTURE round-trips."""
     def g(path, leaf):
-        ax = cache_batch_axis(path, leaf)
+        ax = page_axis(path, leaf)
         return leaf if ax is None else take_pages(leaf, idx, ax)
 
     return jax.tree_util.tree_map_with_path(g, cache)
@@ -254,7 +309,7 @@ def scatter_pages(cache: Any, payload: Any, idx) -> Any:
     the values (tests/test_tier.py pins it across dtype x scan_layers
     x int8-KV scale leaves)."""
     def sc(path, leaf, pleaf):
-        ax = cache_batch_axis(path, leaf)
+        ax = page_axis(path, leaf)
         if ax is None:
             return leaf  # dest counters win; payload's ride-alongs drop
         # indexed on the page axis where it lies (scan_layers' stacked
@@ -296,8 +351,8 @@ def paged_view(cache: Any, table, max_len: int) -> Any:
     (scanning a mostly-empty [max_seq_len] buffer) disappears along
     with the residency waste."""
     def to_view(path, leaf):
-        ax = cache_batch_axis(path, leaf)
-        if ax is None:
+        ax = page_axis(path, leaf)
+        if ax is None:  # counters; slot-resident rows as they are
             return leaf
         v = take_pages(leaf, table, ax)  # [.., b, cols, ps, ..]
         shape = v.shape[:ax] + (v.shape[ax],
@@ -320,7 +375,8 @@ def paged_write_back(pool: Any, view: Any, table, start, n_steps: int,
     (start < 0 = empty slot), so only those ``b x n_steps`` tokens move
     — everything else in the view is an unmodified copy the pool
     already holds. Out-of-range positions and sentinel table entries
-    drop, exactly like the direct paged scatter."""
+    drop, exactly like the direct paged scatter. A slot-resident leaf
+    is stored whole, by slot: the view's rows are the slots' own."""
     b = table.shape[0]
     pos_w = jnp.where(start[:, None] >= 0,
                       start[:, None]
@@ -328,6 +384,8 @@ def paged_write_back(pool: Any, view: Any, table, start, n_steps: int,
     rows = jnp.arange(b)[:, None]
 
     def wb(path, pleaf, vleaf):
+        if slot_resident(path):
+            return vleaf  # the round's rows ARE the slots' rows
         ax = cache_batch_axis(path, pleaf)
         if ax is None:
             return pleaf
@@ -452,7 +510,7 @@ class PagePool:
     """
 
     def __init__(self, model, params, n_pages: int, page_size: int,
-                 mesh=None, shared: bool = False):
+                 mesh=None, shared: bool = False, slots: int = 1):
         if n_pages < 1 or page_size < 1:
             raise ValueError("n_pages and page_size must be >= 1")
         self.n_pages = int(n_pages)
@@ -467,7 +525,7 @@ class PagePool:
         # (stats -> cow_shared, reserve -> available) self-nest
         self._mu = threading.RLock()
         self.cache = paged_cache(model, params, n_pages, page_size,
-                                 mesh=mesh)
+                                 mesh=mesh, slots=slots)
         self.tree_epoch = 0  # trees lost to a failed dispatch so far
         self.page_nbytes = page_nbytes(self.cache)
         self.refcount = np.zeros(self.n_pages, np.int32)
