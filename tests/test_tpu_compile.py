@@ -334,3 +334,29 @@ def test_heads_of_64_packed_into_lanes_keep_the_pool_row_major(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * 1024 * 64 * 512 * 2 \
         + b * 2 * 2048 * 2
+
+
+def test_loss_head_gradient_is_one_pass_at_the_training_cells_shapes(
+        tpu_compile, topo):
+    """The training cell's head (8,188 rows of 2,048 bf16 against an
+    untied 50,304-row head, float32 matmul operands): differentiated, it
+    compiles to ONE loop over row tiles holding three matmuls (logits,
+    dhidden, dW) and no recompute of the logits; the value alone keeps
+    the vocab-chunked scan's one matmul."""
+    import re
+
+    from tony_tpu.ops import chunked_cross_entropy
+
+    def loss(h, e, labels):
+        return chunked_cross_entropy(h, e, labels, chunk_size=2048)
+
+    shapes = (S((8188, 2048), BF16), S((50304, 2048), BF16),
+              S((8188,), I32))
+    for fn, scope, n_dots in (
+            (jax.value_and_grad(loss, argnums=(0, 1)), "xent.fused", 3),
+            (loss, "xent.lse", 1)):
+        hlo = tpu_compile(fn, *shapes)
+        assert len(re.findall(r"\) while\(", hlo)) == 1
+        dots = [ln for ln in hlo.splitlines()
+                if " convolution(" in ln and scope in ln]
+        assert len(dots) == n_dots, dots
