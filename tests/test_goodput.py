@@ -83,14 +83,17 @@ def test_cost_model_scales_with_depth_and_view():
 
 
 def test_cost_model_utilization_reference_gating():
-    none_bw, none_mfu = _cm().utilization(1e9, 1e9, 10.0)
-    assert none_bw is None and none_mfu is None  # no reference: null
-    cm = _cm(hbm_gbps=1000.0, peak_flops=100e12)
-    bw, mfu = cm.utilization(5e9, 100e12 * 0.01, 10.0)
+    summary = {"decode": {"ms": 10.0, "est_bytes_steady": 5e9,
+                          "est_flops_steady": 100e12 * 0.01}}
+    none = ledger(summary, 20.0)["utilization"]["decode"]
+    # no reference: null
+    assert none["hbm_bw_pct"] is None and none["mfu_pct"] is None
+    util = ledger(summary, 20.0, hbm_gbps=1000.0,
+                  peak_flops=100e12)["utilization"]["decode"]
     # 5 GB in 10 ms against 1000 GB/s = 50%; 1e12 FLOPs in 10 ms
     # against 100 TFLOP/s = 100%
-    assert bw == pytest.approx(50.0, abs=0.1)
-    assert mfu == pytest.approx(100.0, abs=0.1)
+    assert util["hbm_bw_pct"] == pytest.approx(50.0, abs=0.1)
+    assert util["mfu_pct"] == pytest.approx(100.0, abs=0.1)
 
 
 def test_detect_hbm_gbps_env_override(monkeypatch):
@@ -229,11 +232,13 @@ def test_explicit_hbm_reference_prices_utilization(tiny):
     led = server.goodput()
     assert led["hbm_gbps"] == 800.0
     util = led["utilization"]["decode"]
-    assert util["hbm_bw_pct"] is not None and util["hbm_bw_pct"] > 0
-    # per-dispatch tags carry the same estimate
+    # a tiny model's share of a host-timed dispatch may round to 0.00:
+    # what must hold is that steady bytes were priced against the knob
+    assert util["hbm_bw_pct"] is not None
+    assert server.timeline.summary()["decode"]["est_bytes_steady"] > 0
+    # the share is the ledger's, per kind; a record carries no tag
     recs = [r for r in server.timeline.recent() if r.kind == "decode"]
-    assert recs and all("hbm_bw_pct" in r.tags for r in recs
-                        if not r.compile)
+    assert recs and not any("hbm_bw_pct" in r.tags for r in recs)
 
 
 def test_merged_local_plus_remote_ledger_pins(tiny):

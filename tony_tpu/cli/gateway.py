@@ -58,6 +58,9 @@ import signal
 import sys
 import threading
 
+# first of the program: the start-up ledger's clock and compile listeners
+from tony_tpu.obs import startup
+
 
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
@@ -574,8 +577,9 @@ def server_factory(args, model, params, eos):
         ps = max(1, min(int(ps), cfg.max_seq_len))
         per_replica = paged_kw.get("kv_pages", 0) \
             or args.serve_batch * (-(-cfg.max_seq_len // ps))
-        pool = PagePool(model, params, int(per_replica) * ceiling, ps,
-                        mesh=mesh, shared=True)
+        with startup.leaf("startup.pool"):
+            pool = PagePool(model, params, int(per_replica) * ceiling, ps,
+                            mesh=mesh, shared=True)
 
     def make(index: int):
         return Server(model, params, batch_size=args.serve_batch,
@@ -936,47 +940,48 @@ def main(argv=None) -> int:
     encode = decode = None
     model = params = None
     eos: list = []
-    if remote:
-        # the gateway process is a pure router: the agents own the
-        # weights (and pay the compiles). With a checkpoint named, load
-        # ONLY the tokenizer so text prompts still work at the door.
-        if args.model:
+    with startup.leaf("startup.load"):
+        if remote:
+            # the gateway process is a pure router: the agents own the
+            # weights (and pay the compiles). With a checkpoint named, load
+            # ONLY the tokenizer so text prompts still work at the door.
+            if args.model:
+                try:
+                    import transformers
+
+                    tok = transformers.AutoTokenizer.from_pretrained(
+                        args.model)
+                    encode, decode = tok.encode, tok.decode
+                except Exception:  # noqa: BLE001 — token_ids still serve
+                    print("note: no tokenizer in model dir; token_ids "
+                          "requests only", file=sys.stderr)
+        elif args.demo_model:
+            model, params, eos = *demo_model(), \
+                ([args.eos_id] if args.eos_id >= 0 else [])
+        else:
+            from tony_tpu.cli.generate import load_model
+            from tony_tpu.models.generate import normalize_eos_ids
+
+            model, wrapped, config = load_model(args.model)
+            params = wrapped["params"]
+            if args.dtype == "bf16":
+                import jax
+                import jax.numpy as jnp
+
+                params = jax.tree.map(
+                    lambda x: x.astype(jnp.bfloat16)
+                    if jnp.issubdtype(x.dtype, jnp.floating) else x, params)
+            eos = normalize_eos_ids(args.eos_id) or \
+                normalize_eos_ids(getattr(config, "eos_token_id", None))
             try:
                 import transformers
 
-                tok = transformers.AutoTokenizer.from_pretrained(
-                    args.model)
+                tok = transformers.AutoTokenizer.from_pretrained(args.model)
                 encode, decode = tok.encode, tok.decode
-            except Exception:  # noqa: BLE001 — token_ids still serve
+            except Exception:  # noqa: BLE001 — a checkpoint without a
+                # tokenizer still serves token_ids requests
                 print("note: no tokenizer in model dir; token_ids "
                       "requests only", file=sys.stderr)
-    elif args.demo_model:
-        model, params, eos = *demo_model(), \
-            ([args.eos_id] if args.eos_id >= 0 else [])
-    else:
-        from tony_tpu.cli.generate import load_model
-        from tony_tpu.models.generate import normalize_eos_ids
-
-        model, wrapped, config = load_model(args.model)
-        params = wrapped["params"]
-        if args.dtype == "bf16":
-            import jax
-            import jax.numpy as jnp
-
-            params = jax.tree.map(
-                lambda x: x.astype(jnp.bfloat16)
-                if jnp.issubdtype(x.dtype, jnp.floating) else x, params)
-        eos = normalize_eos_ids(args.eos_id) or \
-            normalize_eos_ids(getattr(config, "eos_token_id", None))
-        try:
-            import transformers
-
-            tok = transformers.AutoTokenizer.from_pretrained(args.model)
-            encode, decode = tok.encode, tok.decode
-        except Exception:  # noqa: BLE001 — a checkpoint without a
-            # tokenizer still serves token_ids requests
-            print("note: no tokenizer in model dir; token_ids "
-                  "requests only", file=sys.stderr)
     return serve(args, model, params, eos, encode=encode, decode=decode)
 
 
@@ -989,7 +994,41 @@ def serve(args, model, params, eos, *, encode=None, decode=None,
     ``on_ready(http)`` is called once the listener is up — how an
     embedding caller (``chip_smoke.py`` serves a seeded random model
     this way) learns the ephemeral port."""
-    from tony_tpu.gateway import GatewayEdge, GatewayHTTP
+    startup.serve_entered()
+    with startup.rest("startup.build"):
+        gateway, recover_entries = _build(args, model, params, eos)
+    with startup.rest("startup.edge"):
+        http = _open(args, gateway, recover_entries, model, params, eos,
+                     encode, decode)
+
+    stop = threading.Event()
+
+    def _on_signal(signum, frame):
+        if stop.is_set():  # second signal: force exit
+            os._exit(1)
+        print(f"signal {signum}: draining (readyz -> 503, finishing "
+              f"in-flight)...", file=sys.stderr, flush=True)
+        stop.set()
+
+    signal.signal(signal.SIGTERM, _on_signal)
+    signal.signal(signal.SIGINT, _on_signal)
+    startup.stamp("edge_listening_s")
+    if on_ready is not None:
+        on_ready(http)
+    stop.wait()
+    ok = gateway.drain(timeout=args.drain_timeout)
+    http.stop()
+    if not ok:
+        print("drain timed out with requests still in flight",
+              file=sys.stderr)
+        return 1
+    print("drained clean", file=sys.stderr)
+    return 0
+
+
+def _build(args, model, params, eos):
+    """``serve()``'s fleet, started, and the previous boot's journal
+    entries when ``--recover`` asks for them (``startup.build``)."""
     from tony_tpu.metrics import MetricsStore
 
     # --recover: find the DEAD boot's journal BEFORE build_gateway
@@ -1009,6 +1048,15 @@ def serve(args, model, params, eos, *, encode=None, decode=None,
 
     gateway = build_gateway(args, model, params, eos,
                             metrics_store=MetricsStore()).start()
+    return gateway, recover_entries
+
+
+def _open(args, gateway, recover_entries, model, params, eos, encode,
+          decode):
+    """The recovery, the scaler, the rebalancer and the HTTP edge
+    (``startup.edge``); returns the edge, listening."""
+    from tony_tpu.gateway import GatewayEdge, GatewayHTTP
+
     if recover_entries is not None:
         report = gateway.recover_from_journal(recover_entries)
         print(f"recovery: {report['adopted']} adopted mid-stream, "
@@ -1047,29 +1095,7 @@ def serve(args, model, params, eos, *, encode=None, decode=None,
     print(f"tony-tpu gateway at http://{http.host}:{http.port} "
           f"({n_rep} replica(s) x {args.serve_batch} "
           f"slots{elastic}{mode})", flush=True)
-
-    stop = threading.Event()
-
-    def _on_signal(signum, frame):
-        if stop.is_set():  # second signal: force exit
-            os._exit(1)
-        print(f"signal {signum}: draining (readyz -> 503, finishing "
-              f"in-flight)...", file=sys.stderr, flush=True)
-        stop.set()
-
-    signal.signal(signal.SIGTERM, _on_signal)
-    signal.signal(signal.SIGINT, _on_signal)
-    if on_ready is not None:
-        on_ready(http)
-    stop.wait()
-    ok = gateway.drain(timeout=args.drain_timeout)
-    http.stop()
-    if not ok:
-        print("drain timed out with requests still in flight",
-              file=sys.stderr)
-        return 1
-    print("drained clean", file=sys.stderr)
-    return 0
+    return http
 
 
 if __name__ == "__main__":

@@ -132,7 +132,7 @@ from typing import Any, Callable
 from tony_tpu.gateway.admission import (DEFAULT_TIER, WFQueue, TenantQuotas,
                                         parse_tier_weights)
 from tony_tpu.gateway.admission import DEFAULT_TIER_WEIGHTS as _DEFAULT_WEIGHTS
-from tony_tpu.obs import Histogram, RequestTrace, TraceBuffer
+from tony_tpu.obs import Histogram, RequestTrace, TraceBuffer, startup
 from tony_tpu.obs.alerts import AlertBus, default_rules
 from tony_tpu.obs.goodput import merge_ledgers
 from tony_tpu.obs.phases import HostPhases
@@ -3739,6 +3739,8 @@ class Gateway:
                 out["edge"] = edge()
             except Exception:  # a dying edge must not break /stats
                 log.exception("edge stats provider failed")
+        # this process's start-up: spans, stamps, compiles (obs/startup)
+        out["startup"] = startup.snapshot()
         return out
 
     def register_edge(self, stats_fn: Callable | None) -> None:

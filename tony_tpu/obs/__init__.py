@@ -15,6 +15,10 @@ layers, each consumable on its own:
   phases (``/stats`` ``engine.host``), each also a ``tony.*`` span in
   a ``jax.profiler`` capture, where ``profiler/xplane.idle_gaps``
   names the device's idle gaps by them;
+- ``startup``: where the process's time went before it served — the
+  start-up spans (a ``HostPhases`` of ``startup.*``, stamps in seconds
+  since the OS started the process) and a table of every executable
+  jax traced, lowered and compiled or loaded (``/stats`` ``startup``);
 - ``prom`` + ``export``: dependency-free Prometheus text exposition of
   the gateway's counters, gauges, and latency histograms
   (``GET /metrics``).

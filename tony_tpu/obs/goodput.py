@@ -14,9 +14,9 @@ correlate them by hand. This module closes that loop with two pieces:
   with (chunk depth, occupancy, paged view extent). Stamped onto each
   ``DispatchRecord`` as ``est_bytes`` / ``est_flops``; with a
   peak-HBM-GB/s reference available (chip table or ``--hbm-gbps``)
-  each record also gets a per-dispatch HBM-BW% and MFU estimate. CPU
-  runs report bytes with ``utilization: null`` — an estimate against
-  an unknown roofline would be a lie.
+  the ledger prices each kind's steady bytes and FLOPs as HBM-BW% and
+  MFU. CPU runs report bytes with ``utilization: null`` — an estimate
+  against an unknown roofline would be a lie.
 - the goodput LEDGER (``ledger()``): decompose a replica's wall clock
   into named buckets that sum to <= 1.0 — steady useful work per
   dispatch kind, compile time, bucket/view padding waste (the pow2
@@ -248,21 +248,6 @@ class CostModel:
         gather/scatter): a pure copy — ``n_bytes`` moved, zero
         FLOPs."""
         return float(n_bytes), 0.0
-
-    def utilization(self, n_bytes: float, flops: float,
-                    dur_ms: float) -> tuple:
-        """(hbm_bw_pct, mfu_pct) for a dispatch that moved ``n_bytes``
-        and computed ``flops`` in ``dur_ms`` — ``None`` where no
-        roofline reference is known (CPU runs report bytes with
-        utilization null rather than a made-up percentage)."""
-        if dur_ms <= 0:
-            return None, None
-        secs = dur_ms / 1e3
-        bw = round(100.0 * n_bytes / (secs * self.hbm_gbps * 1e9), 2) \
-            if self.hbm_gbps > 0 else None
-        mfu = round(100.0 * flops / (secs * self.peak_flops), 2) \
-            if self.peak_flops > 0 else None
-        return bw, mfu
 
 
 def ledger(summary: dict, wall_ms: float, *, hbm_gbps: float = 0.0,

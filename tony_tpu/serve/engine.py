@@ -60,6 +60,7 @@ from tony_tpu.models.generate import (_is_eos, init_cache,
 from tony_tpu.models.transformer import _serve_replicate
 from tony_tpu.obs.goodput import (CostModel, detect_hbm_gbps,
                                   detect_peak_flops, ledger)
+from tony_tpu.obs import startup
 from tony_tpu.obs.phases import HostPhases
 from tony_tpu.obs.timeline import DispatchRecord, DispatchTimeline
 from tony_tpu.parallel.moe import N_COUNTS
@@ -957,36 +958,37 @@ class Server:
         if page_pool is not None and not self.paged:
             raise ValueError("a shared page_pool needs the paged KV "
                              "cache")
-        if self.paged:
-            if page_pool is not None:
-                # SHARED pool (ISSUE-18): a gateway-owned fleet pool
-                # lent to every co-located engine — the pool keeps
-                # device-tree ownership (SlotCache delegates), and
-                # the pool's lock is the single-writer dispatch
-                # discipline serialized below
-                pool = page_pool
+        with startup.leaf("startup.pool"):
+            if self.paged:
+                if page_pool is not None:
+                    # SHARED pool (ISSUE-18): a gateway-owned fleet pool
+                    # lent to every co-located engine — the pool keeps
+                    # device-tree ownership (SlotCache delegates), and
+                    # the pool's lock is the single-writer dispatch
+                    # discipline serialized below
+                    pool = page_pool
+                else:
+                    ps = int(kv_page_size) or default_page_size(model.cfg)
+                    ps = max(1, min(ps, model.cfg.max_seq_len))
+                    max_pages = -(-model.cfg.max_seq_len // ps)
+                    # auto pool: the unpaged-equivalent footprint — every
+                    # slot can still hold a full-length sequence, so
+                    # capacity parity with the fixed-shape path is the
+                    # floor; explicit kv_pages grows the batch into the
+                    # same HBM or shrinks the footprint for short-sequence
+                    # traffic
+                    n_pages = int(kv_pages) or batch_size * max_pages
+                    # mesh: the pool allocates DIRECTLY under its kv-head
+                    # shardings (slots._alloc_sharded) — a dense-then-
+                    # reshard order would transiently hold the whole pool
+                    # on one chip and OOM exactly the configurations the
+                    # mesh unlocks
+                    pool = PagePool(model, params, n_pages, ps, mesh=mesh,
+                                    slots=batch_size)
+                self.slots = SlotCache(model, params, batch_size, pool=pool,
+                                       mesh=mesh)
             else:
-                ps = int(kv_page_size) or default_page_size(model.cfg)
-                ps = max(1, min(ps, model.cfg.max_seq_len))
-                max_pages = -(-model.cfg.max_seq_len // ps)
-                # auto pool: the unpaged-equivalent footprint — every
-                # slot can still hold a full-length sequence, so
-                # capacity parity with the fixed-shape path is the
-                # floor; explicit kv_pages grows the batch into the
-                # same HBM or shrinks the footprint for short-sequence
-                # traffic
-                n_pages = int(kv_pages) or batch_size * max_pages
-                # mesh: the pool allocates DIRECTLY under its kv-head
-                # shardings (slots._alloc_sharded) — a dense-then-
-                # reshard order would transiently hold the whole pool
-                # on one chip and OOM exactly the configurations the
-                # mesh unlocks
-                pool = PagePool(model, params, n_pages, ps, mesh=mesh,
-                                slots=batch_size)
-            self.slots = SlotCache(model, params, batch_size, pool=pool,
-                                   mesh=mesh)
-        else:
-            self.slots = SlotCache(model, params, batch_size, mesh=mesh)
+                self.slots = SlotCache(model, params, batch_size, mesh=mesh)
         # dispatch concurrency (ISSUE-19): every engine owns ITS OWN
         # scheduler lock, so co-located engines on a shared pool do not
         # serialize whole step() iterations. The shared device TREE is
@@ -1261,12 +1263,13 @@ class Server:
             # mid-page — one chance first-token match among unrelated
             # prompts is enough — and stalls every live stream for the
             # compile (0.7 s on the v5e, PERF.md PR 28)
-            with self._tree_lock:
+            with startup.leaf("startup.fork_warm"), self._tree_lock:
                 self.slots.cache = _copy_page(
                     self.slots.cache, jnp.int32(0), jnp.int32(0))
 
         if warm_views and self.paged:
-            self.warm_views()
+            with startup.leaf("startup.warm_views"):
+                self.warm_views()
 
     def warm_views(self) -> int:
         """Compile the chunk program for EVERY view bucket and depth the
@@ -1364,8 +1367,7 @@ class Server:
                          est: tuple = (0.0, 0.0)) -> None:
         """One timeline record, goodput-stamped: position accounting
         (work/fed/rejected — the ledger's exact duration split) plus
-        the cost model's bytes/FLOPs estimate, with per-dispatch
-        HBM-BW% / MFU tags when a roofline reference is known."""
+        the cost model's bytes/FLOPs estimate."""
         tags = tags or {}
         if kind != "decode" and self._inflight:
             # enqueued behind the rounds in flight, this program's sync
@@ -1376,13 +1378,6 @@ class Server:
                 t0 += late
                 dur_ms = max(0.0, dur_ms - late * 1e3)
         est_bytes, est_flops = est
-        if self.cost is not None and est_bytes:
-            bw, mfu = self.cost.utilization(est_bytes, est_flops,
-                                            dur_ms)
-            if bw is not None:
-                tags["hbm_bw_pct"] = bw
-            if mfu is not None:
-                tags["mfu_pct"] = mfu
         self.timeline.record(DispatchRecord(
             kind, t0, dur_ms, occ, bucket, tokens,
             key_ not in self._compiled, request_id=request_id,

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+# the start-up ledger's compile listeners, before any step is built
+from tony_tpu.obs import startup  # noqa: F401
 from tony_tpu.ops.adamw import FusedAdamW, fused_adamw_update
 from tony_tpu.parallel.sharding import batch_sharding, shard_params_by_size
 
