@@ -1,9 +1,11 @@
 """Pallas kernel tests (interpreter mode on CPU)."""
 
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
-import functools
 import pytest
 
 from tony_tpu.ops import add_rmsnorm, flash_attention, rmsnorm
@@ -465,6 +467,95 @@ def test_flash_attention_causal_cross_blockable_lengths():
     np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), atol=5e-5,
                                rtol=5e-5)
 
+
+
+# -- the flash backward: one fused kernel, or dq + dk/dv past the budget -----
+
+def _flash_bwd_kernels(fn, *args):
+    """Names of the flash backward kernels that tracing ``fn`` reaches."""
+    return set(re.findall(r"flash_bwd_\w+", str(jax.make_jaxpr(fn)(*args))))
+
+
+# name -> (q shape, kv shape, flash_attention keywords); segments by row
+_BWD_CASES = {
+    "causal": ((2, 64, 2, 16), (2, 64, 2, 16),
+               dict(causal=True, block_q=16, block_k=32)),
+    "gqa": ((1, 64, 4, 16), (1, 64, 2, 16),
+            dict(causal=True, block_q=16, block_k=16)),
+    "window": ((1, 64, 2, 8), (1, 64, 2, 8),
+               dict(causal=True, block_q=8, block_k=16, window=20)),
+    "segments_gqa_window": ((2, 32, 4, 8), (2, 32, 2, 8),
+                            dict(causal=True, block_q=8, block_k=8,
+                                 window=9, segment_ids=[[0] * 10 + [1] * 22,
+                                                        [0] * 32])),
+    "non_causal": ((1, 64, 2, 16), (1, 64, 2, 16),
+                   dict(causal=False, block_q=16, block_k=32)),
+    "padded_length": ((1, 27, 2, 8), (1, 27, 2, 8),
+                      dict(causal=True, block_q=8, block_k=8)),
+    "cross_lengths": ((1, 32, 2, 8), (1, 8, 2, 8),
+                      dict(causal=True, block_q=8, block_k=8)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BWD_CASES))
+def test_flash_backward_fused_matches_split(case, monkeypatch):
+    """The fused backward (P recomputed once a tile, dQ accumulated in f32
+    across kv blocks and rounded once) and the split one (a dq and a dk/dv
+    kernel) give the SAME dQ, dK and dV on the same inputs: each tile's
+    arithmetic and the order of every f32 sum are the same. The split path
+    is reached as in production, by a shape past the VMEM budget: here the
+    budget is lowered below what any shape needs."""
+    from tony_tpu.ops import attention
+
+    q_shape, kv_shape, kw = _BWD_CASES[case]
+    kw = dict(kw)
+    if "segment_ids" in kw:
+        kw["segment_ids"] = jnp.asarray(kw["segment_ids"], jnp.int32)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(21), 3)
+    q = jax.random.normal(kq, q_shape)
+    k = jax.random.normal(kk, kv_shape)
+    v = jax.random.normal(kv, kv_shape)
+
+    def run(kernels):
+        # a new function each time: jax caches a function's trace
+        grad = jax.grad(
+            lambda q, k, v: jnp.sum(flash_attention(q, k, v, **kw) ** 2),
+            argnums=(0, 1, 2))
+        assert _flash_bwd_kernels(grad, q, k, v) == kernels
+        return grad(q, k, v)
+
+    fused = run({"flash_bwd_fused"})
+    monkeypatch.setattr(attention, "_FUSED_BWD_VMEM", 0)
+    split = run({"flash_bwd_dq", "flash_bwd_dkv"})
+    for a, b, name in zip(fused, split, "qkv"):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("q_shape,kv_heads", [
+    ((1, 131072, 2, 128), 2),
+    ((1, 16384, 32, 128), 4),
+], ids=["long_sequence", "wide_gqa_group"])
+def test_flash_backward_splits_where_dq_outgrows_the_vmem_budget(
+        q_shape, kv_heads):
+    """Where the q-head group's f32 dQ scratch alone is past the budget
+    (64 MiB here: 131072 positions of one head, or 16384 of a group of
+    8), the backward takes the split dq and dk/dv kernels; the training
+    cell's shapes (4 x 2048, 16 heads x 128, blocks 512 x 1024) take the
+    fused one. Traced only: nothing of this size runs."""
+    from tony_tpu.ops import attention
+
+    b, length, heads, d = q_shape
+    assert (heads // kv_heads) * length * d * 4 > attention._FUSED_BWD_VMEM
+    grad = jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, True, 512, 1024).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, length, kv_heads, d), jnp.bfloat16)
+    assert _flash_bwd_kernels(grad, q, kv, kv) == {"flash_bwd_dq",
+                                                   "flash_bwd_dkv"}
+    cell = jax.ShapeDtypeStruct((4, 2048, 16, 128), jnp.bfloat16)
+    assert _flash_bwd_kernels(grad, cell, cell, cell) == {"flash_bwd_fused"}
 
 # -- int8 weight-only matmul (ops/quant.py) ----------------------------------
 

@@ -93,26 +93,61 @@ def _flash_grad(**kw):
 
 
 _LONG = S((1, 8192, 8, 128), BF16)
-# name -> (fn, argument shapes, Mosaic kernels expected: the backward
-# adds a dq and a dk/dv kernel to the forward's one)
+# the training cell's attention (pythia-1.4b: 16 heads x 128, 4 x 2048)
+_CELL = S((4, 2048, 16, 128), BF16)
+# long enough that one head's f32 dQ (32768 x 128 x 4 B = 16 MiB, with
+# its output buffers 32 MiB) passes the fused backward's VMEM budget
+_LONGER = S((1, 32768, 8, 128), BF16)
+_FUSED = ("flash_bwd_fused",)
+_SPLIT = ("flash_bwd_dq", "flash_bwd_dkv")
+# name -> (fn, argument shapes, Mosaic kernels expected, backward kernels
+# by name): the forward's one kernel, and one fused backward kernel where
+# the q-head group's f32 dQ fits VMEM, else a dq and a dk/dv kernel
 _FLASH_CASES = {
-    "fwd": (_flash, {}, (_FLAG, _FLAG, _FLAG), 1),
-    "fwd_bwd": (_flash_grad, {}, (_FLAG, _FLAG, _FLAG), 3),
+    "fwd": (_flash, {}, (_FLAG, _FLAG, _FLAG), 1, ()),
+    "fwd_bwd": (_flash_grad, {}, (_FLAG, _FLAG, _FLAG), 2, _FUSED),
     "window_fwd_bwd": (_flash_grad, {"window": 1024},
-                       (_FLAG, _FLAG, _FLAG), 3),
+                       (_FLAG, _FLAG, _FLAG), 2, _FUSED),
     "segments_fwd_bwd": (_flash_grad, {},
-                         (_FLAG, _FLAG, _FLAG, S((4, 2048), I32)), 3),
-    "gqa_fwd": (_flash, {}, (_GQA_Q, _GQA_KV, _GQA_KV), 1),
-    "gqa_fwd_bwd": (_flash_grad, {}, (_GQA_Q, _GQA_KV, _GQA_KV), 3),
+                         (_FLAG, _FLAG, _FLAG, S((4, 2048), I32)), 2,
+                         _FUSED),
+    "gqa_fwd": (_flash, {}, (_GQA_Q, _GQA_KV, _GQA_KV), 1, ()),
+    "gqa_fwd_bwd": (_flash_grad, {}, (_GQA_Q, _GQA_KV, _GQA_KV), 2,
+                    _FUSED),
     "window_seq8192_fwd": (_flash, {"window": 1024},
-                           (_LONG, _LONG, _LONG), 1),
+                           (_LONG, _LONG, _LONG), 1, ()),
+    "seq32768_fwd_bwd": (_flash_grad, {}, (_LONGER, _LONGER, _LONGER), 3,
+                         _SPLIT),
 }
+
+
+def _assert_flash_kernels(hlo: str, n: int, backward: tuple):
+    """Exactly ``n`` Mosaic kernels, the backward's by their names (a
+    pallas call's name lands in its custom call's op name)."""
+    assert hlo.count("tpu_custom_call") == n
+    for name in ("flash_bwd_fused", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert (name in hlo) == (name in backward), name
 
 
 @pytest.mark.parametrize("case", sorted(_FLASH_CASES))
 def test_flash_attention_compiles(tpu_compile, case):
-    make, kw, shapes, n_kernels = _FLASH_CASES[case]
-    _assert_kernel(tpu_compile(make(**kw), *shapes), n_kernels)
+    make, kw, shapes, n_kernels, backward = _FLASH_CASES[case]
+    _assert_flash_kernels(tpu_compile(make(**kw), *shapes), n_kernels,
+                          backward)
+
+
+def test_training_cells_attention_step_holds_two_kernels(tpu_compile):
+    """The training cell's attention, value and gradient, at its blocks
+    (512 x 1024): one forward and one fused backward kernel, where the
+    split backward had a dq and a dk/dv kernel (three in all)."""
+    fa = _flash()
+
+    def loss(q, k, v):
+        return fa(q, k, v).astype(F32).sum()
+
+    hlo = tpu_compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                      _CELL, _CELL, _CELL)
+    _assert_flash_kernels(hlo, 2, _FUSED)
 
 
 def test_flash_attention_compiles_under_a_data_parallel_mesh(
@@ -142,7 +177,7 @@ def test_flash_attention_compiles_under_a_data_parallel_mesh(
 
     hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         *qkv).compile().as_text()
-    _assert_kernel(hlo, 3)
+    _assert_flash_kernels(hlo, 2, _FUSED)
 
 
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])

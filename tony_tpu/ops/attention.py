@@ -16,10 +16,19 @@ SDPA/FlashAttention-CUDA). Design per the pallas TPU playbook:
 - causal masking prunes fully-masked kv blocks via @pl.when
 
 Falls back to the interpreter off-TPU (tests run it on CPU), and exposes a
-custom_vjp with a pallas FlashAttention-2 backward: the forward saves the
-per-row logsumexp; dQ and dK/dV kernels recompute P = exp(S - lse)
-blockwise, so no [L, L] tensor is ever materialized in either direction
-and GQA K/V are never repeated in HBM (block-indexed per q-head group).
+custom_vjp with a pallas backward in the FlashAttention-2 scheme: the
+forward saves the per-row logsumexp and the backward recomputes
+P = exp(S - lse) blockwise, so no [L, L] tensor is ever materialized in
+either direction and GQA K/V are never repeated in HBM (block-indexed per
+q-head group). The shapes choose one of two backward paths:
+
+- fused (kernel ``flash_bwd_fused``) where the q-head group's f32 dQ fits
+  the VMEM budget (``_fused_bwd_vmem_bytes``): for each kv block, one
+  kernel walks the q blocks, recomputes P ONCE a tile and accumulates
+  dK, dV and the whole sequence's dQ from it (5 tile products a tile);
+- split (``flash_bwd_dq`` and ``flash_bwd_dkv``) where it does not (long
+  sequences, wide GQA groups): each kernel recomputes P for its own
+  gradients (7 tile products a tile, two exp passes).
 """
 
 from __future__ import annotations
@@ -263,21 +272,30 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           *rest, causal: bool,
                           block_q: int, block_k: int, scale: float,
                           nq: int, nqb: int, window: int = 0,
-                          has_seg: bool = False):
-    if has_seg:
-        qseg_ref, kseg_ref, dk_ref, dv_ref, dk_scr, dv_scr = rest
-    else:
-        dk_ref, dv_ref, dk_scr, dv_scr = rest
-    """dK/dV: grid (b*kvh, nk, group*nq); for each KV-HEAD block, the
+                          has_seg: bool = False, with_dq: bool = False):
+    """dK/dV: grid (b*kvh, nk, group*nqb); for each KV-HEAD block, the
     innermost scan walks every q block of every q head in this kv group
-    (step s: head g = s // nq, q block qi = s % nq), accumulating into one
+    (step s: head g = s // nqb, q block qi = s % nqb), accumulating into one
     [block_k, d] scratch pair — so dK/dV are written at their true
     [b*kvh, lk, d] size with no group-factor HBM amplification.
 
     dV = sum_{g,q} P^T dO; dK = sum_{g,q} dS^T Q (dS as in the dQ kernel).
 
+    ``with_dq`` is the fused backward: the same tile also adds dS K into
+    an f32 scratch holding dQ for the whole sequence of every q head in
+    the group, so P is recomputed once a tile for all three gradients
+    (five tile products and one exp pass, against seven and two beside
+    the dQ kernel). That scratch carries across the kv axis, which is
+    then sequential too; dQ is rounded once, after the last kv block.
+
     ``nq`` is the TOTAL q-block count; ``nqb`` the banded width actually
     walked per head (== nq when unwindowed)."""
+    if has_seg:
+        qseg_ref, kseg_ref, *rest = rest
+    if with_dq:
+        dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = rest
+    else:
+        dk_ref, dv_ref, dk_scr, dv_scr = rest
     ki = pl.program_id(1)
     s_idx = pl.program_id(2)
     ns = pl.num_programs(2)
@@ -291,13 +309,18 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
+    if with_dq:
+        @pl.when((ki == 0) & (s_idx == 0))
+        def _init_dq():
+            dq_scr[:] = jnp.zeros_like(dq_scr)
+
     def _body(masked: bool):
         q = q_ref[0]  # native dtype: full-rate MXU, fp32 accumulation
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0]
         # raw scores (see the dQ kernel): scale folds into the exp; the
-        # dS scale lands on the [block_k, d] dK accumulator at finalize
+        # dS scale lands on the dK and dQ accumulators at finalize
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if masked:
@@ -316,6 +339,11 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if with_dq:
+            rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+            dq_scr[s_idx // nqb, rows, :] += jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     _dispatch_body(_body, causal, has_seg, qi, ki, block_q, block_k,
                    window)
@@ -324,6 +352,12 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _finalize():
         dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+    if with_dq:
+        @pl.when((ki == pl.num_programs(1) - 1) & (s_idx == ns - 1))
+        def _finalize_dq():
+            for g in range(dq_ref.shape[0]):
+                dq_ref[g] = (dq_scr[g] * scale).astype(dq_ref.dtype)
 
 
 def _flash_forward(q, k, v, *, causal: bool, block_q: int, block_k: int,
@@ -402,15 +436,45 @@ def _flash_forward(q, k, v, *, causal: bool, block_q: int, block_k: int,
     return out.reshape(b, h, lq, d).transpose(0, 2, 1, 3), lse
 
 
+# VMEM the fused backward may plan to hold: a quarter of a v5e's 128 MiB,
+# leaving the compiler room for its own internal scratch
+_FUSED_BWD_VMEM = 32 << 20
+
+
+def _fused_bwd_vmem_bytes(group: int, lq: int, d: int, block_q: int,
+                          block_k: int, itemsize: int) -> int:
+    """VMEM the fused backward holds live at once, from its shapes: the
+    group's f32 dQ scratch with its double-buffered output block; the
+    double-buffered q, dO, k, v, dK and dV blocks and the lse and delta
+    rows (a [block_q, 1] f32 block pads to 128 lanes); the dK/dV scratch;
+    and a tile's four f32 temporaries (S, P, dP, dS) with the two casts
+    that feed the MXU."""
+    dq = group * lq * d * (4 + 2 * itemsize)
+    blocks = (2 * itemsize * d * (2 * block_q + 4 * block_k)
+              + 2 * 2 * block_q * 128 * 4)
+    dkv_scratch = 2 * block_k * d * 4
+    tile = block_q * block_k * (4 * 4 + 2 * itemsize)
+    return dq + blocks + dkv_scratch + tile
+
+
 def _flash_backward(q, k, v, o, lse, g, *, causal: bool, block_q: int,
                     block_k: int, interpret: bool, window: int = 0,
                     segments=None):
-    """Pallas dQ/dK/dV (FlashAttention-2 scheme).
+    """Pallas dQ/dK/dV (FlashAttention-2 scheme), by one of two paths
+    that the shapes choose:
+
+    - fused (kernel ``flash_bwd_fused``), where ``_fused_bwd_vmem_bytes``
+      fits ``_FUSED_BWD_VMEM``: one kernel over the per-KV-head grid that
+      recomputes P once a tile and accumulates all three gradients;
+    - split (``flash_bwd_dq``, then ``flash_bwd_dkv``) where the group's
+      f32 dQ does not fit (long sequences, wide GQA groups): each kernel
+      recomputes P for its own gradients.
 
     GQA: the kv BlockSpec indexes each q head's group row (as in the
-    forward), so K/V are never repeated in HBM, and the dK/dV kernel
-    accumulates the whole q-head group in VMEM scratch so its outputs are
-    the true [b*kvh] size (no group-factor HBM amplification)."""
+    forward), so K/V are never repeated in HBM, and the dK/dV (and fused)
+    kernels accumulate the whole q-head group in VMEM scratch so dK/dV
+    are written at the true [b*kvh] size (no group-factor HBM
+    amplification)."""
     b, lq, h, d = q.shape
     lk, kvh = k.shape[1], k.shape[2]
     group = h // kvh
@@ -424,53 +488,61 @@ def _flash_backward(q, k, v, o, lse, g, *, causal: bool, block_q: int,
     delta = jnp.sum(dor.astype(jnp.float32)
                     * o.transpose(0, 2, 1, 3).reshape(b * h, lq, d)
                     .astype(jnp.float32), axis=-1, keepdims=True)
+    has_seg = segments is not None
+    seg3 = segments[:, None, :] if has_seg else None
+    kw = dict(causal=causal, block_q=block_q, block_k=block_k, scale=scale,
+              window=window, has_seg=has_seg)
+    dq_shape = jax.ShapeDtypeStruct((b * h, lq, d), q.dtype)
+    vmem = _fused_bwd_vmem_bytes(group, lq, d, block_q, block_k,
+                                 q.dtype.itemsize)
+    fused = vmem <= _FUSED_BWD_VMEM
 
     nk = lk // block_k
-    nkb = _kv_band(window, block_q, block_k, nk) if causal else nk
+    if not fused:
+        nkb = _kv_band(window, block_q, block_k, nk) if causal else nk
 
-    def kv_index_dq(bh, qi, ki):
-        row = (bh // h) * kvh + (bh % h) // group
-        if causal:
-            return row, _banded_ki(qi, ki, nkb, block_q, block_k, nk), 0
-        return row, ki, 0
+        def kv_index_dq(bh, qi, ki):
+            row = (bh // h) * kvh + (bh % h) // group
+            if causal:
+                return row, _banded_ki(qi, ki, nkb, block_q, block_k, nk), 0
+            return row, ki, 0
 
-    q_spec_dq = pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0))
-    row_spec_dq = pl.BlockSpec((1, block_q, 1),
-                               lambda bh, qi, ki: (bh, qi, 0))
-    in_specs_dq = [
-        q_spec_dq,
-        pl.BlockSpec((1, block_k, d), kv_index_dq),
-        pl.BlockSpec((1, block_k, d), kv_index_dq),
-        q_spec_dq,
-        row_spec_dq,
-        row_spec_dq,
-    ]
-    operands_dq = [qr, kr, vr, dor, lse, delta]
-    if segments is not None:
-        seg3 = segments[:, None, :]
-        in_specs_dq += [
-            pl.BlockSpec((1, 1, block_q),
-                         lambda bh, qi, ki: (bh // h, 0, qi)),
-            pl.BlockSpec(
-                (1, 1, block_k),
-                (lambda bh, qi, ki:
-                 (bh // h, 0, _banded_ki(qi, ki, nkb, block_q, block_k, nk)))
-                if causal else (lambda bh, qi, ki: (bh // h, 0, ki))),
+        q_spec_dq = pl.BlockSpec((1, block_q, d),
+                                 lambda bh, qi, ki: (bh, qi, 0))
+        row_spec_dq = pl.BlockSpec((1, block_q, 1),
+                                   lambda bh, qi, ki: (bh, qi, 0))
+        in_specs_dq = [
+            q_spec_dq,
+            pl.BlockSpec((1, block_k, d), kv_index_dq),
+            pl.BlockSpec((1, block_k, d), kv_index_dq),
+            q_spec_dq,
+            row_spec_dq,
+            row_spec_dq,
         ]
-        operands_dq += [seg3, seg3]
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, causal=causal,
-                          block_q=block_q, block_k=block_k, scale=scale,
-                          nk_total=nk, window=window,
-                          has_seg=segments is not None),
-        out_shape=jax.ShapeDtypeStruct((b * h, lq, d), q.dtype),
-        grid=(b * h, lq // block_q, nkb),
-        in_specs=in_specs_dq,
-        out_specs=q_spec_dq,
-        scratch_shapes=[_vmem((block_q, d))],
-        compiler_params=_compiler_params(),
-        interpret=interpret,
-    )(*operands_dq)
+        operands_dq = [qr, kr, vr, dor, lse, delta]
+        if has_seg:
+            in_specs_dq += [
+                pl.BlockSpec((1, 1, block_q),
+                             lambda bh, qi, ki: (bh // h, 0, qi)),
+                pl.BlockSpec(
+                    (1, 1, block_k),
+                    (lambda bh, qi, ki:
+                     (bh // h, 0,
+                      _banded_ki(qi, ki, nkb, block_q, block_k, nk)))
+                    if causal else (lambda bh, qi, ki: (bh // h, 0, ki))),
+            ]
+            operands_dq += [seg3, seg3]
+        dq = pl.pallas_call(
+            functools.partial(_flash_bwd_dq_kernel, nk_total=nk, **kw),
+            out_shape=dq_shape,
+            grid=(b * h, lq // block_q, nkb),
+            in_specs=in_specs_dq,
+            out_specs=q_spec_dq,
+            scratch_shapes=[_vmem((block_q, d))],
+            compiler_params=_compiler_params(),
+            interpret=interpret,
+            name="flash_bwd_dq",
+        )(*operands_dq)
 
     # dK/dV grid is per KV head: the innermost axis walks group*nqb steps
     # (the banded q blocks of all q heads in this group), so outputs are
@@ -497,8 +569,7 @@ def _flash_backward(q, k, v, o, lse, g, *, causal: bool, block_q: int,
         row_spec_dkv,
     ]
     operands_dkv = [qr, kr, vr, dor, lse, delta]
-    if segments is not None:
-        seg3 = segments[:, None, :]
+    if has_seg:
         in_specs_dkv += [
             pl.BlockSpec(
                 (1, 1, block_q),
@@ -511,22 +582,36 @@ def _flash_backward(q, k, v, o, lse, g, *, causal: bool, block_q: int,
                          lambda bkv, ki, s: (bkv // kvh, 0, ki)),
         ]
         operands_dkv += [seg3, seg3]
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, causal=causal,
-                          block_q=block_q, block_k=block_k, scale=scale,
-                          nq=nq, nqb=nqb, window=window,
-                          has_seg=segments is not None),
-        out_shape=[
-            jax.ShapeDtypeStruct((b * kvh, lk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * kvh, lk, d), v.dtype),
-        ],
-        grid=(b * kvh, lk // block_k, group * nqb),
+    out_shapes = [jax.ShapeDtypeStruct((b * kvh, lk, d), k.dtype),
+                  jax.ShapeDtypeStruct((b * kvh, lk, d), v.dtype)]
+    out_specs = [kv_spec_dkv, kv_spec_dkv]
+    scratch = [_vmem((block_k, d)), _vmem((block_k, d))]
+    params = _compiler_params()
+    if fused:
+        # the q heads of one kv head are consecutive rows of dq, so the
+        # group's whole dQ is one output block, resident across both
+        # inner axes and written back once
+        out_shapes.insert(0, dq_shape)
+        out_specs.insert(0, pl.BlockSpec((group, lq, d),
+                                         lambda bkv, ki, s: (bkv, 0, 0)))
+        scratch.insert(0, _vmem((group, lq, d)))
+        params = _compiler_params("arbitrary", vmem_limit_bytes=vmem)
+    outs = pl.pallas_call(
+        functools.partial(_flash_bwd_dkv_kernel, nq=nq, nqb=nqb,
+                          with_dq=fused, **kw),
+        out_shape=out_shapes,
+        grid=(b * kvh, nk, group * nqb),
         in_specs=in_specs_dkv,
-        out_specs=[kv_spec_dkv, kv_spec_dkv],
-        scratch_shapes=[_vmem((block_k, d)), _vmem((block_k, d))],
-        compiler_params=_compiler_params(),
+        out_specs=out_specs,
+        scratch_shapes=scratch,
+        compiler_params=params,
         interpret=interpret,
+        name="flash_bwd_fused" if fused else "flash_bwd_dkv",
     )(*operands_dkv)
+    if fused:
+        dq, dk, dv = outs
+    else:
+        dk, dv = outs
 
     dq = dq.reshape(b, h, lq, d).transpose(0, 2, 1, 3)
     dk = dk.reshape(b, kvh, lk, d).transpose(0, 2, 1, 3)
@@ -540,11 +625,15 @@ def _vmem(shape):
     return pltpu.VMEM(shape, jnp.float32)
 
 
-def _compiler_params():
+def _compiler_params(second: str = "parallel",
+                     vmem_limit_bytes: int | None = None):
+    """The grid's first axis is parallel and its last sequential; the
+    middle axis is sequential too where scratch carries across it."""
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+        dimension_semantics=("parallel", second, "arbitrary"),
+        vmem_limit_bytes=vmem_limit_bytes)
 
 
 def _pick_block(limit: int, length: int) -> int:
